@@ -11,7 +11,6 @@ from __future__ import annotations
 
 __version__ = "0.5.0"  # keep in sync with pyproject.toml
 
-from .core import jax_compat as _jax_compat  # noqa: F401  (shims first)
 from . import ops as _ops_ns
 from .core import dtypes as _dtypes
 from .core import tensor as _tensor_mod

@@ -57,10 +57,7 @@ import jax
 
 from .findings import Finding, Report, Severity
 
-try:  # jaxpr types moved around across jax versions
-    from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var  # noqa: F401
-except Exception:  # pragma: no cover - older/newer layouts
-    from jax.core import ClosedJaxpr, Jaxpr, Literal, Var  # noqa: F401
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal, Var  # noqa: F401
 
 
 @dataclasses.dataclass
@@ -125,16 +122,14 @@ _WIDTH = {  # float widths for narrow->wide upcast detection
 
 
 def _src(eqn):
-    """Best-effort user frame of an eqn: 'file:line (function)'."""
-    try:
-        from jax._src import source_info_util
+    """User frame of an eqn: 'file:line (function)' ('' when every
+    frame is jax-internal)."""
+    from jax._src import source_info_util
 
-        fr = source_info_util.user_frame(eqn.source_info)
-        if fr is None:
-            return ""
-        return f"{fr.file_name}:{fr.start_line} ({fr.function_name})"
-    except Exception:
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
+    if fr is None:
         return ""
+    return f"{fr.file_name}:{fr.start_line} ({fr.function_name})"
 
 
 def _aval_str(aval):
@@ -199,7 +194,9 @@ def _quant_tagged(where, dtypes):
     if not any(np.dtype(d).name in _QUANT_DTYPES for d in dtypes):
         return False
     if "(" in (where or ""):
-        fn_name = where.rsplit("(", 1)[1].rstrip(")")
+        # the frame carries the QUALIFIED name; only the function's own
+        # name tags it, not an enclosing function's or class's
+        fn_name = where.rsplit("(", 1)[1].rstrip(")").rsplit(".", 1)[-1]
         if _QUANT_FN_RE.search(fn_name):
             return True
     return _QUANT_MARKER in _source_line(where)

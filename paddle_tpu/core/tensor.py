@@ -130,10 +130,7 @@ class Tensor:
     def cuda(self, device_id=None, blocking=True):
         """Move to the accelerator (reference Tensor.cuda; here: the
         default non-CPU device — TPU)."""
-        accel = [d for d in jax.devices() if d.platform != "cpu"]
-        if not accel:
-            return self  # CPU-only environment: no-op (tests/CI)
-        dev = accel[device_id or 0] if device_id is not None else accel[0]
+        dev = device_mod.jax_device(device_mod.TPUPlace(device_id or 0))
         return Tensor(jax.device_put(self.value, dev), self.stop_gradient)
 
     def ndimension(self):

@@ -73,9 +73,12 @@ def fused_layer_norm(x, norm_weight, norm_bias, epsilon=1e-5,
 
 # --------------------------------------------------------------------- rope
 def _rope_neox(tv, c, s):
-    if str(tv.dtype) == "float16":
-        # Mosaic TPU rejects f16 ('Unsupported type in mosaic dialect');
-        # composed rotation instead — XLA fuses it
+    from ....kernels import autotune
+
+    if str(tv.dtype) == "float16" or autotune.spmd_refusal("rope"):
+        # Mosaic TPU rejects f16 ('Unsupported type in mosaic dialect')
+        # and a kernel GSPMD would have to partition; composed rotation
+        # instead — XLA fuses it
         half = tv.shape[-1] // 2
         x1, x2 = tv[..., :half], tv[..., half:]
         o1 = x1 * c - x2 * s

@@ -9,5 +9,6 @@ whole-program-compiled callable; ``save``/``load`` export/import StableHLO
 via jax.export (the deployment format replacing ProgramDesc+params).
 """
 from .api import TranslatedLayer, ignore_module, load, not_to_static, save, to_static  # noqa: F401
+from .compile_cache import place_compile_cache  # noqa: F401
 from .trainer import CompiledTrainStep  # noqa: F401
 from . import dy2static  # noqa: F401

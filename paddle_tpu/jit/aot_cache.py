@@ -130,8 +130,15 @@ class AOTProgramCache:
             from jax.experimental import serialize_executable as se
 
             with open(p, "rb") as f:
-                parts = pickle.load(f)
-            compiled = se.deserialize_and_load(*parts)
+                *parts, device_ids = pickle.load(f)
+            # load onto the devices the program was compiled for: left
+            # to its default, jax spreads a one-device program over
+            # every local device and refuses its one-shard arguments
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = se.deserialize_and_load(
+                *parts,
+                execution_devices=[by_id[i] for i in device_ids],
+            )
         except Exception as e:
             _count("error")
             logger.warning(
@@ -153,7 +160,10 @@ class AOTProgramCache:
         try:
             from jax.experimental import serialize_executable as se
 
-            blob = pickle.dumps(se.serialize(compiled))
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ]
+            blob = pickle.dumps((*se.serialize(compiled), device_ids))
             fd, tmp = tempfile.mkstemp(
                 dir=self.path, suffix=".aotx.tmp"
             )

@@ -639,11 +639,13 @@ class CompiledTrainStep:
         """Run the jitted step, translating XLA's unbounded-while reverse-AD
         limitation into an actionable paddle-level error."""
         if self._step_args_sds is None:
-            # avals only — donation below frees the buffers, the
-            # shapes/dtypes stay valid for memory_report()'s re-trace
+            # avals only — donation below frees the buffers; the
+            # shapes/dtypes and the mesh placements stay valid for
+            # memory_report()'s re-trace and for lowering the step again
             self._step_args_sds = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(
-                    jnp.shape(a), jnp.result_type(a)
+                    jnp.shape(a), jnp.result_type(a),
+                    sharding=self._explicit_sharding(a),
                 ),
                 step_args,
             )

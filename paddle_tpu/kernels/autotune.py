@@ -78,6 +78,31 @@ def interpret_mode():
     return all(d.platform == "cpu" for d in jax.devices())
 
 
+def spmd_refusal(kernel):
+    """Whether ``kernel`` must give way to its composed path because
+    the program it is traced into will be partitioned by GSPMD: a
+    multi-device mesh is installed, and the chip's compiler refuses a
+    compiled Pallas kernel there ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map.").
+    Counted and warned once through :func:`note_fallback`. Interpreted
+    kernels are plain jax ops and partition like any other, so the CPU
+    runs keep them."""
+    if interpret_mode():
+        return False
+    from ..parallel import mesh as mesh_mod
+
+    if not mesh_mod.mesh_defined() or mesh_mod.get_mesh().size == 1:
+        return False
+    mesh = mesh_mod.get_mesh()
+    note_fallback(
+        kernel, "mesh" + "x".join(str(n) for n in mesh.devices.shape),
+        "unpartitionable",
+        detail="Mosaic kernels cannot be automatically partitioned. "
+               "Please wrap the call in a shard_map.",
+    )
+    return True
+
+
 def flash_sig(b, sq, sk, h, d, causal):
     return f"b{b}_sq{sq}_sk{sk}_h{h}_d{d}_c{int(bool(causal))}"
 
@@ -428,23 +453,31 @@ def norm_matmul_config_legal(rows, n_out, config):
     return (br >= 1 and bc >= 1 and rows % br == 0 and n_out % bc == 0)
 
 
-def paged_attention_candidates(kv_heads):
+def paged_attention_candidates(kv_heads, quant=False):
     """``block_kvh`` candidates for the paged decode attention kernel:
-    KV heads handled per grid step. Larger blocks amortize the per-page
-    table-indexed loads across more heads; smaller blocks bound the
-    per-step VMEM footprint (the gathered V scratch is
-    ``[block_kvh * group, S_virtual, D]`` fp32). Only divisors of the
-    model's KV-head count are legal."""
+    KV heads handled per grid step. A page block is
+    ``[page_size, block_kvh, D]`` of a ``[.., page_size, kvH, D]``
+    arena, and the chip's compiler takes a second-minor block dim only
+    when it is the whole axis or a multiple of 8 — so the candidates
+    are all of ``kvH`` and its multiple-of-8 divisors. Smaller blocks
+    bound the per-step VMEM footprint; larger ones amortize the
+    per-page table-indexed loads across more heads. An int8 arena's
+    scale block is ``[page_size, block_kvh]`` with the heads on the
+    MINOR axis, where only the whole axis (or a multiple of 128) is
+    taken: ``quant`` leaves the one candidate."""
+    if quant:
+        return [{"block_kvh": kv_heads}]
     return [{"block_kvh": b}
-            for b in _divisors(kv_heads, (8, 4, 2, 1))]
+            for b in sorted({kv_heads, *_divisors(kv_heads, (32, 16, 8))},
+                            reverse=True)]
 
 
-def paged_attention_config_legal(kv_heads, config):
+def paged_attention_config_legal(kv_heads, config, quant=False):
     try:
         bk = int(config["block_kvh"])
     except (KeyError, TypeError, ValueError):
         return False
-    return bk >= 1 and kv_heads % bk == 0
+    return {"block_kvh": bk} in paged_attention_candidates(kv_heads, quant)
 
 
 def int8_matmul_candidates(rows, n_out):

@@ -180,6 +180,8 @@ def _select(q, k, v, causal):
             return False, None, "policy:cross-length-causal"
         return False, None, "policy:below-threshold"
     sig = autotune.flash_sig(b, sq, sk, h, d, causal)
+    if autotune.spmd_refusal("flash_attention"):
+        return False, None, "fallback:unpartitionable"
     if _pallas_fa() is None:
         autotune.note_fallback("flash_attention", sig,
                                "kernel-unavailable")

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-from .autotune import interpret_mode as _interpret
+from . import autotune
 
 
 def _normed_rows(x, w, eps):
@@ -44,7 +44,10 @@ def _normed_rows(x, w, eps):
 
 def _fused_kernel(x_ref, w_ref, m_ref, o_ref, *, eps):
     y = _normed_rows(x_ref[:], w_ref[:], eps)   # [br, H]
-    o_ref[:] = jnp.dot(y, m_ref[:]).astype(o_ref.dtype)
+    # the MXU accumulates in fp32 (Mosaic refuses a narrower accumulator)
+    o_ref[:] = jnp.dot(
+        y, m_ref[:], preferred_element_type=jnp.float32
+    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -63,7 +66,7 @@ def _norm_matmul(x2d, w, wm, eps, block_rows, block_cols):
         out_specs=pl.BlockSpec((block_rows, block_cols),
                                lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n_out), out_dtype),
-        interpret=_interpret(),
+        interpret=autotune.interpret_mode(),
     )(x2d, w.reshape(1, h), wm)
 
 
@@ -90,8 +93,6 @@ _norm_matmul.defvjp(_fwd, _bwd)
 
 
 def _resolve_blocks(rows, n_out, block_rows, block_cols):
-    from . import autotune
-
     if block_rows is None or block_cols is None:
         cands = autotune.norm_matmul_candidates(rows, n_out)
         if not cands:
@@ -137,8 +138,6 @@ def head_fusion_select(rows, hidden, n_out):
     config when a measured entry exists for this exact shape on this
     device, else None (call sites keep the unfused path —
     byte-identical to the pre-autotuner behavior)."""
-    from . import autotune
-
     sig = autotune.norm_matmul_sig(rows, hidden, n_out)
     entry = autotune.lookup_entry("rms_norm_matmul", sig)
     if entry is None:
@@ -154,6 +153,8 @@ def head_fusion_select(rows, hidden, n_out):
         # the tuner measured composed FASTER for this exact shape on
         # this device — a measured policy decision, not a fallback
         autotune.note_selection("rms_norm_matmul", "composed:measured")
+        return None
+    if autotune.spmd_refusal("rms_norm_matmul"):
         return None
     autotune.note_selection("rms_norm_matmul", "fused:cached")
     return cfg

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-from .autotune import interpret_mode as _interpret
+from . import autotune
 
 
 def _table_2d(t):
@@ -89,9 +89,11 @@ def _fused_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, o_ref, *,
     v = v_ref[0, 0].astype(jnp.float32)      # [S, D]
     cos = cos_ref[:].astype(jnp.float32)     # [S, D/2]
     sin = sin_ref[:].astype(jnp.float32)
-    row0 = i * block_q
-    cos_q = jax.lax.dynamic_slice_in_dim(cos, row0, block_q, axis=0)
-    sin_q = jax.lax.dynamic_slice_in_dim(sin, row0, block_q, axis=0)
+    row0 = pl.multiple_of(i * block_q, block_q)
+    # the q rows' table slice is read off the ref: Mosaic has no
+    # value-level dynamic_slice
+    cos_q = cos_ref[pl.ds(row0, block_q), :].astype(jnp.float32)
+    sin_q = sin_ref[pl.ds(row0, block_q), :].astype(jnp.float32)
     rq = _rotate(q, cos_q, sin_q)
     rk = _rotate(k, cos, sin)
     # contract d-with-d directly (no rk.T): the same dot_general
@@ -122,7 +124,7 @@ def _rope_attention(q, k, v, cos, sin, causal, scale, block_q):
         out_specs=pl.BlockSpec((1, 1, block_q, d),
                                lambda i, j, t: (i, j, t, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        interpret=_interpret(),
+        interpret=autotune.interpret_mode(),
     )(qt, kt, vt, cos, sin)
     return jnp.swapaxes(out, 1, 2)
 
@@ -184,8 +186,6 @@ def rope_attention_fused(q, k, v, cos, sin, causal=True, scale=None,
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if block_q is None:
-        from . import autotune
-
         cands = autotune.rope_attention_candidates(s)
         if not cands:
             raise ValueError(f"S={s} has no legal block_q")
@@ -214,8 +214,6 @@ def rope_attention_select(b, s, h, d):
     None (call sites keep the unfused path — byte-identical to the
     pre-autotuner behavior). A cached-but-illegal (stale) config is a
     counted, one-shot-warned fallback."""
-    from . import autotune
-
     if d % 2 or s < 8:
         return None
     sig = autotune.rope_attention_sig(b, s, h, d)
@@ -231,6 +229,8 @@ def rope_attention_select(b, s, h, d):
         # the tuner measured composed FASTER for this exact shape on
         # this device — a measured policy decision, not a fallback
         autotune.note_selection("rope_attention", "composed:measured")
+        return None
+    if autotune.spmd_refusal("rope_attention"):
         return None
     autotune.note_selection("rope_attention", "fused:cached")
     return cfg

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .autotune import interpret_mode as _interpret
+from . import autotune
 
 
 def quantize_weight_with_scales(w, scale):
@@ -70,7 +70,10 @@ def _dequant(w_q, scale, dtype):
 
 def _int8_kernel(x_ref, w_ref, s_ref, o_ref, *, out_dtype):
     w = _dequant(w_ref[:], s_ref[:], x_ref.dtype)   # [H, bc] in VMEM
-    o_ref[:] = jnp.dot(x_ref[:], w).astype(out_dtype)
+    # the MXU accumulates in fp32 (Mosaic refuses a narrower accumulator)
+    o_ref[:] = jnp.dot(
+        x_ref[:], w, preferred_element_type=jnp.float32
+    ).astype(out_dtype)
 
 
 def int8_matmul(x, w_q, scale, block_rows=None, block_cols=None):
@@ -91,7 +94,7 @@ def int8_matmul(x, w_q, scale, block_rows=None, block_cols=None):
         ],
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, n_out), x2d.dtype),
-        interpret=_interpret(),
+        interpret=autotune.interpret_mode(),
     )(x2d, w_q, scale.reshape(1, n_out).astype(jnp.float32))
     return out.reshape(tuple(shape[:-1]) + (n_out,))
 
@@ -111,8 +114,6 @@ def int8_matmul_composed(x, w_q, scale):
 
 
 def _resolve_blocks(rows, n_out, block_rows, block_cols):
-    from . import autotune
-
     if block_rows is None or block_cols is None:
         cands = autotune.int8_matmul_candidates(rows, n_out)
         if not cands:
@@ -133,8 +134,6 @@ def int8_matmul_select(rows, hidden, n_out):
     """Tune-cache OPT-IN selection: the fused kernel's config when a
     measured entry exists for this exact shape on this device, else
     None (call sites keep the composed dequant->matmul)."""
-    from . import autotune
-
     sig = autotune.int8_matmul_sig(rows, hidden, n_out)
     entry = autotune.lookup_entry("int8_matmul", sig)
     if entry is None:
@@ -148,6 +147,8 @@ def int8_matmul_select(rows, hidden, n_out):
         return None
     if entry.get("fused_beats_composed") is False:
         autotune.note_selection("int8_matmul", "composed:measured")
+        return None
+    if autotune.spmd_refusal("int8_matmul"):
         return None
     autotune.note_selection("int8_matmul", "fused:cached")
     return cfg

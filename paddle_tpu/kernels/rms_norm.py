@@ -18,14 +18,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-from .autotune import interpret_mode as _interpret
+from . import autotune
 
 
-def _block_rows(n):
-    for b in (256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if n % b == 0:
-            return b
-    return 1
+# Mosaic gives a kernel 16 MiB of scoped VMEM on a v5e unless told
+# otherwise; the row block is sized to stay inside three quarters of it.
+_VMEM_BUDGET = 12 << 20
+
+
+def _block_rows(n, h, itemsize, n_io, n_tmp):
+    """Rows per grid step: the largest rung whose VMEM footprint fits
+    the budget — ``n_io`` double-buffered ``[rows, h]`` activation
+    blocks of ``itemsize`` bytes plus ``n_tmp`` fp32 ``[rows, h]``
+    temporaries the body keeps live. The v5e compiler's own allocation
+    reports at hidden 4096 put the temporaries at 1 (fwd) and 3.5 (bwd)
+    per element; the rest of the elementwise chain fuses.
+
+    The rung need not divide ``n``: the grid is ``cdiv(n, rows)`` and
+    the last block may hang over the end (its extra rows are never
+    written back; the backward masks them out of ``dw``). A block's
+    row count must be a multiple of 8 or the whole axis, so a prompt of
+    338 tokens cannot be tiled by its divisors (2 x 169)."""
+    if n <= 8:
+        return n  # a whole-axis block is legal at any row count
+    cap = max(_VMEM_BUDGET // (h * (2 * n_io * itemsize + 4 * n_tmp)), 8)
+    return next(b for b in (256, 128, 64, 32, 16, 8) if b <= min(cap, n))
 
 
 # ------------------------------------------------------------------ forward
@@ -41,10 +58,10 @@ def _fwd_kernel(x_ref, w_ref, o_ref, rstd_ref, *, eps):
 
 def _rms_fwd(x2d, w, eps):
     n, h = x2d.shape
-    br = _block_rows(n)
+    br = _block_rows(n, h, x2d.dtype.itemsize, n_io=2, n_tmp=1)
     y, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
-        grid=(n // br,),
+        grid=(pl.cdiv(n, br),),
         in_specs=[
             pl.BlockSpec((br, h), lambda i: (i, 0)),
             pl.BlockSpec((1, h), lambda i: (0, 0)),
@@ -57,7 +74,7 @@ def _rms_fwd(x2d, w, eps):
             jax.ShapeDtypeStruct((n, h), x2d.dtype),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=autotune.interpret_mode(),
     )(x2d, w.reshape(1, h))
     return y, rstd
 
@@ -65,7 +82,7 @@ def _rms_fwd(x2d, w, eps):
 # ----------------------------------------------------------------- backward
 
 
-def _bwd_kernel(x_ref, w_ref, g_ref, rstd_ref, dx_ref, dw_ref):
+def _bwd_kernel(x_ref, w_ref, g_ref, rstd_ref, dx_ref, dw_ref, *, n_rows):
     x = x_ref[:].astype(jnp.float32)
     g = g_ref[:].astype(jnp.float32)
     w = w_ref[:].astype(jnp.float32)
@@ -76,7 +93,15 @@ def _bwd_kernel(x_ref, w_ref, g_ref, rstd_ref, dx_ref, dw_ref):
     dx = rstd * gw - x * (rstd * rstd * rstd) * m
     dx_ref[:] = dx.astype(dx_ref.dtype)
     # dw accumulates across row blocks into the single resident block
-    part = jnp.sum(g * (x * rstd), axis=0, keepdims=True)
+    gx = g * (x * rstd)
+    br = x.shape[0]
+    if n_rows % br:
+        # the last block hangs over the end: what it read there is not
+        # data (a select, not a multiply — it may be NaN)
+        row = pl.program_id(0) * br + jax.lax.broadcasted_iota(
+            jnp.int32, (br, 1), 0)
+        gx = jnp.where(row < n_rows, gx, 0.0)
+    part = jnp.sum(gx, axis=0, keepdims=True)
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -89,10 +114,10 @@ def _bwd_kernel(x_ref, w_ref, g_ref, rstd_ref, dx_ref, dw_ref):
 
 def _rms_bwd(x2d, w, g2d, rstd):
     n, h = x2d.shape
-    br = _block_rows(n)
+    br = _block_rows(n, h, x2d.dtype.itemsize, n_io=3, n_tmp=4)
     dx, dw = pl.pallas_call(
-        _bwd_kernel,
-        grid=(n // br,),
+        functools.partial(_bwd_kernel, n_rows=n),
+        grid=(pl.cdiv(n, br),),
         in_specs=[
             pl.BlockSpec((br, h), lambda i: (i, 0)),
             pl.BlockSpec((1, h), lambda i: (0, 0)),
@@ -107,7 +132,7 @@ def _rms_bwd(x2d, w, g2d, rstd):
             jax.ShapeDtypeStruct((n, h), x2d.dtype),
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=autotune.interpret_mode(),
     )(x2d, w.reshape(1, h), g2d, rstd)
     return dx, dw.reshape(h)
 

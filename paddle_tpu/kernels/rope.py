@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-from .autotune import interpret_mode as _interpret
+from . import autotune
 
 
 def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref):
@@ -67,7 +67,7 @@ def _rope_apply(x, cos, sin):
         ],
         out_specs=pl.BlockSpec((1, sb, h, d), lambda i, k: (i, k, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, h, d), x.dtype),
-        interpret=_interpret(),
+        interpret=autotune.interpret_mode(),
     )(x, cos, sin)
     return out
 

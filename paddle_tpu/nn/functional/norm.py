@@ -142,9 +142,10 @@ def _fused_rms_available(x, weight, bias, begin_axis):
         return False
     if str(getattr(x, "dtype", "")) == "float16":
         return False
-    import jax as _j
+    from ...kernels import autotune
 
-    return any(d.platform != "cpu" for d in _j.devices())
+    return not autotune.interpret_mode() and not autotune.spmd_refusal(
+        "rms_norm")
 
 
 def rms_norm(x, weight=None, bias=None, epsilon=1e-6, begin_norm_axis=-1, name=None):

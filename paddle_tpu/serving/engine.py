@@ -232,16 +232,12 @@ class ServingEngine:
         self._key = jax.random.PRNGKey(seed)  # warmup example key shape
         self.keys = SamplingKeySource(seed)
         self.step_count = 0
-        # donation only helps (and only works) on accelerators; on the
-        # CPU CI it would just emit unusable-donation warnings
-        accel = any(d.platform != "cpu" for d in jax.devices())
+        # every program that rewrites the KV state donates it, on every
+        # backend: the tests run the programs users get
         self._prefill_fns = {}   # bucket -> jitted fn
         self._adopt_fns = {}     # bucket -> jitted fn
         self._spec_gather_fn = None  # lazy (speculative verify only)
-        self._decode_fn = jax.jit(
-            self._decode_body, donate_argnums=(3,) if accel else ()
-        )
-        self._donate = accel
+        self._decode_fn = jax.jit(self._decode_body, donate_argnums=(3,))
         self._traced = set()
         # count of in-flight requests that carry an open decode span —
         # the decode hot path checks this ONE integer and, when zero
@@ -324,7 +320,7 @@ class ServingEngine:
         body = build_prefill_body(self.net, self.do_sample, self.top_k,
                                   self.top_p)
         fn = jax.jit(
-            body, donate_argnums=(4,) if self._donate else ()
+            body, donate_argnums=(4,)
         )
         self._prefill_fns[bucket] = fn
         self.trace_guard.record_compile(
@@ -346,7 +342,7 @@ class ServingEngine:
             ]
 
         fn = jax.jit(
-            body, donate_argnums=(0,) if self._donate else ()
+            body, donate_argnums=(0,)
         )
         self._adopt_fns[bucket] = fn
         self.trace_guard.record_compile(
@@ -618,13 +614,10 @@ class ServingEngine:
             if psp is not None:
                 psp.finish(error="admission_error")
             self._slab.release(slot)
-            # under donation the failed call may already have consumed
-            # the block's buffers — recycling them would poison the
-            # bucket's freelist; drop the block instead
-            if self._donate:
-                self.pool.discard(blk)
-            else:
-                self.pool.free(blk)
+            # the failed call may already have consumed the block's
+            # donated buffers — recycling them would poison the bucket's
+            # freelist; drop the block instead
+            self.pool.discard(blk)
             raise
         if psp is not None:
             psp.finish()
@@ -970,7 +963,6 @@ class ServingEngine:
             "do_sample": self.do_sample,
             "top_k": self.top_k,
             "top_p": self.top_p,
-            "donate": self._donate,
             "model": {
                 "vocab": int(cfg.vocab_size),
                 "hidden": int(cfg.hidden_size),
@@ -1104,7 +1096,7 @@ class ServingEngine:
                 cache, "decode", ("decode",), self._decode_fn,
                 self._decode_example_args(),
                 lambda comp: setattr(self, "_decode_fn", comp), stats,
-                donate=(3,) if self._donate else (),
+                donate=(3,),
             )
             if decode_fresh:
                 self.trace_guard.record_compile(
@@ -1124,7 +1116,7 @@ class ServingEngine:
                         self._prefill_fn(b), pargs,
                         lambda comp, b=b: self._prefill_fns
                         .__setitem__(b, comp), stats,
-                        donate=(4,) if self._donate else (),
+                        donate=(4,),
                     )
                     self._warm_one(
                         cache, f"adopt_b{b}", ("adopt", b),
@@ -1132,7 +1124,7 @@ class ServingEngine:
                         self._adopt_example_args(flat, b),
                         lambda comp, b=b: self._adopt_fns
                         .__setitem__(b, comp), stats,
-                        donate=(0,) if self._donate else (),
+                        donate=(0,),
                     )
                 finally:
                     self.pool.free(blk)

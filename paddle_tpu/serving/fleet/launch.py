@@ -18,6 +18,12 @@ token-exact across processes.
 ``make fleet-smoke`` and the tests share: launch, wait for the READY
 line, keep draining the child's output into a bounded tail ring (so a
 chatty child can never block on a full pipe), and hand back the port.
+
+Neither ``main`` nor the spawn helpers choose a platform: a child runs
+on whatever its inherited environment says (the CPU gates and the tests
+export ``JAX_PLATFORMS=cpu``). A chip belongs to one process, so N
+replica children on a one-chip host cannot all claim it — they fail at
+start-up instead of quietly serving from the CPU.
 """
 from __future__ import annotations
 
@@ -102,7 +108,6 @@ def _warmup(engine, args):
 
 
 def main(argv=None):
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--role", choices=("replica", "prefill", "router"),
                     default="replica")
@@ -274,7 +279,6 @@ def _popen(role, cli_args, env):
         os.path.dirname(os.path.abspath(__file__)))))
     child_env = dict(os.environ)
     child_env.update(env or {})
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
     child_env["PYTHONUNBUFFERED"] = "1"
     child_env["PYTHONPATH"] = (
         repo_root + os.pathsep + child_env.get("PYTHONPATH", "")
@@ -359,4 +363,7 @@ def spawn_all(specs, *, timeout_s=300.0, env=None):
 
 
 if __name__ == "__main__":
+    from ...jit import place_compile_cache
+
+    place_compile_cache()
     sys.exit(main())

@@ -451,7 +451,7 @@ class PagedServingEngine(ServingEngine):
             ]
 
         fn = jax.jit(
-            body, donate_argnums=(0,) if self._donate else ()
+            body, donate_argnums=(0,)
         )
         self._adopt_fns[bucket] = fn
         self.trace_guard.record_compile(
@@ -498,7 +498,7 @@ class PagedServingEngine(ServingEngine):
         body = build_chunk_prefill_body(self.net, self.do_sample,
                                         self.top_k, self.top_p)
         fn = jax.jit(
-            body, donate_argnums=(5,) if self._donate else ()
+            body, donate_argnums=(5,)
         )
         self._chunk_fns[(bucket, tail_bucket)] = fn
         self.trace_guard.record_compile(
@@ -741,15 +741,11 @@ class PagedServingEngine(ServingEngine):
 
     # ---------------------------------------------------------- requests
     def _drop_block(self, blk):
-        """Return a prefill block after a failed admission. Under
-        donation the failed call may already have consumed the block's
-        buffers — recycling would poison the freelist, so discard."""
-        if blk is None:
-            return
-        if self._donate:
+        """Return a prefill block after a failed admission. The failed
+        call may already have consumed the block's donated buffers —
+        recycling would poison the freelist, so discard."""
+        if blk is not None:
             self.pool.discard(blk)
-        else:
-            self.pool.free(blk)
 
     def _remote_prefill(self, req, bucket, key, trace=None):
         """Try the attached prefill pool: ``(first_token, flat_block)``
@@ -1007,7 +1003,7 @@ class PagedServingEngine(ServingEngine):
                             cargs,
                             lambda comp, b=b, tb=tb: self._chunk_fns
                             .__setitem__((b, tb), comp), stats,
-                            donate=(5,) if self._donate else (),
+                            donate=(5,),
                         )
                 finally:
                     self.pool.free(blk)
@@ -1024,7 +1020,7 @@ class PagedServingEngine(ServingEngine):
                      jnp.zeros((1,), jnp.int32)),
                     lambda comp: self._adopt_fns
                     .__setitem__(ps, comp), stats,
-                    donate=(0,) if self._donate else (),
+                    donate=(0,),
                 )
         finally:
             # lowering traced the bodies — restore concrete weights

@@ -473,8 +473,11 @@ def _two_rank_mesh():
 
 def test_collective_divergence_positive():
     """The distributed-hang shape: one cond branch psums, the other
-    does not — ranks disagreeing on the predicate deadlock."""
-    from jax.experimental.shard_map import shard_map
+    does not — ranks disagreeing on the predicate deadlock. jax's own
+    varying-axes type check rejects this cond at trace time, so the
+    hazard only reaches a compiled program from code that turned that
+    check off (``check_vma=False``) — which is where the linter is the
+    last line of defence."""
     from jax.sharding import PartitionSpec as P
 
     mesh = _two_rank_mesh()
@@ -487,8 +490,8 @@ def test_collective_divergence_positive():
                 lambda v: v,
                 x,
             )
-        return shard_map(body, mesh=mesh, in_specs=P("dp"),
-                         out_specs=P("dp"))(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=P("dp"),
+                             out_specs=P("dp"), check_vma=False)(x)
 
     cfg = LintConfig(mesh_axes=("dp",), check_fp64=False)
     rep = analysis.lint_fn(f, jnp.ones((2, 4), jnp.float32),

@@ -516,9 +516,22 @@ def _paged_fixture(dtype=jnp.float32, kvh=2, h=4):
 def test_paged_candidates_legal_and_sig():
     for cfg in at.paged_attention_candidates(8):
         assert at.paged_attention_config_legal(8, cfg), cfg
-    assert {c["block_kvh"] for c in at.paged_attention_candidates(8)} \
-        == {8, 4, 2, 1}
+    # the chip takes a page block only at the whole kvH axis or a
+    # multiple of 8 of it
+    assert [c["block_kvh"] for c in at.paged_attention_candidates(8)] \
+        == [8]
+    assert [c["block_kvh"] for c in at.paged_attention_candidates(32)] \
+        == [32, 16, 8]
+    assert [c["block_kvh"] for c in at.paged_attention_candidates(4)] \
+        == [4]
     assert not at.paged_attention_config_legal(8, {"block_kvh": 3})
+    assert not at.paged_attention_config_legal(8, {"block_kvh": 4})
+    # an int8 arena's scale block has the heads on its minor axis: only
+    # the whole axis
+    assert at.paged_attention_candidates(32, quant=True) \
+        == [{"block_kvh": 32}]
+    assert not at.paged_attention_config_legal(32, {"block_kvh": 8},
+                                               quant=True)
     assert not at.paged_attention_config_legal(8, {})
     s = at.paged_attention_sig(2, 4, 8, 4, 2, 16)
     assert s == "b2_p4_ps8_h4_kv2_d16"
@@ -532,11 +545,12 @@ def test_paged_kernel_bitexact_vs_reference(dtype):
     from paddle_tpu.kernels import paged_attention as pa
 
     q, kp, vp, tbl, pos = _paged_fixture(dtype)
-    ref = pa.paged_attention_reference(q, kp, vp, tbl, pos)
+    ref = jax.jit(lambda a, k_, v_: pa.paged_attention_reference(
+        a, k_, v_, tbl, pos))(q, kp, vp)
     outs = [
         jax.jit(lambda a, k_, v_: pa.paged_attention_fused(
             a, k_, v_, tbl, pos, block_kvh=bk))(q, kp, vp)
-        for bk in (1, 2)
+        for bk in (1, 2, None)
     ]
     for out in outs:
         assert out.dtype == q.dtype
@@ -642,7 +656,7 @@ def test_paged_entry_activates_llama_decode_path(tmp_cache):
         "paged_attention",
         at.paged_attention_sig(B, P, ps, cfg.num_attention_heads,
                                cfg.kv_heads, cfg.head_dim),
-        {"block_kvh": 1}, save=False,
+        {"block_kvh": cfg.kv_heads}, save=False,
     )
     sel_before = at.selection_counter().series()
     fused, _ = decode(arena)
@@ -786,7 +800,8 @@ def test_int8_paged_kernel_bitexact_vs_reference(dtype):
     q, kp, vp, tbl, pos = _paged_fixture(dtype)
     kq = QuantizedKV(*quantize_kv(kp))
     vq = QuantizedKV(*quantize_kv(vp))
-    ref = pa.paged_attention_reference(q, kq, vq, tbl, pos)
+    ref = jax.jit(lambda a, k_, v_: pa.paged_attention_reference(
+        a, k_, v_, tbl, pos))(q, kq, vq)
     for bk in (1, 2):
         out = jax.jit(lambda a, k_, v_: pa.paged_attention_fused(
             a, k_, v_, tbl, pos, block_kvh=bk))(q, kq, vq)
@@ -816,7 +831,7 @@ def test_int8_paged_selection_keyed_by_quant_sig(tmp_cache):
     at.get_cache().record(
         "paged_attention",
         at.paged_attention_sig(2, 4, 8, 4, 2, 16, quant=True),
-        {"block_kvh": 1}, save=False,
+        {"block_kvh": 2}, save=False,
     )
     assert pa.paged_attention_select(2, 4, 8, 4, 2, 16,
-                                     quantized=True) == {"block_kvh": 1}
+                                     quantized=True) == {"block_kvh": 2}

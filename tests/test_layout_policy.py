@@ -7,9 +7,8 @@ the explicit vocab-parallel CE matches unsharded cross entropy to fp32
 tolerance while NEVER materializing a full-vocab fp32 block (pinned on
 avals), pp-sharded optimizer state writes moments back sharded over pp
 with unchanged training numerics, and the jaxpr linter accepts the
-policy's axis names. The full 7B / compiled-pp-ring lowering proofs need
-partial-manual shard_map and skip on legacy jax images (tools/
-layout_smoke.py runs their reduced forms as a make gate everywhere).
+policy's axis names. The full 7B lowerings run in tools/layout_smoke.py
+(a make gate); the compiled-pp-ring proofs here use a small config.
 """
 import numpy as np
 import pytest
@@ -21,7 +20,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
-from paddle_tpu.core.jax_compat import partial_manual_shard_map_supported
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.distributed.fleet.base.topology import (
     CommunicateTopology,
@@ -37,12 +35,6 @@ from paddle_tpu.jit.trainer import CompiledTrainStep
 from paddle_tpu.parallel import layout, mesh as mesh_mod, tp_ops
 
 VOCAB, HID, B, S = 32, 16, 4, 6
-
-needs_partial_manual = pytest.mark.skipif(
-    not partial_manual_shard_map_supported(),
-    reason="compiled pp ring needs partial-manual shard_map (jax>=0.6)",
-)
-
 
 @pytest.fixture(scope="module")
 def hcg():
@@ -526,7 +518,6 @@ def test_compiled_pipe_vocab_ce_loss_parity_pp1(hcg):
     assert l_def[-1] < l_def[0]  # it actually learns
 
 
-@needs_partial_manual
 def test_lower_7b_small_pp_sharded_layout(hcg):
     """The lower_7b flow under the pp-sharded-state policy on a small
     config: moments lower pp-sharded (verified in the module text) and
@@ -548,7 +539,6 @@ def test_lower_7b_small_pp_sharded_layout(hcg):
     assert rep["fp32_full_vocab_avals"] == 0
 
 
-@needs_partial_manual
 def test_lower_7b_small_long_context_sep(hcg):
     """S-long small config through the sep ring: the lowering keeps the
     ring collectives and the sep-sharded batch."""
@@ -612,15 +602,14 @@ def test_per_chip_budget_pp_sharded_hits_roadmap_number():
         b["rows_gib"]["activations_remat"] * 1.01
 
 
-def test_bench_long_context_reduced_record(hcg):
+def test_bench_long_context_record(hcg):
     """The --long-context impl emits the standard self-describing JSON
-    with the layout-policy name echoed (reduced geometry on legacy
-    jax; the full sep ring needs partial-manual shard_map)."""
+    with the layout-policy name echoed (8 devices: the full pp/sep
+    geometry, so no ``reduced`` label)."""
     import bench
 
     rec = bench._long_context_impl(S=32)
     assert rec["layout_policy"] == "long-context"
     assert rec["value"] > 0 and rec["unit"] == "tokens/s"
     assert "geometry" in rec and "window_sec" in rec
-    if not partial_manual_shard_map_supported():
-        assert "reduced" in rec
+    assert "reduced" not in rec

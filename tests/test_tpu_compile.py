@@ -1,0 +1,307 @@
+"""The chip's compiler, asked without the chip.
+
+Every Pallas kernel in ``paddle_tpu/kernels`` at Llama-2-7B widths
+(hidden 4096, 32 heads x 128, intermediate 11008, vocab 32000), plus the
+paged decode step program at depth 1, compiled for a DESCRIBED v5e chip
+(``jax.experimental.topologies``): what the compiler refuses here it
+refuses on the chip — a block the tiling cannot take, more VMEM than a
+kernel may use, a primitive Mosaic does not lower. Interpret-mode tests
+cannot see any of that. A compile that passes is not a chip run;
+``chip_smoke.py`` is.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: one process at a time may load the TPU's library,
+and under xdist every worker imports every test file. All of these
+tests stay in this one file for the same reason.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.kernels import (
+    autotune,
+    flash_attention as fa,
+    fused_norm_matmul as nm,
+    fused_rope_attention as ra,
+    int8_matmul as i8,
+    paged_attention as pa,
+    rms_norm as rn,
+    rope as rp,
+)
+
+HID, HEADS, HD, FFN, VOCAB = 4096, 32, 128, 11008, 32000
+B, S = 4, 1024          # train rows
+ROWS, CTX = 8, 2048     # decode rows, context a page table spans
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip_compile(one_chip, monkeypatch):
+    """``compile_(fn, *shapes) -> Compiled``: kernels NOT interpreted,
+    persistent compile cache off (an entry written for a described chip
+    cannot be read back without one), shapes placed on the described
+    chip. ``sds(shape, dtype)`` builds such a shape."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(autotune, "interpret_mode", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    # conftest turns x64 on for the finite-difference harness; the
+    # program runs without it, and with it a kernel's index maps come
+    # out i64, which Mosaic does not take
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compile_(fn, *shapes):
+        return jax.jit(fn).lower(*shapes).compile()
+
+    compile_.sds = sds
+    yield compile_
+    jax.config.update("jax_enable_x64", x64)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _grad(fn, argnums):
+    return jax.grad(
+        lambda *a: fn(*a).astype(jnp.float32).sum(), argnums=argnums)
+
+
+def _rms(x, w):
+    return rn.rms_norm_fused(x, w, 1e-6)
+
+
+def _flash(q, k, v):
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        flash_attention,
+    )
+
+    seq = q.shape[2]
+    return flash_attention(
+        q, k, v, causal=True, sm_scale=HD ** -0.5,
+        block_sizes=fa._tuned_block_sizes(seq, seq))
+
+
+def _paged(ps, kvh=HEADS):
+    pages = CTX // ps
+    arena = [((ROWS * pages + 1, ps, kvh, HD), BF)] * 2
+    return [((ROWS, 1, HEADS, HD), BF), *arena,
+            ((ROWS, pages), I32), ((ROWS,), I32)]
+
+
+# name -> (function, [(shape, dtype), ...]). Shapes only: nothing here
+# touches a device or the topology while the module is imported.
+BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+TRAIN, DEC = (B, S, HID), (ROWS, 1, HID)
+QKV = [((B, S, HEADS, HD), BF)] * 3
+TABLE = [((1, S, 1, HD // 2), F32)] * 2
+FLASH = [((2, HEADS, 2048, HD), BF)] * 3
+KERNEL_CASES = {
+    "rms_norm fwd train": (_rms, [(TRAIN, BF), ((HID,), F32)]),
+    "rms_norm bwd train": (_grad(_rms, (0, 1)),
+                           [(TRAIN, BF), ((HID,), F32)]),
+    "rms_norm bwd train fp32": (_grad(_rms, (0, 1)),
+                                [(TRAIN, F32), ((HID,), F32)]),
+    "rms_norm fwd+bwd decode": (_grad(_rms, (0, 1)),
+                                [(DEC, BF), ((HID,), BF)]),
+    # a 338-token prompt: no multiple-of-8 divisor (2 x 169)
+    "rms_norm fwd+bwd 338 rows": (
+        _grad(_rms, (0, 1)), [((1, 338, HID), BF), ((HID,), BF)]),
+    "rope fwd 338 rows": (
+        rp.rope_fused, [((1, 338, HEADS, HD), BF),
+                        *[((1, 338, 1, HD // 2), F32)] * 2]),
+    "rope fwd": (rp.rope_fused, [QKV[0], *TABLE]),
+    "rope bwd": (_grad(rp.rope_fused, 0), [QKV[0], *TABLE]),
+    "rope per-row decode": (
+        rp.rope_fused, [((ROWS, 1, HEADS, HD), BF),
+                        *[((ROWS, 1, 1, HD // 2), F32)] * 2]),
+    "flash fwd S=2048": (_flash, FLASH),
+    "flash bwd S=2048": (_grad(_flash, (0, 1, 2)), FLASH),
+    "int8_matmul ffn": (
+        i8.int8_matmul,
+        [((ROWS, HID), BF), ((HID, FFN), jnp.int8), ((FFN,), F32)]),
+    "int8_matmul head": (
+        i8.int8_matmul,
+        [((ROWS, HID), BF), ((HID, VOCAB), jnp.int8), ((VOCAB,), F32)]),
+    "rms_norm_matmul head": (
+        nm.rms_norm_matmul,
+        [((ROWS, HID), BF), ((HID,), BF), ((HID, VOCAB), BF)]),
+    "rope_attention_fused": (
+        ra.rope_attention_fused,
+        [*QKV, ((S, HD // 2), F32), ((S, HD // 2), F32)]),
+    "paged_attention ps=8": (pa.paged_attention_fused, _paged(8)),
+    "paged_attention ps=16": (pa.paged_attention_fused, _paged(16)),
+    "paged_attention ps=128": (pa.paged_attention_fused, _paged(128)),
+    "paged_attention ps=16 gqa kvh=8": (
+        pa.paged_attention_fused, _paged(16, kvh=8)),
+}
+
+
+@pytest.mark.parametrize("name", KERNEL_CASES)
+def test_kernel_compiles_for_v5e_at_7b_widths(chip_compile, name):
+    fn, shapes = KERNEL_CASES[name]
+    compiled = chip_compile(fn, *(chip_compile.sds(*s) for s in shapes))
+    assert "tpu_custom_call" in compiled.as_text(), (
+        "no Mosaic kernel in the compiled program")
+
+
+def test_paged_attention_int8_arena_compiles(chip_compile):
+    """The int8 flavour: int8 page blocks + their fp32 scale blocks."""
+    from paddle_tpu.quantization.kv import QuantizedKV
+
+    sds = chip_compile.sds
+    ps, pages = 16, CTX // 16
+    n = ROWS * pages + 1
+    arena = QuantizedKV(sds((n, ps, HEADS, HD), jnp.int8),
+                        sds((n, ps, HEADS), jnp.float32))
+    compiled = chip_compile(
+        pa.paged_attention_fused, sds((ROWS, 1, HEADS, HD)), arena, arena,
+        sds((ROWS, pages), jnp.int32), sds((ROWS,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_block_the_chip_refuses_is_not_a_candidate(chip_compile):
+    """``block_kvh`` that is neither the whole kvH axis nor a multiple
+    of 8 is what the seed shipped as its default; the compiler refuses
+    it, and the tuner no longer offers it."""
+    sds = chip_compile.sds
+    ps, pages = 16, CTX // 16
+    n = ROWS * pages + 1
+    with pytest.raises(ValueError, match="divisible by 8 and 128"):
+        chip_compile(
+            lambda q, k, v, t, p: pa.paged_attention_fused(
+                q, k, v, t, p, block_kvh=1),
+            sds((ROWS, 1, HEADS, HD)), sds((n, ps, HEADS, HD)),
+            sds((n, ps, HEADS, HD)), sds((ROWS, pages), jnp.int32),
+            sds((ROWS,), jnp.int32))
+    assert not autotune.paged_attention_config_legal(
+        HEADS, {"block_kvh": 1})
+    assert all(
+        autotune.paged_attention_config_legal(HEADS, c)
+        for c in autotune.paged_attention_candidates(HEADS))
+    assert autotune.paged_attention_candidates(HEADS, quant=True) == [
+        {"block_kvh": HEADS}]
+
+
+def test_rms_norm_row_block_fits_vmem_budget():
+    """The row block shrinks with hidden x itemsize, fwd and bwd apart
+    (the seed's fixed 256 rows ran the 4096-wide backward out of VMEM),
+    and is a multiple of 8 or the whole axis at ANY row count (the
+    seed tiled 338 rows by 2, which the chip refuses)."""
+    assert rn._block_rows(4096, 4096, 2, n_io=2, n_tmp=1) == 256
+    assert rn._block_rows(4096, 4096, 2, n_io=3, n_tmp=4) == 64
+    assert rn._block_rows(4096, 4096, 4, n_io=3, n_tmp=4) == 64
+    assert rn._block_rows(4096, 2048, 2, n_io=3, n_tmp=4) == 128
+    assert rn._block_rows(8, 4096, 2, n_io=3, n_tmp=4) == 8
+    assert rn._block_rows(5, 4096, 2, n_io=3, n_tmp=4) == 5
+    assert rn._block_rows(12, 4096, 2, n_io=3, n_tmp=4) == 8
+    assert rn._block_rows(338, 4096, 2, n_io=3, n_tmp=4) == 64
+
+
+@pytest.mark.parametrize("rows", [5, 12, 338])
+def test_rms_norm_overhanging_last_block_is_masked(rows):
+    """Interpreted, on the CPU: a row count its block does not divide
+    gives the reference's y, dx and — the rows past the end masked out
+    of the accumulation — dw."""
+    rng = np.random.RandomState(rows)
+    x = jnp.asarray(rng.randn(rows, 64), jnp.float32)
+    w = jnp.asarray(rng.randn(64), jnp.float32)
+
+    def ref(x, w):
+        ms = jnp.mean(x * x, axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(ms + 1e-6) * w
+
+    np.testing.assert_allclose(rn.rms_norm_fused(x, w, 1e-6), ref(x, w),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda x, w: jnp.sin(rn.rms_norm_fused(x, w, 1e-6)).sum(),
+                   (0, 1))(x, w)
+    want = jax.grad(lambda x, w: jnp.sin(ref(x, w)).sum(), (0, 1))(x, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
+
+
+def test_paged_decode_step_compiles_at_depth_1(chip_compile, topo,
+                                               monkeypatch):
+    """The whole decode program ``PagedServingEngine`` serves with, at
+    7B widths and depth 1 (bf16 weights as abstract shapes, bf16 pages):
+    the default-path kernels are in it and it fits the chip."""
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import PagedServingEngine
+
+    cfg = paddle.models.LlamaConfig.llama2_7b(
+        num_hidden_layers=1, max_position_embeddings=512)
+    with paddle.LazyGuard():
+        net = paddle.models.LlamaForCausalLM(cfg)
+    for p in net.parameters():
+        p.value = jax.ShapeDtypeStruct(p.value.shape, jnp.bfloat16)
+    net.eval()
+    engine = PagedServingEngine(net, max_batch_size=ROWS, max_seq_len=512,
+                                page_size=16, min_bucket=64)
+    args = jax.tree_util.tree_map(
+        lambda a: chip_compile.sds(jnp.shape(a), jnp.result_type(a)),
+        engine._decode_example_args())
+    # the model picks its kernels from jax.devices(): show it the chip
+    # for the length of the trace
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    compiled = engine._decode_fn.lower(*args).compile()
+    monkeypatch.undo()
+    text = compiled.as_text()
+    # rms_norm x3 (two per layer + final) and rope (q, k)
+    assert text.count("tpu_custom_call") >= 5, text.count("tpu_custom_call")
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 4 << 30, total
+    engine.close()
+
+
+def test_kernels_give_way_under_a_mesh_loudly(monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot
+    be automatically partitioned"), so with a multi-device mesh
+    installed the compiled kernels are refused — counted, in the
+    compiler's words — and the interpreted ones (CPU runs) are not."""
+    from jax.sharding import Mesh
+
+    from paddle_tpu.parallel import mesh as mesh_mod
+
+    devs = jax.devices()
+    if len(devs) < 2:
+        pytest.skip("needs >= 2 virtual devices")
+    prev = mesh_mod._STATE["mesh"]
+    mesh_mod.set_mesh(Mesh(np.array(devs[:2]), ("mp",)))
+    try:
+        assert not autotune.spmd_refusal("rms_norm")   # interpreted
+        monkeypatch.setattr(autotune, "interpret_mode", lambda: False)
+        before = dict(autotune.fallback_counter().series())
+        with pytest.warns(RuntimeWarning, match="cannot be automatically"):
+            autotune.reset_warned()
+            assert autotune.spmd_refusal("rms_norm")
+        after = autotune.fallback_counter().series()
+        key = (("kernel", "rms_norm"), ("reason", "unpartitionable"))
+        assert after.get(key, 0) == before.get(key, 0) + 1
+        mesh_mod.set_mesh(None)
+        assert not autotune.spmd_refusal("rms_norm")   # no mesh
+    finally:
+        mesh_mod.set_mesh(prev)

@@ -124,7 +124,8 @@ def _sig_and_candidates(kernel, spec):
         sig = autotune.paged_attention_sig(
             spec["b"], spec["pages"], spec["page_size"], spec["h"],
             spec["kvh"], spec["d"], quant=spec.get("quant", False))
-        cands = autotune.paged_attention_candidates(spec["kvh"])
+        cands = autotune.paged_attention_candidates(
+            spec["kvh"], quant=spec.get("quant", False))
     elif kernel == "int8_matmul":
         sig = autotune.int8_matmul_sig(spec["rows"], spec["hidden"],
                                        spec["n_out"])
@@ -519,7 +520,7 @@ def smoke():
                     spec["s"], cfg), cfg
             elif kernel == "paged_attention":
                 assert autotune.paged_attention_config_legal(
-                    spec["kvh"], cfg), cfg
+                    spec["kvh"], cfg, spec.get("quant", False)), cfg
             elif kernel == "int8_matmul":
                 assert autotune.int8_matmul_config_legal(
                     spec["rows"], spec["n_out"], cfg), cfg
@@ -562,8 +563,9 @@ def smoke():
     tbl = jnp.asarray(1 + np.arange(8).reshape(2, 4), jnp.int32)
     pos = jnp.asarray([13, 27], jnp.int32)
     fp = jax.jit(lambda a: pa.paged_attention_fused(
-        a, kp, vp, tbl, pos, block_kvh=1))(qp)
-    rp = pa.paged_attention_reference(qp, kp, vp, tbl, pos)
+        a, kp, vp, tbl, pos))(qp)
+    rp = jax.jit(lambda a: pa.paged_attention_reference(
+        a, kp, vp, tbl, pos))(qp)
     assert (np.asarray(fp) == np.asarray(rp)).all(), \
         "paged_attention parity"
     # int8 flavors: weight-only matmul fused == composed bit-exact,
@@ -581,8 +583,9 @@ def smoke():
     kq = QuantizedKV(*quantize_kv(kp))
     vq = QuantizedKV(*quantize_kv(vp))
     fq = jax.jit(lambda a: pa.paged_attention_fused(
-        a, kq, vq, tbl, pos, block_kvh=1))(qp)
-    rq = pa.paged_attention_reference(qp, kq, vq, tbl, pos)
+        a, kq, vq, tbl, pos))(qp)
+    rq = jax.jit(lambda a: pa.paged_attention_reference(
+        a, kq, vq, tbl, pos))(qp)
     assert (np.asarray(fq) == np.asarray(rq)).all(), \
         "int8 paged_attention parity"
     print("tune-smoke OK: generators legal, cache round-trips, "
@@ -625,4 +628,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.jit import place_compile_cache
+
+    place_compile_cache()
     sys.exit(main())

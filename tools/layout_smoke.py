@@ -15,11 +15,9 @@ process-global) and asserts the layout-policy contract end to end:
    per-chip state bytes must shrink by the pp degree, and the analytic
    v5p-64 table must come in at <= 18.4 GiB/chip pp-sharded
    (vs ~29.4 default) — regression here fails the gate;
-5. on a jax with partial-manual shard_map, the full 7B lowering for
-   both layouts PLUS the S=8192 long-context (sep-ring) flagship,
-   asserting the collective set and writing LOWER_7B.json. Legacy
-   0.4.x images run steps 1-4 (GSPMD + manual-over-all shard_map) and
-   report the reduced mode honestly.
+5. the full 7B lowering for both layouts PLUS the S=8192 long-context
+   (sep-ring) flagship, asserting the collective set and writing
+   LOWER_7B.json.
 """
 from __future__ import annotations
 
@@ -218,9 +216,6 @@ def _full_lowerings(out):
 
 
 def run_smoke():
-    from paddle_tpu.core.jax_compat import (
-        partial_manual_shard_map_supported,
-    )
     from paddle_tpu.distributed.fleet.base.topology import (
         CommunicateTopology,
         HybridCommunicateGroup,
@@ -236,17 +231,7 @@ def run_smoke():
     _check_vocab_ce(out)
     _check_pp_sharded_step(out)
     _measure_7b(out)
-    if partial_manual_shard_map_supported():
-        _full_lowerings(out)
-        out["mode"] = "full"
-    else:
-        out["mode"] = "reduced"
-        out["reduced_reason"] = (
-            "legacy jax: partial-manual shard_map unavailable, the "
-            "compiled pp ring cannot lower here — measured-aval + GSPMD "
-            "checks ran; run on a modern-jax image for the full 7B "
-            "lowerings"
-        )
+    _full_lowerings(out)
     out["ok"] = True
     print("layout-smoke: " + json.dumps(out))
     return out

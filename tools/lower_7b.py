@@ -32,10 +32,7 @@ to the analytic table — so layout claims are checked, not assumed:
 
 Run via ``python bench.py --lower-7b`` or ``make layout-smoke`` (both
 self-provision a virtual 8-device CPU mesh) or from
-``__graft_entry__.dryrun_multichip`` phase 4. The full lowering needs a
-jax with partial-manual shard_map (the compiled pp ring); on legacy
-0.4.x images ``make layout-smoke`` degrades to the measured-aval +
-GSPMD-lowering reduced mode.
+``__graft_entry__.dryrun_multichip`` phase 4.
 """
 from __future__ import annotations
 
@@ -46,6 +43,22 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 GiB = 1024 ** 3
+
+
+def _sdy_dims(spec, ndim):
+    """A PartitionSpec's per-dimension axes as Shardy writes them into a
+    lowered module: ``P("pp", "mp")`` -> ``[{"pp"}, {"mp"}]>`` (the
+    closing ``>`` ends the ``#sdy.sharding<@mesh, ...>`` attribute, so
+    a shorter spec cannot match a longer one's prefix)."""
+    dims = []
+    for i in range(ndim):
+        axes = spec[i] if i < len(spec) else None
+        if axes is None:
+            axes = ()
+        elif isinstance(axes, str):
+            axes = (axes,)
+        dims.append("{" + ", ".join(f'"{a}"' for a in axes) + "}")
+    return "[" + ", ".join(dims) + "]>"
 
 
 def _per_chip_budget(cfg, n_params, tp, pp, dp, b_micro, seq, hbm_gib,
@@ -161,6 +174,15 @@ def memory_cross_check(built, budget, tolerance=0.10):
     }
     total = sum(rows.values())
     analytic = budget["effective_total_gib"] * GiB
+    if not analytic:
+        # a cut-down config (the tier-1 harness tests): the analytic
+        # table is kept in hundredths of a GiB and rounds to zero, so
+        # there is nothing to hold the aval sum against
+        return {
+            "state_per_chip_gib": round(total / GiB, 4),
+            "analytic_effective_gib": 0.0,
+            "skipped": "analytic table rounds to 0 GiB at this size",
+        }
     out = {
         "rows_gib": {k: round(v / GiB, 4) for k, v in rows.items()},
         "state_per_chip_gib": round(total / GiB, 4),
@@ -393,9 +415,9 @@ def lower_7b(dp=2, pp=2, mp=2, sep=1, B=8, S=4096, micro_batches=4,
     if pol.pp_shard_optimizer_state:
         # the pp-sharded layout must be IN the lowered module, not just
         # the input avals: every distinct moment sharding the policy
-        # produced must appear as an HLO sharding annotation
+        # produced must appear as a Shardy sharding annotation
         pinned = {
-            str(a.sharding._to_xla_hlo_sharding(len(a.shape)))
+            _sdy_dims(a.sharding.spec, len(a.shape))
             for accs in opt_state.values()
             for a in accs
             if pol.pp_axis in str(getattr(a.sharding, "spec", ""))

@@ -1411,4 +1411,7 @@ if __name__ == "__main__":
     sys.path.insert(
         0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
+    from paddle_tpu.jit import place_compile_cache
+
+    place_compile_cache()
     main()
