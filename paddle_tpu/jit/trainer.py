@@ -346,7 +346,10 @@ class CompiledTrainStep:
                 network.train()
                 out = self._forward_traced(inputs)
                 outs = out if isinstance(out, (list, tuple)) else [out]
-                loss = loss_fn(*(list(outs) + [Tensor(v) for v in labels]))
+                with jax.named_scope("loss"):
+                    loss = loss_fn(
+                        *(list(outs) + [Tensor(v) for v in labels])
+                    )
             new_buffers = {k: b.value for k, b in network.named_buffers()}
             out_vals = tuple(o.value for o in outs)
             if fp8_ctx is not None:
@@ -389,49 +392,9 @@ class CompiledTrainStep:
 
         scaler = self.scaler
 
-        def step(params, opt_state, buffers, lr, t, rng, inputs, labels,
-                 scale=None, good=None, bad=None, fp8_state=None):
-            if scaler is not None:
-                def scaled_loss_of(params, buffers, rng, inputs, labels):
-                    loss, aux = loss_of(params, buffers, rng, inputs,
-                                        labels, fp8_state=fp8_state)
-                    return loss * scale, (aux, loss)
-
-                (
-                    (_, ((new_buffers, out_vals, new_fp8), loss)),
-                    grads,
-                ) = jax.value_and_grad(scaled_loss_of, has_aux=True)(
-                    params, buffers, rng, inputs, labels
-                )
-                inv = (1.0 / scale).astype(jnp.float32)
-                grads = jax.tree_util.tree_map(
-                    lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype),
-                    grads,
-                )
-                finite = jnp.all(jnp.asarray([
-                    jnp.all(jnp.isfinite(g.astype(jnp.float32)))
-                    for g in jax.tree_util.tree_leaves(grads)
-                ]))
-            else:
-                (loss, (new_buffers, out_vals, new_fp8)), grads = \
-                    jax.value_and_grad(loss_of, has_aux=True)(
-                        params, buffers, rng, inputs, labels,
-                        fp8_state,
-                    )
-                finite = None
-
-            if grad_placements:
-                grads = {
-                    k: (
-                        jax.lax.with_sharding_constraint(
-                            g, grad_placements[k]
-                        )
-                        if k in grad_placements
-                        else g
-                    )
-                    for k, g in grads.items()
-                }
-
+        def apply_update(params, opt_state, grads, lr, t):
+            """Gradient clipping and the optimizer's update of every
+            parameter: the part of the step under scope ``optimizer``."""
             # gradient clipping (global-norm path fused into the step)
             if isinstance(clip, ClipGradByGlobalNorm):
                 sq = sum(
@@ -498,6 +461,55 @@ class CompiledTrainStep:
                     )
                     new_params[k] = np_
                     new_state[k] = (m2, v2)
+            return new_params, new_state
+
+        def step(params, opt_state, buffers, lr, t, rng, inputs, labels,
+                 scale=None, good=None, bad=None, fp8_state=None):
+            if scaler is not None:
+                def scaled_loss_of(params, buffers, rng, inputs, labels):
+                    loss, aux = loss_of(params, buffers, rng, inputs,
+                                        labels, fp8_state=fp8_state)
+                    return loss * scale, (aux, loss)
+
+                (
+                    (_, ((new_buffers, out_vals, new_fp8), loss)),
+                    grads,
+                ) = jax.value_and_grad(scaled_loss_of, has_aux=True)(
+                    params, buffers, rng, inputs, labels
+                )
+                inv = (1.0 / scale).astype(jnp.float32)
+                grads = jax.tree_util.tree_map(
+                    lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype),
+                    grads,
+                )
+                finite = jnp.all(jnp.asarray([
+                    jnp.all(jnp.isfinite(g.astype(jnp.float32)))
+                    for g in jax.tree_util.tree_leaves(grads)
+                ]))
+            else:
+                (loss, (new_buffers, out_vals, new_fp8)), grads = \
+                    jax.value_and_grad(loss_of, has_aux=True)(
+                        params, buffers, rng, inputs, labels,
+                        fp8_state,
+                    )
+                finite = None
+
+            if grad_placements:
+                grads = {
+                    k: (
+                        jax.lax.with_sharding_constraint(
+                            g, grad_placements[k]
+                        )
+                        if k in grad_placements
+                        else g
+                    )
+                    for k, g in grads.items()
+                }
+
+            with jax.named_scope("optimizer"):
+                new_params, new_state = apply_update(
+                    params, opt_state, grads, lr, t
+                )
 
             if policy_state_pins or policy_param_pins:
                 new_state = {

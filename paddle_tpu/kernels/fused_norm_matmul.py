@@ -67,6 +67,7 @@ def _norm_matmul(x2d, w, wm, eps, block_rows, block_cols):
                                lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n_out), out_dtype),
         interpret=autotune.interpret_mode(),
+        name="rms_norm_matmul",
     )(x2d, w.reshape(1, h), wm)
 
 

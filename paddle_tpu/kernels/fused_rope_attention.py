@@ -125,6 +125,7 @@ def _rope_attention(q, k, v, cos, sin, causal, scale, block_q):
                                lambda i, j, t: (i, j, t, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         interpret=autotune.interpret_mode(),
+        name="rope_attention",
     )(qt, kt, vt, cos, sin)
     return jnp.swapaxes(out, 1, 2)
 

@@ -95,6 +95,7 @@ def int8_matmul(x, w_q, scale, block_rows=None, block_cols=None):
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, n_out), x2d.dtype),
         interpret=autotune.interpret_mode(),
+        name="int8_matmul",
     )(x2d, w_q, scale.reshape(1, n_out).astype(jnp.float32))
     return out.reshape(tuple(shape[:-1]) + (n_out,))
 

@@ -372,6 +372,7 @@ def paged_attention_fused(q, k_pages, v_pages, page_table, pos,
         out_shape=jax.ShapeDtypeStruct((b, nblk, group, bkvh, d),
                                        q.dtype),
         interpret=autotune.interpret_mode(),
+        name="paged_attention",
     )(*operands)
     # [B, nblk, group, bkvh, D] -> [B, 1, H, D] (head = kv-head-major)
     return out.swapaxes(2, 3).reshape(b, 1, h, d)

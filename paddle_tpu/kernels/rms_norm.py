@@ -75,6 +75,7 @@ def _rms_fwd(x2d, w, eps):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=autotune.interpret_mode(),
+        name="rms_norm_fwd",
     )(x2d, w.reshape(1, h))
     return y, rstd
 
@@ -133,6 +134,7 @@ def _rms_bwd(x2d, w, g2d, rstd):
             jax.ShapeDtypeStruct((1, h), jnp.float32),
         ],
         interpret=autotune.interpret_mode(),
+        name="rms_norm_bwd",
     )(x2d, w.reshape(1, h), g2d, rstd)
     return dx, dw.reshape(h)
 

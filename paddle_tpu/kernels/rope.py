@@ -68,6 +68,7 @@ def _rope_apply(x, cos, sin):
         out_specs=pl.BlockSpec((1, sb, h, d), lambda i, k: (i, k, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, h, d), x.dtype),
         interpret=autotune.interpret_mode(),
+        name="rope",
     )(x, cos, sin)
     return out
 

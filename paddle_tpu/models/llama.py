@@ -130,6 +130,23 @@ class LlamaAttention(nn.Layer):
         q = self.q_proj(x).reshape([B, S, cfg.num_attention_heads, cfg.head_dim])
         k = self.k_proj(x).reshape([B, S, cfg.kv_heads, cfg.head_dim])
         v = self.v_proj(x).reshape([B, S, cfg.kv_heads, cfg.head_dim])
+        # everything between the q/k/v and the o projections, whichever
+        # of its paths runs, is one scope of the compiled program
+        with jax.named_scope("attn_core"):
+            out, new_cache = self._attn_core(
+                q, k, v, rope_cos, rope_sin, attn_mask, cache, pos,
+                page_table,
+            )
+        out = self.o_proj(out.reshape([B, S, -1]))
+        return out if cache is None else (out, new_cache)
+
+    def _attn_core(self, q, k, v, rope_cos, rope_sin, attn_mask, cache,
+                   pos, page_table):
+        """Rope, cache write, page gather, GQA repeat and the attention
+        itself (composed SDPA or a selected kernel): ``(out [B, S, H,
+        D], new_cache)``, ``new_cache`` None without a cache."""
+        cfg = self.cfg
+        B, S = int(q.shape[0]), int(q.shape[1])
         if (cache is None and attn_mask is None
                 and cfg.kv_heads == cfg.num_attention_heads
                 and rope_cos is not None and rope_sin is not None):
@@ -149,7 +166,7 @@ class LlamaAttention(nn.Layer):
                     q, k, v, rope_cos, rope_sin, causal=True,
                     block_q=sel["block_q"],
                 )
-                return self.o_proj(out.reshape([B, S, -1]))
+                return out, None
         pos_ids = None
         if cache is not None:
             p0 = jnp.asarray(pos.value if hasattr(pos, "value") else pos)
@@ -207,10 +224,7 @@ class LlamaAttention(nn.Layer):
                 out = paged_attention_apply(
                     q, k_pages, v_pages, tbl, p, config=sel
                 )
-                return (
-                    self.o_proj(out.reshape([B, S, -1])),
-                    (k_pages, v_pages),
-                )
+                return out, (k_pages, v_pages)
             # default: composed gather + the SAME masked-SDPA the slab
             # per-row branch below decodes through — token streams stay
             # bit-identical to the slab engine and net.generate (extra
@@ -235,10 +249,7 @@ class LlamaAttention(nn.Layer):
                 q, kk, vv, attn_mask=Tensor(mask), is_causal=False,
                 training=False,
             )
-            return (
-                self.o_proj(out.reshape([B, S, -1])),
-                (k_pages, v_pages),
-            )
+            return out, (k_pages, v_pages)
         if cache is not None:
             from ..quantization import kv as qkv
 
@@ -285,10 +296,7 @@ class LlamaAttention(nn.Layer):
                 q, kk, vv, attn_mask=Tensor(mask), is_causal=False,
                 training=False,
             )
-            return (
-                self.o_proj(out.reshape([B, S, -1])),
-                (k_cache, v_cache),
-            )
+            return out, (k_cache, v_cache)
         if cfg.kv_heads != cfg.num_attention_heads:
             rep = cfg.num_attention_heads // cfg.kv_heads
             k = k.repeat_interleave(rep, axis=2)
@@ -297,7 +305,7 @@ class LlamaAttention(nn.Layer):
             q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
             training=self.training,
         )
-        return self.o_proj(out.reshape([B, S, -1]))
+        return out, None
 
 
 class LlamaMLP(nn.Layer):
@@ -456,6 +464,17 @@ class LlamaForCausalLM(LlamaFlopsMixin, nn.Layer):
             block_rows=sel["block_rows"], block_cols=sel["block_cols"],
         )
 
+    def _head(self, h, sel):
+        """Hidden state to logits, always under scope ``lm_head``: the
+        ``lm_head`` layer opens it itself; the fused norm+matmul kernel
+        and the tied head (the embedding's transpose) are no layer."""
+        if sel is None and self.lm_head is not None:
+            return self.lm_head(h)
+        with jax.named_scope("lm_head"):
+            if sel is not None:
+                return self._fused_head(h, sel)
+            return F.linear(h, self.model.embed_tokens.weight.t())
+
     def forward(self, input_ids, attn_mask=None, caches=None, pos=None,
                 page_table=None, exit_layer=None):
         B, S = int(input_ids.shape[0]), int(input_ids.shape[1])
@@ -466,22 +485,11 @@ class LlamaForCausalLM(LlamaFlopsMixin, nn.Layer):
                 apply_final_norm=sel is None, page_table=page_table,
                 exit_layer=exit_layer,
             )
-            if sel is not None:
-                logits = self._fused_head(h, sel)
-            else:
-                logits = (
-                    F.linear(h, self.model.embed_tokens.weight.t())
-                    if self.lm_head is None else self.lm_head(h)
-                )
-            return logits, new_caches
+            return self._head(h, sel), new_caches
         h = self.model(input_ids, attn_mask,
                        apply_final_norm=sel is None,
                        exit_layer=exit_layer)
-        if sel is not None:
-            return self._fused_head(h, sel)
-        if self.lm_head is None:
-            return F.linear(h, self.model.embed_tokens.weight.t())
-        return self.lm_head(h)
+        return self._head(h, sel)
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,
                  temperature=1.0, top_k=0, top_p=1.0, eos_token_id=None,

@@ -35,6 +35,7 @@ requirement of the compiled pipeline's stacked-scan schedule).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
@@ -102,6 +103,17 @@ class LlamaDecoderLayerTP(nn.Layer):
         )
         k = self.k_proj(h).reshape([B, S, cfg.kv_heads, cfg.head_dim])
         v = self.v_proj(h).reshape([B, S, cfg.kv_heads, cfg.head_dim])
+        with jax.named_scope("attn_core"):  # as LlamaAttention's
+            a = self._attn_core(q, k, v, cos, sin)
+        x = x + self.o_proj(a.reshape([B, S, -1]))
+        h2 = self.post_attention_layernorm(x)
+        return x + self.down_proj(
+            IF.swiglu(self.gate_proj(h2), self.up_proj(h2))
+        )
+
+
+    def _attn_core(self, q, k, v, cos, sin):
+        cfg = self.cfg
         q, k, _ = IF.fused_rotary_position_embedding(
             q, k, None, sin=Tensor(sin), cos=Tensor(cos),
             rotary_emb_base=cfg.rope_theta,
@@ -119,21 +131,25 @@ class LlamaDecoderLayerTP(nn.Layer):
             # long-context policies: exact full attention over the
             # sep-sharded sequence via the KV rotation ring — per-device
             # score memory stays O((S/sep)^2) per hop
-            a = ring_flash_attention(q, k, v, causal=True,
-                                     axis=pol.sep_axis)
-        else:
-            a = F.scaled_dot_product_attention(
-                q, k, v, is_causal=True, training=self.training
-            )
-        x = x + self.o_proj(a.reshape([B, S, -1]))
-        h2 = self.post_attention_layernorm(x)
-        return x + self.down_proj(
-            IF.swiglu(self.gate_proj(h2), self.up_proj(h2))
+            return ring_flash_attention(q, k, v, causal=True,
+                                        axis=pol.sep_axis)
+        return F.scaled_dot_product_attention(
+            q, k, v, is_causal=True, training=self.training
         )
 
 
 class _FinalNorm(nn.RMSNorm):
     pass  # distinct type so the block-run detector keeps it in the suffix
+
+
+class _LMHead(ColumnParallelLinear):
+    """The vocab-parallel head. A pipeline layer is registered under
+    its index, so the head opens the scope ``lm_head`` itself, as
+    ``LlamaForCausalLM``'s does through its attribute name."""
+
+    def forward(self, x):
+        with jax.named_scope("lm_head"):
+            return super().forward(x)
 
 
 class LlamaForCausalLMPipe(LlamaFlopsMixin, PipelineLayer):
@@ -168,7 +184,7 @@ class LlamaForCausalLMPipe(LlamaFlopsMixin, PipelineLayer):
             + [
                 LayerDesc(_FinalNorm, config.hidden_size,
                           epsilon=config.rms_norm_eps),
-                LayerDesc(ColumnParallelLinear, config.hidden_size,
+                LayerDesc(_LMHead, config.hidden_size,
                           config.vocab_size, has_bias=False,
                           gather_output=False),
             ],

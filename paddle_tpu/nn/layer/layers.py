@@ -13,6 +13,7 @@ import collections
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ...core.dtypes import convert_dtype, get_default_dtype
@@ -57,6 +58,20 @@ class ParamAttr:
         raise TypeError(f"cannot convert {attr!r} to ParamAttr")
 
 
+class _SubLayers(collections.OrderedDict):
+    """A layer's ``_sub_layers``. Every way of registering a sublayer
+    (attribute, ``add_sublayer``, a container's index) ends in a write
+    here, which tells the sublayer the name it goes by:
+    ``Layer.__call__`` opens that name as a ``jax.named_scope``, so the
+    operations of a compiled program carry their module's path
+    (``model/layers/0/self_attn/q_proj``) into the profiler trace."""
+
+    def __setitem__(self, name, layer):
+        if isinstance(layer, Layer):
+            object.__setattr__(layer, "_scope_name", str(name))
+        super().__setitem__(name, layer)
+
+
 class Layer:
     _name_counters: dict = collections.defaultdict(int)
 
@@ -69,7 +84,7 @@ class Layer:
         object.__setattr__(self, "_parameters", collections.OrderedDict())
         object.__setattr__(self, "_buffers", collections.OrderedDict())
         object.__setattr__(self, "_non_persistable_buffer_names", set())
-        object.__setattr__(self, "_sub_layers", collections.OrderedDict())
+        object.__setattr__(self, "_sub_layers", _SubLayers())
         object.__setattr__(self, "training", True)
         object.__setattr__(self, "_forward_pre_hooks", collections.OrderedDict())
         object.__setattr__(self, "_forward_post_hooks", collections.OrderedDict())
@@ -428,7 +443,13 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        outputs = self.forward(*inputs, **kwargs)
+        scope = self.__dict__.get("_scope_name")
+        if scope is None:  # a root: registered under no name
+            outputs = self.forward(*inputs, **kwargs)
+        else:
+            # metadata of the traced operations only; eager it is inert
+            with jax.named_scope(scope):
+                outputs = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             result = hook(self, inputs, outputs)
             if result is not None:

@@ -105,14 +105,10 @@ def lint_event_counts():
 
 
 def record_span(name, dur, kind="user"):
-    """Inject an externally-timed span into the current RECORD window.
-
-    The hook ``paddle_tpu.serving.metrics`` exports through: every
-    serving histogram sample (TTFT, inter-token latency, ...) lands in
-    the same tables as RecordEvent spans, so ``Profiler.summary()`` and
-    the chrome trace show serving latencies alongside op timings. A
-    no-op (returns False) outside a RECORD window — serving keeps its
-    own counters regardless, so nothing accumulates unbounded here."""
+    """Inject an externally-timed span into the current RECORD window:
+    it lands in the same tables as RecordEvent spans (the trace guard's
+    recompile-storm marker comes this way). A no-op (returns False)
+    outside a RECORD window, so nothing accumulates unbounded here."""
     if not _RECORDING.is_set():
         return False
     with _LOCK:
@@ -124,17 +120,24 @@ def record_span(name, dur, kind="user"):
 
 
 class RecordEvent:
-    """Context manager/decorator span (paddle.profiler.RecordEvent parity)."""
+    """Context manager/decorator span (paddle.profiler.RecordEvent parity).
 
-    def __init__(self, name, event_type=None):
+    The span is a ``jax.profiler.TraceAnnotation``: while a jax profiler
+    trace is open it lands on the calling thread's line of the host
+    plane, on the same clock as the device planes. Keyword arguments
+    become the event's stats (``step=...``); keep what varies out of
+    the name."""
+
+    def __init__(self, name, event_type=None, **attrs):
         self.name = name
+        self._attrs = attrs
         self._ann = None
         self._t0 = None
 
     def begin(self):
         import jax
 
-        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self._attrs)
         self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self

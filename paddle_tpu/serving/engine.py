@@ -243,6 +243,10 @@ class ServingEngine:
         # the decode hot path checks this ONE integer and, when zero
         # (tracing off / sampled out), allocates no span machinery
         self._traced_live = 0
+        # engine.clock at the return of the last decode step's blocking
+        # read, None once the engine is idle: where the next
+        # metrics.host_gap sample starts
+        self._read_done = None
         self._closed = False
         # runtime lint guard: the whole engine design exists so that
         # admission/retirement NEVER recompile — if compile caches grow
@@ -546,6 +550,10 @@ class ServingEngine:
             # answer) — the exact chain turn N+1's prompt extends
             self.sessions.note_turn(sid, h.output_ids)
         self._seqs[slot] = None
+        if not self.active_slots:
+            # the engine goes idle: what passes until the next decode
+            # program is no host gap
+            self._read_done = None
         self._release_slot(slot)
         h._fire_terminal()
 
@@ -593,6 +601,7 @@ class ServingEngine:
         slot = self._slab.claim()
         assert slot is not None  # caller checked free_slots
         key = self._next_key()
+        t_pre = self.clock()
         psp = None if handle.trace is None else get_tracer().start_span(
             "engine.prefill", handle.trace, mode="local", bucket=bucket
         )
@@ -632,6 +641,7 @@ class ServingEngine:
         self.metrics.admitted.inc()
         self.metrics.prefill_tokens.inc(req.prompt_len)
         self.metrics.queue_wait.observe(wait, trace_id=tid)
+        self.metrics.prefill.observe(handle.first_token_time - t_pre)
         slo_ttft, slo_itl, slo_e2e = self.metrics.slo_children(
             req.slo_class
         )
@@ -673,23 +683,45 @@ class ServingEngine:
 
     def step(self):
         """One engine iteration: retire expired, admit into free slots,
-        run one decode step over the whole resident KV state."""
+        run one decode step over the whole resident KV state. Each
+        phase is a ``RecordEvent`` span with a fixed name (a phase's
+        own time is its span less the spans inside it)."""
         if self._closed:
             raise RuntimeError(f"{type(self).__name__} is closed")
-        # a staged weight swap applies the moment nothing is in flight
-        self._maybe_apply_reload()
-        now = self.clock()
-        # running sequences past their deadline free their slot NOW
-        for i, seq in enumerate(self._seqs):
-            if seq is None:
-                continue
-            dl = self.scheduler.deadline_of(seq.handle)
-            if dl is not None and now > dl:
-                self._finish(i, TIMEOUT, reason=REASON_TIMEOUT)
-        # queued requests whose deadline passed never run at all
-        self.scheduler.sweep_expired()
-        # admission: fill free capacity in priority-FIFO order under the
-        # in-flight token cap (and the per-step prefill cap, when set)
+        with profiler.RecordEvent("serving::step", step=self.step_count):
+            # a staged weight swap applies the moment nothing is in
+            # flight
+            self._maybe_apply_reload()
+            now = self.clock()
+            # running sequences past their deadline free their slot NOW
+            for i, seq in enumerate(self._seqs):
+                if seq is None:
+                    continue
+                dl = self.scheduler.deadline_of(seq.handle)
+                if dl is not None and now > dl:
+                    self._finish(i, TIMEOUT, reason=REASON_TIMEOUT)
+            # queued requests whose deadline passed never run at all
+            self.scheduler.sweep_expired()
+            with profiler.RecordEvent("serving::admit"):
+                self._admit()
+            self._decode_once()
+            with profiler.RecordEvent("serving::step_tail"):
+                # the last in-flight request may have finished this
+                # step — a pending swap must not wait for another
+                # external step() call
+                self._maybe_apply_reload()
+                self.step_count += 1
+                # poll jit-internal compile caches (decode shape drift
+                # is invisible to the bucket maps above); fires
+                # _on_guard_fire
+                self.trace_guard.check()
+                self.metrics.observe_step(self.scheduler.depth,
+                                          self.active_slots)
+
+    def _admit(self):
+        """Admission: fill free capacity in priority-FIFO order under
+        the in-flight token cap (and the per-step prefill cap, when
+        set)."""
         cap = self._max_admissions_per_step()
         admitted = 0
         # a pending reload pauses admission: in-flight requests drain
@@ -720,15 +752,6 @@ class ServingEngine:
         # mid-step while a prefill compiles)
         for _ in self.scheduler.drain_timed_out():
             self.metrics.timeouts.inc()
-        self._decode_once()
-        # the last in-flight request may have finished this step — a
-        # pending swap must not wait for another external step() call
-        self._maybe_apply_reload()
-        self.step_count += 1
-        # poll jit-internal compile caches (decode shape drift is
-        # invisible to the bucket maps above); fires _on_guard_fire
-        self.trace_guard.check()
-        self.metrics.observe_step(self.scheduler.depth, self.active_slots)
 
     def _decode_once(self):
         """One fused decode step over every row (free rows are masked
@@ -741,42 +764,58 @@ class ServingEngine:
             # per-token step (speculative.py)
             self.speculative.decode_once(self)
             return
-        tok = np.zeros((self.max_batch_size,), np.int32)
-        pos = np.zeros((self.max_batch_size,), np.int32)
-        keys = np.zeros((self.max_batch_size, 2), np.uint32)
-        for i in active:
-            tok[i] = self._seqs[i].last_tok
-            pos[i] = self._seqs[i].pos
-            keys[i] = self._seqs[i].key
-        t0 = self.clock()
-        with profiler.RecordEvent("serving::decode_step"):
+        with profiler.RecordEvent("serving::decode_inputs"):
+            tok = np.zeros((self.max_batch_size,), np.int32)
+            pos = np.zeros((self.max_batch_size,), np.int32)
+            keys = np.zeros((self.max_batch_size, 2), np.uint32)
+            for i in active:
+                tok[i] = self._seqs[i].last_tok
+                pos[i] = self._seqs[i].pos
+                keys[i] = self._seqs[i].key
+            t0 = self.clock()
+            inputs = (
+                jnp.asarray(tok), self._flat, *self._decode_extra(),
+                jnp.asarray(pos), jnp.float32(self.temperature),
+                jnp.asarray(keys),
+            )
+        if self._read_done is not None:
+            self.metrics.host_gap.observe(self.clock() - self._read_done)
+            self._read_done = None
+        with profiler.RecordEvent("serving::decode_step",
+                                  step=self.step_count):
             nxt, self._flat = self._run(
                 ("decode",), self._decode_fn,
-                self._params, self._buffers, jnp.asarray(tok),
-                self._flat, *self._decode_extra(), jnp.asarray(pos),
-                jnp.float32(self.temperature), jnp.asarray(keys),
+                self._params, self._buffers, *inputs,
             )
+            # the uploads die here, with the launch, as temporaries
+            # would: freeing a device array lets go of the GIL, and
+            # after emit that hands it to every woken stream thread
+            # while the front end's lock is still held
+            del inputs
             nxt = np.asarray(nxt)
-        dt = self.clock() - t0
-        if self._traced_live:
-            # ONE bounded-ring event per traced request per step (the
-            # O(1)-spans discipline: a 500-step decode stays one span);
-            # sampled-out runs never reach this branch — the single
-            # integer check above is the whole hot-path cost
-            occ = len(active)
+        self._read_done = self.clock()
+        dt = self._read_done - t0
+        with profiler.RecordEvent("serving::emit"):
+            if self._traced_live:
+                # ONE bounded-ring event per traced request per step
+                # (the O(1)-spans discipline: a 500-step decode stays
+                # one span); sampled-out runs never reach this branch —
+                # the single integer check above is the whole hot-path
+                # cost
+                occ = len(active)
+                for i in active:
+                    sp = self._seqs[i].handle._decode_span
+                    if sp is not None:
+                        sp.event("decode_step", step=self.step_count,
+                                 occupancy=occ, dt_s=dt)
             for i in active:
-                sp = self._seqs[i].handle._decode_span
-                if sp is not None:
-                    sp.event("decode_step", step=self.step_count,
-                             occupancy=occ, dt_s=dt)
-        for i in active:
-            seq = self._seqs[i]
-            if seq is None:
-                continue  # finished by an earlier row this step
-            # per-class child bound at admission: no label resolution
-            # (and no allocation) on this per-token path
-            (seq.slo_itl or self.metrics.itl).observe(dt)
-            self._append(i, nxt[i])
+                seq = self._seqs[i]
+                if seq is None:
+                    continue  # finished by an earlier row this step
+                # per-class child bound at admission: no label
+                # resolution (and no allocation) on this per-token path
+                (seq.slo_itl or self.metrics.itl).observe(dt)
+                self._append(i, nxt[i])
 
     def run_until_idle(self, max_steps=100_000):
         """Drive ``step()`` until queue and slab are empty."""

@@ -50,6 +50,7 @@ import queue
 import threading
 import time
 
+from .. import profiler
 from ..observability import get_registry
 from ..observability.exporter import prometheus_text
 from ..observability.tracing import (
@@ -216,7 +217,10 @@ class ServingFrontend:
         while not self._stop.is_set():
             busy = False
             errored = False
+            # what the driver waits here, the handler threads hold
+            waited = profiler.RecordEvent("frontend::lock_wait").begin()
             with self._lock:
+                waited.end()
                 if self._engine_busy() and not getattr(
                     self.engine, "_closed", False
                 ):
@@ -480,6 +484,12 @@ class ServingFrontend:
                     *submit_args, on_token=on_token, on_event=on_event,
                     **kwargs,
                 )
+                # received -> submit returned: the wait for the driver's
+                # lock. Into the ENGINE's metrics, so that it reaches
+                # engine.metrics.report() beside the engine's own phases
+                em = getattr(self.engine, "metrics", None)
+                if em is not None:
+                    em.submit_wait.observe(time.monotonic() - t_recv)
                 # under the SAME lock the driver steps with: the engine
                 # cannot admit this handle before its trace is attached
                 if not handle.finished:

@@ -1,4 +1,4 @@
-"""Serving metrics: registry-based counters + histograms with profiler export.
+"""Serving metrics: registry-based counters + histograms.
 
 The serving quantities users actually page on — queue depth,
 time-to-first-token, inter-token latency, slot occupancy, rejection and
@@ -9,11 +9,12 @@ telemetry PR these are thin subclasses of the process-wide
 registers its set under ``paddle_serving_*`` names in the global
 registry (replace-on-register — the newest engine's metrics own the
 series), so one Prometheus scrape covers serving alongside training
-and analysis telemetry. Every histogram sample is ALSO forwarded to
-``paddle_tpu.profiler.record_span`` under a ``serving::`` prefix, so
-when a ``profiler.Profiler`` RECORD window is open the serving
-latencies appear in ``Profiler.summary()`` and the chrome trace next to
-the op/user spans — one observability surface, not two.
+and analysis telemetry. What the serving loop was DOING at a moment is
+not here but in the ``profiler.RecordEvent`` phase spans of
+``serving/engine.py`` and ``http_frontend.py``, which a jax profiler
+trace holds on the device's clock; the three histograms ``host_gap``,
+``prefill`` and ``submit_wait`` carry the same phases' totals over a
+whole run, where a few seconds of trace hold too few requests.
 """
 from __future__ import annotations
 
@@ -51,7 +52,7 @@ class Counter(_reg.Counter):
 
 
 class Histogram(_reg.Histogram):
-    """Sample store with percentile readout + profiler span export.
+    """Sample store with percentile readout.
 
     Memory-bounded for long-running servers: the window keeps the most
     recent ``maxlen`` samples (sliding-window percentiles — what a
@@ -62,22 +63,13 @@ class Histogram(_reg.Histogram):
     ``snapshot()['window_count']`` tells dashboards how big that window
     population is (see the base class docstring for the full split)."""
 
-    def __init__(self, name, unit="s", export=True, maxlen=65536,
-                 prom_name=None, buckets=None, help=""):
+    def __init__(self, name, unit="s", maxlen=65536, prom_name=None,
+                 buckets=None, help=""):
         if buckets is None:
             buckets = (_reg.DEFAULT_BUCKETS if unit == "s"
                        else _reg.COUNT_BUCKETS)
         super().__init__(name, help=help, unit=unit, maxlen=maxlen,
                          buckets=buckets, prom_name=prom_name)
-        self._export = export
-
-    def observe(self, v, trace_id=None, labels_key=None):
-        super().observe(float(v), trace_id=trace_id,
-                        labels_key=labels_key)
-        if self._export:
-            from .. import profiler
-
-            profiler.record_span(f"serving::{self.name}", float(v))
 
 
 class ServingMetrics:
@@ -144,13 +136,28 @@ class ServingMetrics:
             "queue_wait", prom_name=f"{ns}_queue_wait_seconds",
             help="queue wait before admission")
         self.queue_depth = Histogram(
-            "queue_depth", unit="reqs", export=False,
+            "queue_depth", unit="reqs",
             prom_name=f"{ns}_queue_depth",
             help="scheduler queue depth sampled per engine step")
         self.slot_occupancy = Histogram(
-            "slot_occupancy", unit="slots", export=False,
+            "slot_occupancy", unit="slots",
             prom_name=f"{ns}_slot_occupancy",
             help="active decode-slab slots sampled per engine step")
+        # the host loop's phases as window-wide totals; the same phases
+        # are RecordEvent spans on the profiler trace's clock
+        self.host_gap = Histogram(
+            "host_gap", prom_name=f"{ns}_host_gap_seconds",
+            help="driver thread, per decode step: return of the last "
+                 "step's blocking read to the launch of this step's "
+                 "decode program (an idle engine starts no sample)")
+        self.prefill = Histogram(
+            "prefill", prom_name=f"{ns}_prefill_seconds",
+            help="per admission: gather, prefill, the blocking "
+                 "first-token read and the adopt")
+        self.submit_wait = Histogram(
+            "submit_wait", prom_name=f"{ns}_submit_wait_seconds",
+            help="per front-end request: received to engine.submit "
+                 "returned (the wait for the driver's lock)")
         # speculative decoding (serving.speculative): one round = one
         # draft proposal pass + one target verify launch
         self.spec_rounds = Counter(
@@ -166,7 +173,7 @@ class ServingMetrics:
             prom_name=f"{ns}_speculative_accepted_tokens_total",
             help="draft tokens the verifier accepted")
         self.spec_accept_length = Histogram(
-            "speculative_accept_length", unit="toks", export=False,
+            "speculative_accept_length", unit="toks",
             prom_name=f"{ns}_speculative_accept_length",
             help="tokens emitted per speculative round (accepted "
                  "prefix + the correction/bonus token; mean > 1 is "
@@ -183,6 +190,7 @@ class ServingMetrics:
             self.guard_fires, self.reloads, self.reload_ttft_spike,
             self.ttft, self.itl, self.e2e,
             self.queue_wait, self.queue_depth, self.slot_occupancy,
+            self.host_gap, self.prefill, self.submit_wait,
             self.spec_rounds, self.spec_proposed, self.spec_accepted,
             self.spec_accept_length,
         ])
@@ -243,6 +251,9 @@ class ServingMetrics:
             "queue_wait": self.queue_wait.snapshot(),
             "queue_depth": self.queue_depth.snapshot(),
             "slot_occupancy": self.slot_occupancy.snapshot(),
+            "host_gap": self.host_gap.snapshot(),
+            "prefill": self.prefill.snapshot(),
+            "submit_wait": self.submit_wait.snapshot(),
         }
 
     def render(self):
@@ -251,8 +262,9 @@ class ServingMetrics:
         lines = ["serving metrics", "-" * 15]
         for k, v in r["counters"].items():
             lines.append(f"{k:>20}: {v}")
-        for name in ("ttft", "itl", "e2e", "queue_wait",
-                     "queue_depth", "slot_occupancy"):
+        for name in ("ttft", "itl", "e2e", "queue_wait", "submit_wait",
+                     "prefill", "host_gap", "queue_depth",
+                     "slot_occupancy"):
             s = r[name]
             if not s.get("count"):
                 lines.append(f"{name:>20}: (no samples)")

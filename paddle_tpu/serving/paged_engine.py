@@ -802,6 +802,7 @@ class PagedServingEngine(ServingEngine):
         # the per-admission prefill span: mode (remote|local|fallback|
         # chunk) plus the prefix-hit/chunk-plan attributes the warm
         # path decided on — None (zero allocations) when sampled out
+        t_pre = self.clock()
         psp = None if handle.trace is None else get_tracer().start_span(
             "engine.prefill", handle.trace, bucket=bucket,
             prefix_hit=match is not None,
@@ -947,6 +948,7 @@ class PagedServingEngine(ServingEngine):
         self.metrics.admitted.inc()
         self.metrics.prefill_tokens.inc(req.prompt_len)
         self.metrics.queue_wait.observe(wait, trace_id=tid)
+        self.metrics.prefill.observe(handle.first_token_time - t_pre)
         slo_ttft, slo_itl, slo_e2e = self.metrics.slo_children(
             req.slo_class
         )
@@ -1052,7 +1054,8 @@ class PagedServingEngine(ServingEngine):
 
     def _decode_once(self):
         if self._demand_paging:
-            self._grow_pages()
+            with profiler.RecordEvent("serving::grow_pages"):
+                self._grow_pages()
         super()._decode_once()
 
     def close(self):

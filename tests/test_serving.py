@@ -222,7 +222,7 @@ def test_kv_pool_fp32_override(net):
 
 
 # --------------------------------------------------------------- metrics
-def test_metrics_percentiles_and_profiler_export():
+def test_metrics_percentiles_and_profiler_export(net):
     m = ServingMetrics()
     for v in (0.1, 0.2, 0.3, 0.4):
         m.ttft.observe(v)
@@ -232,17 +232,24 @@ def test_metrics_percentiles_and_profiler_export():
     assert m.ttft.snapshot()["p50"] in (0.2, 0.3)
     assert "ttft" in m.render()
 
-    # inside a profiler RECORD window, serving samples land in the
-    # summary tables (the record_span export seam)
+    # a histogram sample is no profiler span; what a RECORD window
+    # holds of the serving loop is its phases, each a RecordEvent
     from paddle_tpu import profiler
 
+    eng = ServingEngine(net, max_batch_size=2, max_seq_len=64,
+                        min_bucket=8)
     prof = profiler.Profiler(timer_only=True)
     prof.start()
-    m2 = ServingMetrics()
-    m2.itl.observe(0.005)
+    m.itl.observe(0.005)
+    eng.generate([RNG.randint(0, 64, (1, 6))], max_new_tokens=3)
     summary = prof.summary()
     prof.stop()
-    assert "serving::itl" in summary
+    eng.close()
+    assert "serving::itl" not in summary
+    for phase in ("serving::step", "serving::admit", "serving::prefill_b8",
+                  "serving::decode_inputs", "serving::decode_step",
+                  "serving::emit", "serving::step_tail"):
+        assert phase in summary, phase
 
 
 # ------------------------------------------------- saved-artifact serving
@@ -369,7 +376,7 @@ def test_scheduler_lazy_pop_expiry_reaches_drain():
 def test_histogram_window_bounded_running_totals():
     from paddle_tpu.serving import Histogram
 
-    hist = Histogram("x", export=False, maxlen=8)
+    hist = Histogram("x", maxlen=8)
     for i in range(20):
         hist.observe(float(i))
     assert hist.count == 20            # running total: every sample
