@@ -35,9 +35,11 @@ the page relaid head-major first. Only the full-row score scratch
   (the PR 6 parity discipline; the kernel is also invariant in its
   ``block_kvh`` knob).
 - :func:`paged_attention_composed` is the gather+SDPA formulation the
-  serving engine's DEFAULT paged path runs (op order of ``_sdpa_ref``,
-  which the slab engine also decodes through — that identity is what
-  keeps default paged token streams exact-equal to ``net.generate``).
+  serving engine's DEFAULT paged path runs (``_sdpa_ref``, or under
+  GQA its grouped form ``_sdpa_grouped_ref`` that reads each KV head
+  once; the slab engine decodes through the same two — that identity
+  is what keeps default paged token streams exact-equal to
+  ``net.generate``).
   Kernel vs composed agree to float rounding (a different reduction
   order; the parity test bounds it at fp32 epsilon), which is why
   kernel activation stays a measured, opt-in decision rather than a
@@ -98,36 +100,30 @@ def _softmax_rows(s, axis=-1):
 
 def paged_attention_composed(q, k_pages, v_pages, page_table, pos,
                              scale=None):
-    """Composed reference: gather the paged cache and attend — the same
-    op order ``nn.functional.scaled_dot_product_attention``'s composed
-    body (``_sdpa_ref``) runs for the slab engine, so the paged engine's
-    default path and the slab engine round identically.
+    """Composed reference: gather the paged cache and attend through the
+    very bodies the engine's default paths run
+    (``nn.functional.attention``: ``_sdpa_ref`` under MHA,
+    ``_sdpa_grouped_ref`` — the grouped contraction over the
+    un-repeated KV heads — under GQA), so the paged engine's default
+    path and the slab engine round identically.
 
     q ``[B, 1, H, D]``; returns ``[B, 1, H, D]`` in q's dtype.
     Quantized (int8) arenas dequantize-on-gather to q's dtype first —
     the op order the engine's default int8 paged path runs."""
-    b, sq, h, d = (int(x) for x in q.shape)
-    kvh = int(k_pages.shape[2])
+    from ..nn.functional.attention import _sdpa_grouped_ref, _sdpa_ref
+
+    h, d = int(q.shape[2]), int(q.shape[3])
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     kk = gather_pages_dense(k_pages, page_table, q.dtype)
     vv = gather_pages_dense(v_pages, page_table, q.dtype)
-    if kvh != h:
-        rep = h // kvh
-        kk = jnp.repeat(kk, rep, axis=2)
-        vv = jnp.repeat(vv, rep, axis=2)
-    s_virt = int(kk.shape[1])
-    # [B, H, sq, S_virt] score + position mask, then _sdpa_ref's order
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(kk, 1, 2)
-    vt = jnp.swapaxes(vv, 1, 2)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * scale
-    valid = jnp.arange(s_virt)[None, None, None, :] \
+    valid = jnp.arange(int(kk.shape[1]))[None, None, None, :] \
         <= pos[:, None, None, None]
-    s = s + jnp.where(valid, 0.0, -jnp.inf)
-    p = _softmax_rows(s.astype(jnp.float32)).astype(q.dtype)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, vt)
-    return jnp.swapaxes(out, 1, 2)
+    mask = jnp.where(valid, 0.0, -jnp.inf)
+    if int(kk.shape[2]) != h:
+        return _sdpa_grouped_ref(q, kk, vv, mask, scale=scale)
+    return _sdpa_ref(q, kk, vv, mask, causal=False, scale=scale,
+                     dropout_p=0.0, key=None)
 
 
 def _page_scores(qg, k, scale):

@@ -8,7 +8,9 @@ of core ops — unverified, mount empty). TPU-first design:
 - rotary embeddings -> fused Pallas rope (kernels/rope.py) via
   incubate.nn.functional.fused_rotary_position_embedding
 - causal attention -> flash attention (kernels/flash_attention.py) through
-  F.scaled_dot_product_attention, with grouped-query attention (GQA)
+  F.scaled_dot_product_attention, with grouped-query attention (GQA);
+  over a KV cache the GQA contraction is grouped, the cache read once
+  per KV head (no repeated copy of K and V)
 - SwiGLU MLP -> incubate.nn.functional.swiglu (one split gemm)
 - everything shape-static and bf16-friendly so the whole step compiles
   onto the MXU as a handful of fused loops.
@@ -24,6 +26,7 @@ import jax.numpy as jnp
 from ..core.tensor import Tensor
 from .. import nn
 from ..nn import functional as F
+from ..nn.functional.attention import grouped_query_cache_attention
 from ..incubate.nn import functional as IF
 
 
@@ -96,6 +99,29 @@ def causal_lm_loss(logits, labels, ignore_index=-100):
     )
 
 
+def _cache_attention(q, kk, vv, mask, attn_mask):
+    """The attention every cache branch ends in: ``q`` ``[B, S, H, D]``
+    over the dense cache view ``kk``/``vv`` ``[B, S_k, kvH, D]`` under
+    the additive positional ``mask`` ``[B or 1, 1, S, S_k]``, combined
+    with a user's ``attn_mask`` (e.g. left-padded prompts; it must
+    broadcast over ``[B, H, S, S_k]``). Under GQA the contraction is
+    grouped: each KV head is read once, never copied ``rep`` times; MHA
+    takes ``F.scaled_dot_product_attention`` as it always did. Paged,
+    slab-scalar and slab-per-row all come here because their token
+    streams are pinned equal (paged == slab == ``generate``) and a
+    grouped contraction is not bitwise the repeated one."""
+    if attn_mask is not None:
+        am = (attn_mask.value if hasattr(attn_mask, "value")
+              else jnp.asarray(attn_mask))
+        mask = mask + am
+    if kk.shape[2] == q.shape[2]:
+        return F.scaled_dot_product_attention(
+            q, kk, vv, attn_mask=Tensor(mask), is_causal=False,
+            training=False,
+        )
+    return grouped_query_cache_attention(q, kk, vv, Tensor(mask))
+
+
 class LlamaAttention(nn.Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -122,7 +148,12 @@ class LlamaAttention(nn.Layer):
         attention runs over the table-gathered logical cache (S must be
         1 — the paged decode step). Page id 0 is the reserved garbage
         page; a tuned Pallas paged-attention kernel replaces the
-        HBM-materializing gather when the tune cache selects one."""
+        HBM-materializing gather when the tune cache selects one.
+
+        Under GQA every cache path contracts the query heads, grouped
+        by their KV head, against the cache as it is stored
+        (``_cache_attention``): K and V are never repeated to ``H``
+        heads."""
         cfg = self.cfg
         B, S = int(x.shape[0]), int(x.shape[1])
         if page_table is not None and cache is None:
@@ -142,9 +173,10 @@ class LlamaAttention(nn.Layer):
 
     def _attn_core(self, q, k, v, rope_cos, rope_sin, attn_mask, cache,
                    pos, page_table):
-        """Rope, cache write, page gather, GQA repeat and the attention
-        itself (composed SDPA or a selected kernel): ``(out [B, S, H,
-        D], new_cache)``, ``new_cache`` None without a cache."""
+        """Rope, cache write, page gather and the attention itself
+        (with a cache: ``_cache_attention``, grouped under GQA, or a
+        selected kernel; without: GQA repeat + SDPA/flash): ``(out [B,
+        S, H, D], new_cache)``, ``new_cache`` None without a cache."""
         cfg = self.cfg
         B, S = int(q.shape[0]), int(q.shape[1])
         if (cache is None and attn_mask is None
@@ -225,8 +257,8 @@ class LlamaAttention(nn.Layer):
                     q, k_pages, v_pages, tbl, p, config=sel
                 )
                 return out, (k_pages, v_pages)
-            # default: composed gather + the SAME masked-SDPA the slab
-            # per-row branch below decodes through — token streams stay
+            # default: composed gather + the SAME _cache_attention the
+            # slab branches below decode through — token streams stay
             # bit-identical to the slab engine and net.generate (extra
             # masked columns contribute exact zeros; int8 arenas
             # dequant-on-gather to the compute dtype)
@@ -234,21 +266,10 @@ class LlamaAttention(nn.Layer):
             kk = Tensor(gather_pages_dense(k_pages, tbl, q.value.dtype))
             vv = Tensor(gather_pages_dense(v_pages, tbl, q.value.dtype))
             S_virt = P * ps
-            if cfg.kv_heads != cfg.num_attention_heads:
-                rep = cfg.num_attention_heads // cfg.kv_heads
-                kk = kk.repeat_interleave(rep, axis=2)
-                vv = vv.repeat_interleave(rep, axis=2)
             cols = p[:, None] + jnp.arange(S)[None, :]
             valid = jnp.arange(S_virt)[None, None, :] <= cols[:, :, None]
             mask = jnp.where(valid, 0.0, -jnp.inf)[:, None, :, :]
-            if attn_mask is not None:
-                am = (attn_mask.value if hasattr(attn_mask, "value")
-                      else jnp.asarray(attn_mask))
-                mask = mask + am
-            out = F.scaled_dot_product_attention(
-                q, kk, vv, attn_mask=Tensor(mask), is_causal=False,
-                training=False,
-            )
+            out = _cache_attention(q, kk, vv, mask, attn_mask)
             return out, (k_pages, v_pages)
         if cache is not None:
             from ..quantization import kv as qkv
@@ -280,22 +301,7 @@ class LlamaAttention(nn.Layer):
             # caches pass through untouched (SDPA upcasts at the matmul)
             kk = Tensor(qkv.read_dense(k_cache, q.value.dtype))
             vv = Tensor(qkv.read_dense(v_cache, q.value.dtype))
-            if cfg.kv_heads != cfg.num_attention_heads:
-                rep = cfg.num_attention_heads // cfg.kv_heads
-                kk = kk.repeat_interleave(rep, axis=2)
-                vv = vv.repeat_interleave(rep, axis=2)
-            if attn_mask is not None:
-                # combine with a user mask (e.g. left-padded prompts);
-                # must broadcast over [B, H, S, S_max] in cache mode
-                am = (
-                    attn_mask.value if hasattr(attn_mask, "value")
-                    else jnp.asarray(attn_mask)
-                )
-                mask = mask + am
-            out = F.scaled_dot_product_attention(
-                q, kk, vv, attn_mask=Tensor(mask), is_causal=False,
-                training=False,
-            )
+            out = _cache_attention(q, kk, vv, mask, attn_mask)
             return out, (k_cache, v_cache)
         if cfg.kv_heads != cfg.num_attention_heads:
             rep = cfg.num_attention_heads // cfg.kv_heads

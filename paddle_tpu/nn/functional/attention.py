@@ -41,6 +41,31 @@ def _sdpa_ref(q, k, v, mask, *, causal, scale, dropout_p, key):
     return jnp.swapaxes(out, 1, 2)
 
 
+def _sdpa_grouped_ref(q, k, v, mask, *, scale):
+    """``_sdpa_ref`` for grouped-query attention over a cache, K and V
+    NOT repeated: q ``[B, S, H, D]`` against k/v ``[B, S_k, kvH, D]``,
+    ``H = kvH * rep``, query head ``h = g * rep + r`` attending KV head
+    ``g`` (the order ``repeat_interleave(rep, axis=2)`` gives). ``mask``
+    is additive and broadcasts over ``[B, H, S, S_k]``. Same op order
+    (scale after the score contraction, mask added, fp32 softmax, cast,
+    value contraction); each KV head is read once, not ``rep`` times."""
+    b, sq, h, d = q.shape
+    kvh = k.shape[2]
+    rep = h // kvh
+    qg = q.reshape(b, sq, kvh, rep, d)
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k) * scale
+    if mask is not None:
+        mask = mask.reshape((1,) * (4 - mask.ndim) + mask.shape)
+        if mask.shape[1] == 1:
+            mask = mask[:, :, None]
+        else:  # a per-head mask: split its head axis as q's was
+            mask = mask.reshape(mask.shape[0], kvh, rep, *mask.shape[2:])
+        s = s + mask
+    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", p, v)
+    return out.reshape(b, sq, h, d)
+
+
 def _use_pallas(q):
     """Pallas flash attention on real TPU; composed jnp elsewhere (CPU CI)."""
     try:
@@ -77,6 +102,25 @@ def scaled_dot_product_attention(
         return _sdpa_ref(
             qv, kv, vv, mv, causal=is_causal, scale=scale, dropout_p=dp, key=rng
         )
+
+    return dispatch.apply(
+        "scaled_dot_product_attention",
+        _sdpa,
+        (query, key, value, attn_mask),
+        cache=False,
+    )
+
+
+def grouped_query_cache_attention(query, key, value, attn_mask):
+    """Inference attention of ``query`` ``[B, S, H, D]`` over a cache
+    view ``key``/``value`` ``[B, S_k, kvH, D]`` with FEWER heads than
+    the query (GQA), under an additive ``attn_mask`` — the composed body
+    of :func:`scaled_dot_product_attention` (same op name for AMP, same
+    op order) without first repeating K and V to ``H`` heads."""
+    scale = 1.0 / math.sqrt(query.shape[-1])
+
+    def _sdpa(qv, kv, vv, mv):
+        return _sdpa_grouped_ref(qv, kv, vv, mv, scale=scale)
 
     return dispatch.apply(
         "scaled_dot_product_attention",

@@ -50,6 +50,21 @@ def net():
     return m
 
 
+@pytest.fixture(scope="module")
+def gqa_net():
+    """Two KV heads under four query heads, as both benchmark models
+    group theirs (8 under 32): the cache paths' grouped contraction."""
+    paddle.seed(6)
+    cfg = LlamaConfig.tiny(
+        vocab_size=64, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=2, num_attention_heads=4,
+        num_key_value_heads=2,
+    )
+    m = LlamaForCausalLM(cfg)
+    m.eval()
+    return m
+
+
 # ------------------------------------------------------------ the big one
 def test_continuous_batching_exact_vs_generate(net):
     """2 slots, 4 staggered requests: late requests ride slots freed by
@@ -437,13 +452,17 @@ def test_paged_pool_claim_release_accounting(net):
 
 
 # ---------------------------------------------------------- paged engine
-def test_paged_engine_exact_vs_slab_and_generate(net):
+@pytest.mark.parametrize("which", ["net", "gqa_net"])
+def test_paged_engine_exact_vs_slab_and_generate(which, request):
     """The tentpole pin: paged continuous batching (2 rows, 4 staggered
     requests, pages claimed per-length) produces token streams
     exact-equal to BOTH the slab engine and standalone net.generate —
-    on the CPU 8-device virtual mesh, like every serving test."""
+    on the CPU 8-device virtual mesh, like every serving test. Under
+    MHA and under GQA, whose three cache branches share one grouped
+    contraction."""
     import jax
 
+    net = request.getfixturevalue(which)
     assert jax.device_count() == 8  # the virtual mesh conftest forces
     prompts = [RNG.randint(0, 64, (1, L)) for L in (6, 5, 7, 9)]
     max_news = [3, 9, 6, 8]

@@ -277,6 +277,37 @@ def test_paged_decode_step_compiles_at_depth_1(chip_compile, topo,
     engine.close()
 
 
+def test_gqa_paged_decode_step_never_repeats_the_cache():
+    """On the CPU lowering, no chip described: the paged decode step of
+    a GQA model holds no broadcast as large as the table-gathered cache
+    times ``rep`` (what ``repeat_interleave`` to ``H`` heads lowers to:
+    ``broadcast_in_dim`` to ``[B, S_virt, kvH, rep, D]``). That copy
+    was 34-38 ms of an 80 ms decode program on the chip (PERF.md, PR
+    27); the grouped contraction reads each KV head once."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import PagedServingEngine
+
+    cfg = paddle.models.LlamaConfig.tiny(num_key_value_heads=2)
+    net = paddle.models.LlamaForCausalLM(cfg)
+    net.eval()
+    rows, s_max = 4, 64
+    engine = PagedServingEngine(net, max_batch_size=rows, max_seq_len=s_max,
+                                page_size=8, min_bucket=16)
+    text = engine._decode_fn.lower(*engine._decode_example_args()).as_text()
+    engine.close()
+    rep = cfg.num_attention_heads // cfg.kv_heads
+    gathered = rows * s_max * cfg.kv_heads * cfg.head_dim
+    shapes = re.findall(r"broadcast(?:_in_dim)?\b[^\n]*->\s*tensor<([0-9x]+)x",
+                        text)
+    assert shapes, "no broadcast found: the lowering's text has changed"
+    sizes = [int(np.prod([int(d) for d in sh.split("x")])) for sh in shapes]
+    assert max(sizes) < gathered * rep, (
+        f"a broadcast of {max(sizes)} elements: the gathered cache "
+        f"({gathered}) repeated to the query heads is back")
+
+
 def test_kernels_give_way_under_a_mesh_loudly(monkeypatch):
     """GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot
     be automatically partitioned"), so with a multi-device mesh
