@@ -25,3 +25,4 @@ from .llama_pipe import (  # noqa: F401
     LlamaDecoderLayerTP,
     LlamaForCausalLMPipe,
 )
+from .xing4 import Xing4Config, Xing4ForCausalLM  # noqa: F401

@@ -15,6 +15,7 @@ single fused device step. Finished sequences (EOS seen) keep emitting
 from __future__ import annotations
 
 import itertools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -102,8 +103,55 @@ def normalize_cache_dtype(cache_dtype):
 _NET_GUARD_IDS = itertools.count()
 
 
+def cache_layout(cfg):
+    """What one cached token is made of, layer by layer: for each layer
+    a tuple with the TRAILING shape of every cache array it keeps (a
+    cache array is ``[B, S_max, *trailing]``, a page arena
+    ``[pages, page_size, *trailing]``). A config that keeps anything
+    but a K and a V of ``(kvH, D)`` a layer states it
+    (``cfg.cache_layout()``: a latent-attention net ONE array of
+    ``(latent + rope dims,)`` shared by all heads); one that states
+    nothing (Llama, every decoder before it) keeps the pair, and this
+    is where the pair is written down."""
+    stated = getattr(cfg, "cache_layout", None)
+    if stated is None:
+        pair = ((int(cfg.kv_heads), int(cfg.head_dim)),) * 2
+        return [pair] * int(cfg.num_hidden_layers)
+    return [tuple(tuple(int(d) for d in a) for a in layer)
+            for layer in stated()]
+
+
+def keeps_kv_pairs(cfg):
+    """True where every layer's cache is a K and a V of ``(kvH, D)``:
+    the layout int8 storage, the prefix cache, tiering and speculation
+    are written for."""
+    return all(len(layer) == 2 and len(layer[0]) == 2
+               and layer[0] == layer[1] for layer in cache_layout(cfg))
+
+
+def cache_token_bytes(cfg, cache_dtype):
+    """Bytes ONE cached token costs over every layer's arrays (int8
+    counts its per-token fp32 scales)."""
+    from ..quantization.kv import kv_token_bytes
+
+    return sum(kv_token_bytes(math.prod(a[:-1]), a[-1], cache_dtype)
+               for layer in cache_layout(cfg) for a in layer)
+
+
+def unflatten_caches(flat, cfg):
+    """The per-layer tuples of a flat list of cache arrays (the
+    inverse of ``[a for layer in caches for a in layer]``)."""
+    out, i = [], 0
+    for layer in cache_layout(cfg):
+        out.append(tuple(flat[i:i + len(layer)]))
+        i += len(layer)
+    return out
+
+
 def alloc_kv_caches(cfg, B, S_max, cache_dtype=None):
-    """Per-layer static KV buffers [B, S_max, kvH, D] x num_layers.
+    """Per-layer static cache buffers ``[B, S_max, *trailing]``, one
+    for each array ``cache_layout(cfg)`` states (Llama: K and V,
+    ``[B, S_max, kvH, D]`` x2 a layer).
 
     ONE place owns the serving cache layout and dtype: the whole-decode
     programs here, the serving engine's slot slab, and the bucketed
@@ -113,20 +161,25 @@ def alloc_kv_caches(cfg, B, S_max, cache_dtype=None):
     quantized storage (int8 values + per-token fp32 scales as one
     :class:`~..quantization.kv.QuantizedKV` pytree per array — halves
     resident bytes again; the write paths quantize, the reads
-    dequantize)."""
+    dequantize); it exists for K/V pairs only."""
     name = normalize_cache_dtype(cache_dtype)
-    shape = (B, S_max, cfg.kv_heads, cfg.head_dim)
+    layout = cache_layout(cfg)
     if name == "int8":
+        if not keeps_kv_pairs(cfg):
+            raise ValueError(
+                "int8 cache storage quantizes K and V per head; "
+                f"{type(cfg).__name__} keeps another cache layout"
+            )
         from ..quantization.kv import alloc_quantized
 
         return [
-            (alloc_quantized(shape), alloc_quantized(shape))
-            for _ in range(cfg.num_hidden_layers)
+            tuple(alloc_quantized((B, S_max) + a) for a in layer)
+            for layer in layout
         ]
     dtype = jnp.dtype(name)
     return [
-        (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-        for _ in range(cfg.num_hidden_layers)
+        tuple(jnp.zeros((B, S_max) + a, dtype) for a in layer)
+        for layer in layout
     ]
 
 
@@ -144,11 +197,22 @@ def prefill(net, ids, caches, length=None, pos=0):
     serving prefix cache uses to recompute only the uncached tail of a
     prompt (tier-1-pinned bitwise-equal to the full-prompt prefill).
     Returns (next-token logits [B, V], caches)."""
+    # a net that says so (``head_takes_row``) runs its head on the one
+    # row wanted. Kept for the FIT alone: a 4096 x 131072 block of
+    # logits is 1.07 GB beside a model that fills the chip. Every
+    # causal LM should get the one-row head and this attribute go
+    # (ROADMAP A9, a perf_opt PR of its own with the Llama cells
+    # measured)
+    one_row = length is not None and getattr(net, "head_takes_row", False)
+    kw = {"head_row": jnp.asarray(length, jnp.int32) - 1} if one_row else {}
     with tape.trace_scope(), tape.no_grad():
         logits, caches = net(
-            Tensor(ids), caches=caches, pos=jnp.asarray(pos, jnp.int32)
+            Tensor(ids), caches=caches, pos=jnp.asarray(pos, jnp.int32),
+            **kw
         )
     lv = logits.value
+    if one_row:
+        return lv[:, 0, :], caches
     if length is None:
         return lv[:, -1, :], caches
     row = jax.lax.dynamic_index_in_dim(
@@ -167,8 +231,9 @@ def decode_step(net, tok, caches, pos, page_table=None):
     paged serving engine's step. Cache-dtype-aware: writes cast to the
     cache's dtype, reads upcast at the matmul. Returns
     (logits [B, V], caches)."""
-    # only forward the kwarg when paging: other causal LMs served
-    # through generate() (gpt_moe etc.) don't take page_table
+    # the kwarg is forwarded only when paging: the slab and the
+    # whole-decode paths call a net's cache seam without it, so a net
+    # with no paged path of its own still decodes through them
     kw = {} if page_table is None else {"page_table": page_table}
     with tape.trace_scope(), tape.no_grad():
         logits, caches = net(Tensor(tok), caches=caches, pos=pos, **kw)
@@ -207,10 +272,7 @@ def _decode_ids(net, ids, max_new, do_sample, top_k, top_p, has_eos,
 
     def step(carry, _):
         tok, pos, flat, finished, key = carry
-        caches = [
-            (flat[2 * i], flat[2 * i + 1])
-            for i in range(cfg.num_hidden_layers)
-        ]
+        caches = unflatten_caches(flat, cfg)
         logits, caches = decode_step(net, tok[:, None], caches, pos)
         if do_sample:
             key, sub = jax.random.split(key)
@@ -276,10 +338,7 @@ def _beam_decode_ids(net, ids, max_new, num_beams, has_eos, eos_id,
         tok = jax.lax.dynamic_slice_in_dim(
             beam_toks, col, 1, axis=2
         )[..., 0].reshape(B * k)
-        caches = [
-            (flat[2 * i], flat[2 * i + 1])
-            for i in range(cfg.num_hidden_layers)
-        ]
+        caches = unflatten_caches(flat, cfg)
         logits, caches = decode_step(net, tok[:, None], caches, pos)
         lp = jax.nn.log_softmax(
             logits.astype(jnp.float32), axis=-1

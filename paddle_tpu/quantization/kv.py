@@ -155,7 +155,7 @@ def write_at_pos(cache, val, pos):
             jax.lax.dynamic_update_slice(cache.scale, s, (z, pos, z)),
         )
     return jax.lax.dynamic_update_slice(
-        cache, val.astype(cache.dtype), (z, pos, z, z)
+        cache, val.astype(cache.dtype), (z, pos) + (z,) * (cache.ndim - 2)
     )
 
 
@@ -232,7 +232,7 @@ def adopt_into_slab(dst, blk, slot):
                                          (slot, z, z)),
         )
     return jax.lax.dynamic_update_slice(
-        dst, blk.astype(dst.dtype), (slot, z, z, z)
+        dst, blk.astype(dst.dtype), (slot,) + (z,) * (dst.ndim - 1)
     )
 
 
@@ -251,7 +251,7 @@ def gather_block_from_pages(arena, page_ids, n_pages, page_size):
             arena.scale[page_ids].reshape(1, n_pages * page_size, kvh),
         )
     return arena[page_ids].reshape(
-        1, n_pages * page_size, arena.shape[2], arena.shape[3]
+        (1, n_pages * page_size) + arena.shape[2:]
     )
 
 
@@ -270,8 +270,7 @@ def adopt_into_pages(arena, blk, page_ids, n_pages, page_size):
                 blk.scale[0].reshape(n_pages, page_size, kvh)
             ),
         )
-    b = blk
     return arena.at[page_ids].set(
-        b[0].reshape(n_pages, page_size, b.shape[2],
-                     b.shape[3]).astype(arena.dtype)
+        blk[0].reshape((n_pages, page_size) + blk.shape[2:])
+        .astype(arena.dtype)
     )

@@ -40,8 +40,11 @@ from ..models.generation import (
     DEFAULT_CACHE_DTYPE,
     _select_next,
     alloc_kv_caches,
+    cache_layout,
     decode_step,
+    keeps_kv_pairs,
     prefill,
+    unflatten_caches,
 )
 from ..observability.tracing import get_tracer
 from .kv_pool import KVCachePool
@@ -68,8 +71,20 @@ def _flatten(caches):
     return [a for kv in caches for a in kv]
 
 
-def _unflatten(flat):
-    return [(flat[2 * i], flat[2 * i + 1]) for i in range(len(flat) // 2)]
+# per-layer tuples of a flat list of cache arrays, as many a layer as
+# the config's cache statement names: _unflatten(flat, cfg)
+_unflatten = unflatten_caches
+
+
+def step_counters(net):
+    """What ``net`` counted in the forward just traced: small arrays by
+    name, which a decode program returns beside the next tokens
+    (``ServingMetrics.observe_step_counters``) and a prefill program
+    drops, so that no tracer outlives its trace on the net. Most nets
+    count nothing, and the empty dict adds no output to their
+    program."""
+    pop = getattr(net, "pop_step_counters", None)
+    return pop() if pop is not None else {}
 
 
 def build_prefill_body(net, do_sample, top_k, top_p):
@@ -83,8 +98,9 @@ def build_prefill_body(net, do_sample, top_k, top_p):
         net.load_functional_state(params, buffers)
         net.eval()
         logits, caches = prefill(
-            net, ids, _unflatten(flat_block), length=length
+            net, ids, _unflatten(flat_block, net.config), length=length
         )
+        step_counters(net)      # a prefill's counts are not a step's
         if do_sample:
             # position-addressed randomness (sampling_keys): the first
             # sampled token lands at cache position `length`
@@ -109,8 +125,9 @@ def build_chunk_prefill_body(net, do_sample, top_k, top_p):
         net.load_functional_state(params, buffers)
         net.eval()
         logits, caches = prefill(
-            net, ids, _unflatten(flat_block), length=length, pos=pos
+            net, ids, _unflatten(flat_block, net.config), length=length, pos=pos
         )
+        step_counters(net)
         if do_sample:
             # same address as the cold path: the sampled token's cache
             # position is pos + length — warm stays bitwise-equal
@@ -210,6 +227,18 @@ class ServingEngine:
             min_bucket=min_bucket, max_seq_len=self.max_seq_len,
         )
         self.cache_dtype = self.pool.dtype
+        if not keeps_kv_pairs(cfg):
+            # a net that states another cache than K and V per head
+            # (a latent page) is served plainly or not at all
+            asked = [what for what, on in self._kv_pair_features(
+                speculative).items() if on]
+            if asked:
+                raise ValueError(
+                    f"{type(net).__name__} states a cache that is not "
+                    f"K and V per head; {', '.join(asked)} "
+                    f"{'is' if len(asked) == 1 else 'are'} written for "
+                    f"K/V pairs and not supported over it"
+                )
         self.scheduler = scheduler or Scheduler(
             max_queue_size=max_queue_size, clock=clock
         )
@@ -280,6 +309,14 @@ class ServingEngine:
         if speculative is not None:
             speculative.bind(self)
 
+    def _kv_pair_features(self, speculative):
+        """The options asked for that exist for K/V-pair caches only,
+        by name: refused at construction over any other layout."""
+        return {
+            "int8 cache storage": self.cache_dtype == jnp.int8,
+            "speculative decoding": speculative is not None,
+        }
+
     def _init_kv_backend(self):
         """Allocate the resident decode KV state — the slab here
         ([N, S_max] rows claimed per request); the paged engine
@@ -306,7 +343,7 @@ class ServingEngine:
         self.net.load_functional_state(params, buffers)
         self.net.eval()
         logits, caches = decode_step(
-            self.net, tok[:, None], _unflatten(flat), pos
+            self.net, tok[:, None], _unflatten(flat, self.config), pos
         )
         if self.do_sample:
             # `key` is [B, 2] — every row carries its request's base
@@ -315,7 +352,7 @@ class ServingEngine:
             key = jax.vmap(jax.random.fold_in)(key, pos + 1)
         nxt = _select_next(logits, self.do_sample, temperature,
                            self.top_k, self.top_p, key)
-        return nxt, _flatten(caches)
+        return nxt, _flatten(caches), step_counters(self.net)
 
     def _prefill_fn(self, bucket):
         fn = self._prefill_fns.get(bucket)
@@ -613,7 +650,7 @@ class ServingEngine:
                     jnp.int32(req.prompt_len), _flatten(blk.caches),
                     jnp.float32(self.temperature), key,
                 )
-                blk.caches = _unflatten(new_flat)
+                blk.caches = _unflatten(new_flat, self.config)
                 self._flat = self._run(
                     ("adopt", bucket), self._adopt_fn(bucket),
                     self._flat, new_flat, jnp.int32(slot),
@@ -772,6 +809,7 @@ class ServingEngine:
                 tok[i] = self._seqs[i].last_tok
                 pos[i] = self._seqs[i].pos
                 keys[i] = self._seqs[i].key
+            self.metrics.resident_tokens.observe(int(pos.sum()))
             t0 = self.clock()
             inputs = (
                 jnp.asarray(tok), self._flat, *self._decode_extra(),
@@ -783,7 +821,7 @@ class ServingEngine:
             self._read_done = None
         with profiler.RecordEvent("serving::decode_step",
                                   step=self.step_count):
-            nxt, self._flat = self._run(
+            nxt, self._flat, counted = self._run(
                 ("decode",), self._decode_fn,
                 self._params, self._buffers, *inputs,
             )
@@ -793,6 +831,9 @@ class ServingEngine:
             # while the front end's lock is still held
             del inputs
             nxt = np.asarray(nxt)
+            self.metrics.observe_step_counters(counted)
+            # device arrays too: they die here, for the same reason
+            del counted
         self._read_done = self.clock()
         dt = self._read_done - t0
         with profiler.RecordEvent("serving::emit"):
@@ -1008,7 +1049,9 @@ class ServingEngine:
                 "inter": int(cfg.intermediate_size),
                 "layers": int(cfg.num_hidden_layers),
                 "heads": int(cfg.num_attention_heads),
-                "kv_heads": int(cfg.kv_heads),
+                # what a cached token is made of, layer 0's arrays
+                # (Llama: K and V of [kvH, D]; a latent net: one)
+                "cache": [list(a) for a in cache_layout(cfg)[0]],
             },
         }
         if name.startswith("spec_") and self.speculative is not None:

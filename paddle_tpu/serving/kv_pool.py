@@ -11,7 +11,8 @@ Serving-time cache residency has two shapes of allocation:
   values sit behind a -inf mask, contributing exactly 0 through the
   fp32 softmax), so scrubbing would be pure overhead.
 - **Slabs** — the engine's resident fixed-shape decode buffer
-  ([num_slots, S_max, kvH, D] per layer x2). Claim/release of slots
+  ([num_slots, S_max, *trailing] for every array a layer's cache
+  statement names: Llama's [.., kvH, D] x2). Claim/release of slots
   flows through the pool so occupancy accounting covers the whole
   serving cache footprint in one place.
 
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 from ..models.generation import (
     DEFAULT_CACHE_DTYPE,
     alloc_kv_caches,
+    cache_token_bytes,
     normalize_cache_dtype,
 )
 
@@ -52,7 +54,8 @@ def bucket_for(seq_len, min_bucket=16, max_seq_len=None):
 
 class KVBlock:
     """A bucketed per-request cache handle: ``caches`` is the
-    ``alloc_kv_caches`` layout ([1, bucket, kvH, D] x2 per layer)."""
+    ``alloc_kv_caches`` layout ([1, bucket, *trailing] per array the
+    layer's cache statement names; Llama: [1, bucket, kvH, D] x2)."""
 
     __slots__ = ("bucket", "caches", "_live")
 
@@ -173,7 +176,8 @@ class KVCachePool:
     # ------------------------------------------------------------- slabs
     def alloc_slab_arrays(self, num_slots, seq_len):
         """The engine decode buffer in the shared cache layout
-        ([num_slots, seq_len, kvH, D] x2 per layer, pool dtype)."""
+        ([num_slots, seq_len, *trailing] per stated array, pool
+        dtype)."""
         return alloc_kv_caches(self.config, num_slots, seq_len, self.dtype)
 
     def register_slab(self, num_slots, seq_len):
@@ -188,15 +192,9 @@ class KVCachePool:
         return self._live_blocks + sum(s.claimed for s in self._slabs)
 
     def _bytes(self, bucket, rows=1):
-        from ..quantization.kv import kv_token_bytes
-
-        cfg = self.config
         # int8 counts its per-token fp32 scale overhead — residency
         # numbers must not flatter quantized caches
-        return (
-            2 * cfg.num_hidden_layers * rows * bucket
-            * kv_token_bytes(cfg.kv_heads, cfg.head_dim, self.dtype)
-        )
+        return rows * bucket * cache_token_bytes(self.config, self.dtype)
 
     def stats(self):
         free_blocks = sum(len(v) for v in self._freelists.values())

@@ -158,6 +158,24 @@ class ServingMetrics:
             "submit_wait", prom_name=f"{ns}_submit_wait_seconds",
             help="per front-end request: received to engine.submit "
                  "returned (the wait for the driver's lock)")
+        # what a decode step's cost depends on beside the batch: the
+        # tokens it attends over, and (an expert model) how many
+        # experts' weights it had to read
+        self.resident_tokens = Histogram(
+            "resident_tokens", unit="toks", buckets=_reg.TOKEN_BUCKETS,
+            prom_name=f"{ns}_resident_tokens",
+            help="per decode step: the rows' cache positions summed "
+                 "(tokens the step attends over)")
+        self.experts_touched = Histogram(
+            "experts_touched", unit="experts",
+            prom_name=f"{ns}_experts_touched",
+            help="per decode step of an expert model: experts that got "
+                 "at least one token, summed over the expert layers "
+                 "(counted by the decode program, read with the next "
+                 "tokens)")
+        # what a net's decode program may count (``pop_step_counters``),
+        # by the name it returns it under
+        self.step_counters = {"experts_touched": self.experts_touched}
         # speculative decoding (serving.speculative): one round = one
         # draft proposal pass + one target verify launch
         self.spec_rounds = Counter(
@@ -191,6 +209,7 @@ class ServingMetrics:
             self.ttft, self.itl, self.e2e,
             self.queue_wait, self.queue_depth, self.slot_occupancy,
             self.host_gap, self.prefill, self.submit_wait,
+            self.resident_tokens, self.experts_touched,
             self.spec_rounds, self.spec_proposed, self.spec_accepted,
             self.spec_accept_length,
         ])
@@ -254,7 +273,16 @@ class ServingMetrics:
             "host_gap": self.host_gap.snapshot(),
             "prefill": self.prefill.snapshot(),
             "submit_wait": self.submit_wait.snapshot(),
+            "resident_tokens": self.resident_tokens.snapshot(),
+            "experts_touched": self.experts_touched.snapshot(),
         }
+
+    def observe_step_counters(self, counted):
+        """One decode step's counters as the net's program returned
+        them (``{name: small device array}``), each into the histogram
+        of its name."""
+        for name, value in counted.items():
+            self.step_counters[name].observe(int(value))
 
     def render(self):
         """Human-readable table of the report."""

@@ -76,6 +76,7 @@ from .engine import (
     _flatten,
     _unflatten,
     build_chunk_prefill_body,
+    step_counters,
 )
 from .paged_pool import PagedKVPool, PagesExhausted
 from .scheduler import CANCELLED, REASON_PAGES_EXHAUSTED, RUNNING
@@ -187,6 +188,14 @@ class PagedServingEngine(ServingEngine):
             )
 
     # ------------------------------------------------------- KV backend
+    def _kv_pair_features(self, speculative):
+        out = super()._kv_pair_features(speculative)
+        out["the prefix cache"] = \
+            self._prefix_cache_arg not in (None, False)
+        out["KV tiering"] = self._kv_tiering_arg not in (None, False)
+        out["remote prefill"] = self.prefill_transport is not None
+        return out
+
     def _init_kv_backend(self):
         num_pages = self._num_pages_arg
         if num_pages is None:
@@ -417,7 +426,7 @@ class PagedServingEngine(ServingEngine):
         self.net.load_functional_state(params, buffers)
         self.net.eval()
         logits, caches = decode_step(
-            self.net, tok[:, None], _unflatten(flat), pos,
+            self.net, tok[:, None], _unflatten(flat, self.config), pos,
             page_table=tbl,
         )
         if self.do_sample:
@@ -425,7 +434,7 @@ class PagedServingEngine(ServingEngine):
             key = jax.vmap(jax.random.fold_in)(key, pos + 1)
         nxt = _select_next(logits, self.do_sample, temperature,
                            self.top_k, self.top_p, key)
-        return nxt, _flatten(caches)
+        return nxt, _flatten(caches), step_counters(self.net)
 
     def _decode_extra(self):
         return (jnp.asarray(self._tables),)
@@ -887,7 +896,7 @@ class PagedServingEngine(ServingEngine):
                         jnp.int32(req.prompt_len), _flatten(blk.caches),
                         jnp.float32(self.temperature), key,
                     )
-                    blk.caches = _unflatten(new_flat)
+                    blk.caches = _unflatten(new_flat, self.config)
                     t0 = int(np.asarray(nxt)[0])
             else:
                 # the prefill pool already ran the bucket program; the

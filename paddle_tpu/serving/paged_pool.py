@@ -3,8 +3,10 @@
 The slab pool's concurrency problem: a decode slab row is ``S_max``
 tokens of resident HBM no matter how short the request, so bucketing
 wins (fewer compiles) never became resident-HBM wins (more concurrent
-requests per chip). The paged pool fixes the unit of residency: K/V
-live in a PAGE ARENA (``[num_pages, page_size, kvH, D]`` per layer x2)
+requests per chip). The paged pool fixes the unit of residency: the
+cache lives in a PAGE ARENA (``[num_pages, page_size, *trailing]`` for
+every array the net's cache statement names, ``generation.cache_layout``:
+Llama's K and V of ``[kvH, D]``, a latent-attention net's ONE array)
 and a request claims only ``ceil(total_tokens / page_size)`` pages —
 its own length, quantized to one page. At equal KV HBM, a mixed-length
 workload admits strictly more concurrent requests (the tier-1 test
@@ -35,7 +37,11 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from ..models.generation import normalize_cache_dtype
+from ..models.generation import (
+    alloc_kv_caches,
+    cache_token_bytes,
+    normalize_cache_dtype,
+)
 
 
 class PagesExhausted(RuntimeError):
@@ -104,26 +110,18 @@ class PagedKVPool:
         return -(-self.max_seq_len // self.page_size)
 
     def alloc_arena_arrays(self):
-        """The page arena in the shared cache layout:
-        ``[num_pages + 1, page_size, kvH, D]`` x2 per layer (row 0 =
-        garbage page), pool dtype. An int8 pool allocates quantized
-        storage (int8 values + per-(slot, kvH) fp32 scales as one
+        """The page arena in the shared cache layout: for every array
+        the config's cache statement names
+        (``generation.cache_layout``), ``[num_pages + 1, page_size,
+        *trailing]`` (row 0 = garbage page), pool dtype — Llama's
+        ``[.., kvH, D]`` x2 a layer, a latent net's one ``[.., latent +
+        rope dims]``. The arena IS the batch-of-pages view of
+        ``alloc_kv_caches``: an int8 pool gets quantized storage there
+        (int8 values + per-(slot, kvH) fp32 scales as one
         ``QuantizedKV`` pytree per array; zero scales keep the garbage
         page dequantizing to exact zeros)."""
-        cfg = self.config
-        shape = (self.num_pages + 1, self.page_size, cfg.kv_heads,
-                 cfg.head_dim)
-        if self.dtype == jnp.int8:
-            from ..quantization.kv import alloc_quantized
-
-            return [
-                (alloc_quantized(shape), alloc_quantized(shape))
-                for _ in range(cfg.num_hidden_layers)
-            ]
-        return [
-            (jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype))
-            for _ in range(cfg.num_hidden_layers)
-        ]
+        return alloc_kv_caches(self.config, self.num_pages + 1,
+                               self.page_size, self.dtype)
 
     # ------------------------------------------------------- claim flow
     @property
@@ -217,19 +215,16 @@ class PagedKVPool:
 
     # ------------------------------------------------------- accounting
     def page_bytes(self):
-        """HBM bytes of ONE page across every layer's K and V arena.
+        """HBM bytes of ONE page across every layer's arena arrays.
         0 when the pool was built without a model config (the saved-
         artifact accounting path — page counts still tally, byte
         figures degrade honestly instead of guessing)."""
         cfg = self.config
         if cfg is None:
             return 0
-        from ..quantization.kv import kv_token_bytes
-
         # int8 pages count their per-token fp32 scale overhead: the
         # equal-HBM concurrency comparison must not flatter quantization
-        return (2 * cfg.num_hidden_layers * self.page_size
-                * kv_token_bytes(cfg.kv_heads, cfg.head_dim, self.dtype))
+        return self.page_size * cache_token_bytes(cfg, self.dtype)
 
     def request_resident_bytes(self, total_tokens):
         """Resident KV bytes one admitted request costs in this pool —

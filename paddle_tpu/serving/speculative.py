@@ -86,6 +86,9 @@ def _flatten(caches):
 
 
 def _unflatten(flat):
+    """K/V pairs a layer: speculation is refused at construction over
+    any other cache statement (``ServingEngine._kv_pair_features``),
+    and an early-exit draft keeps fewer layers than its config says."""
     return [(flat[2 * i], flat[2 * i + 1]) for i in range(len(flat) // 2)]
 
 
