@@ -3,8 +3,13 @@
 The TPU-first serving shape (cf. the kernel-fusion serving stacks in
 PAPERS.md): keep the device running ONE compiled fixed-shape decode-step
 program over a resident KV slab, and do all request lifecycle work —
-admission, retirement, deadlines, metrics — in a host-side loop between
-steps. Three compiled programs total:
+admission, retirement, deadlines, metrics — in a host-side loop that
+runs UNDER the steps: the loop keeps at most one decode step in flight
+(it launches step n+1 before it reads step n's tokens, see
+``ServingEngine._decode_once``), so the device goes from one program to
+the next while the host emits, takes the front end's lock and builds
+the next inputs. Only an iteration that admits reads first and
+launches after. Three compiled programs total:
 
 - **prefill** (one per power-of-two prompt bucket): runs a right-padded
   prompt through the cache path and emits the first token. Bucketing
@@ -17,6 +22,8 @@ steps. Three compiled programs total:
 - **decode step** (exactly one): ``[max_batch]`` tokens at per-row
   positions -> next tokens. Every row sits at its own depth — this is
   what the vector-``pos`` cache path in ``models.llama`` exists for.
+  A continuing row's input token is the step before's output, taken
+  on the device; the host supplies it only for a row admitted since.
   Free rows ride along as masked garbage (their writes land on slots
   the next adoption overwrites), so admission and retirement NEVER
   trigger a recompile or stall in-flight sequences.
@@ -142,7 +149,7 @@ def build_chunk_prefill_body(net, do_sample, top_k, top_p):
 class _Seq:
     """Host-side state of one running sequence (one slab row)."""
 
-    __slots__ = ("handle", "last_tok", "emitted", "key",
+    __slots__ = ("handle", "last_tok", "emitted", "key", "t_tok",
                  "slo_itl", "slo_e2e")
 
     def __init__(self, handle, first_tok, key=None, slo_itl=None,
@@ -153,6 +160,10 @@ class _Seq:
         # the request's base PRNG key (sampling_keys derivation) as a
         # host array — decode steps stack the active rows' keys
         self.key = key
+        # engine.clock when the row's newest token reached the host
+        # (the prefill's first token so far): where its next
+        # inter-token sample starts
+        self.t_tok = handle.first_token_time
         # per-SLO-class bound histogram children, resolved ONCE at
         # admission (observability.slo): the decode hot loop observes
         # straight into them — zero per-token label resolution, the
@@ -165,6 +176,24 @@ class _Seq:
         # cache position of the token being fed next step: the last
         # emitted token sits at prompt_len + emitted - 1
         return self.handle.request.prompt_len + self.emitted - 1
+
+
+class _Launched:
+    """One decode program that was launched and whose tokens the host
+    has not read yet. ``seqs`` holds, by slot, the ``_Seq`` OBJECTS it
+    was launched for (None: the row was fed nothing): a row that was
+    finished while its step ran, by a deadline, a shed or an EOS found
+    one step late, has its lagged token dropped by identity, also when
+    its slot was admitted again meanwhile. ``nxt`` and ``counted`` are
+    the program's device outputs and die with the read."""
+
+    __slots__ = ("nxt", "counted", "seqs", "step")
+
+    def __init__(self, nxt, counted, seqs, step):
+        self.nxt = nxt
+        self.counted = counted
+        self.seqs = seqs
+        self.step = step
 
 
 class ServingEngine:
@@ -276,6 +305,10 @@ class ServingEngine:
         # read, None once the engine is idle: where the next
         # metrics.host_gap sample starts
         self._read_done = None
+        # the decode step launched and not yet read (_Launched), None
+        # when nothing is: before the first launch, after admission
+        # settled it, under speculation, on an idle engine
+        self._in_flight = None
         self._closed = False
         # runtime lint guard: the whole engine design exists so that
         # admission/retirement NEVER recompile — if compile caches grow
@@ -339,9 +372,19 @@ class ServingEngine:
 
     # ------------------------------------------------- compiled programs
     def _decode_body(self, params, buffers, tok, flat, pos, temperature,
-                     key):
+                     key, prev, from_host):
+        # this body runs only while jit traces it, which leaves tracers
+        # in the net: _run puts the weights back after the call. A
+        # LATER trace (``prev`` comes back from a program placed over a
+        # mesh where the first launch had an upload) must do so too
+        self._traced.discard(("decode",))
         self.net.load_functional_state(params, buffers)
         self.net.eval()
+        # a continuing row is fed the token the step before sampled,
+        # which never visited the host; a row admitted since that
+        # launch (and every row when nothing was in flight) its
+        # ``tok`` from the host
+        tok = jnp.where(from_host, tok, prev)
         logits, caches = decode_step(
             self.net, tok[:, None], _unflatten(flat, self.config), pos
         )
@@ -589,8 +632,10 @@ class ServingEngine:
         self._seqs[slot] = None
         if not self.active_slots:
             # the engine goes idle: what passes until the next decode
-            # program is no host gap
+            # program is no host gap, and a step still in flight (the
+            # one launched before an EOS was seen) is for no one
             self._read_done = None
+            self._in_flight = None
         self._release_slot(slot)
         h._fire_terminal()
 
@@ -689,9 +734,10 @@ class ServingEngine:
                                 slo_itl=slo_itl, slo_e2e=slo_e2e)
         self._append(slot, t0)
 
-    def _decode_extra(self):
+    def _decode_extra(self, fed):
         """Extra positional decode-step inputs between the KV state and
-        ``pos`` (the paged engine passes its page tables here)."""
+        ``pos`` (the paged engine passes its page tables here, with the
+        table of an active row that is not ``fed`` cleared)."""
         return ()
 
     def _has_capacity(self):
@@ -720,9 +766,15 @@ class ServingEngine:
 
     def step(self):
         """One engine iteration: retire expired, admit into free slots,
-        run one decode step over the whole resident KV state. Each
-        phase is a ``RecordEvent`` span with a fixed name (a phase's
-        own time is its span less the spans inside it)."""
+        launch one decode step over the whole resident KV state and
+        read the one launched before it. Each phase is a
+        ``RecordEvent`` span with a fixed name (a phase's own time is
+        its span less the spans inside it): ``serving::admit`` (with
+        ``serving::settle``, the read of the step in flight that an
+        admission waits for, and that step's ``serving::emit``, inside
+        it), ``serving::decode_inputs``, ``serving::decode_step`` (the
+        launch of step n+1 AND the blocking read of step n),
+        ``serving::emit`` (step n's tokens), ``serving::step_tail``."""
         if self._closed:
             raise RuntimeError(f"{type(self).__name__} is closed")
         with profiler.RecordEvent("serving::step", step=self.step_count):
@@ -767,6 +819,11 @@ class ServingEngine:
         while self._pending_swap is None and self._has_capacity() and (
             cap is None or admitted < cap
         ):
+            if self._in_flight is not None and self.scheduler.depth:
+                # admission sees settled state: slots, pages and the
+                # budget as they are with every launched token read,
+                # and no ready token waits behind a prefill
+                self._settle()
             handle = self.scheduler.pop_next(self._admission_budget(),
                                              fits=self._admission_fits())
             if handle is None:
@@ -790,73 +847,159 @@ class ServingEngine:
         for _ in self.scheduler.drain_timed_out():
             self.metrics.timeouts.inc()
 
+    def _launch_pos(self, slot):
+        """The cache position the next decode launch feeds row ``slot``
+        at: its host position, one further while its token of the step
+        in flight is unread. None where the launch feeds nothing: a
+        free row, or a row whose unread token is its last by
+        ``max_new_tokens`` (known without seeing it, so a length-bound
+        request never runs a step too many)."""
+        seq = self._seqs[slot]
+        if seq is None:
+            return None
+        fl = self._in_flight
+        lag = fl is not None and fl.seqs[slot] is seq
+        if seq.emitted + lag >= seq.handle.request.max_new_tokens:
+            return None
+        return seq.pos + lag
+
     def _decode_once(self):
-        """One fused decode step over every row (free rows are masked
-        garbage; their writes land on slots adoption overwrites)."""
-        active = [i for i, s in enumerate(self._seqs) if s is not None]
-        if not active:
+        """The decode phase of one iteration, with at most one step in
+        flight: launch the fused step n+1 over every row, THEN read the
+        tokens of step n and emit them, so that the read blocks with
+        the next program already queued behind it on the device, and
+        emit, the step's tail, the front end's lock, page growth and
+        the next inputs all run under a running program. A step costs
+        max(program, host), not their sum.
+
+        A continuing row's input token stays on the device (step n's
+        ``nxt`` feeds step n+1 through ``where(from_host, tok, prev)``);
+        positions, keys and page tables the host knows one step ahead.
+        A row whose unread token is its last is fed nothing; a row that
+        ends on EOS is found one step late, and its extra step (the EOS
+        token's true KV at the next position of its own pages) is
+        dropped with it. When nothing is in flight (the first step, an
+        iteration that admitted, see ``_admit``) there is nothing to
+        read and the launch is all that happens. Free and unfed rows
+        ride along as masked garbage (their writes land on slots
+        adoption overwrites). Speculation runs its own rounds and never
+        has a step in flight."""
+        if not self.active_slots:
             return
         if self.speculative is not None:
             # propose + one-launch verify per row instead of the fused
             # per-token step (speculative.py)
             self.speculative.decode_once(self)
             return
+        prev = self._in_flight
         with profiler.RecordEvent("serving::decode_inputs"):
             tok = np.zeros((self.max_batch_size,), np.int32)
             pos = np.zeros((self.max_batch_size,), np.int32)
             keys = np.zeros((self.max_batch_size, 2), np.uint32)
-            for i in active:
-                tok[i] = self._seqs[i].last_tok
-                pos[i] = self._seqs[i].pos
-                keys[i] = self._seqs[i].key
-            self.metrics.resident_tokens.observe(int(pos.sum()))
-            t0 = self.clock()
-            inputs = (
-                jnp.asarray(tok), self._flat, *self._decode_extra(),
-                jnp.asarray(pos), jnp.float32(self.temperature),
-                jnp.asarray(keys),
-            )
-        if self._read_done is not None:
-            self.metrics.host_gap.observe(self.clock() - self._read_done)
-            self._read_done = None
+            from_host = np.ones((self.max_batch_size,), bool)
+            fed = [None] * self.max_batch_size
+            for i, seq in enumerate(self._seqs):
+                p = self._launch_pos(i)
+                if p is None:
+                    continue
+                fed[i] = seq
+                pos[i] = p
+                keys[i] = seq.key
+                if p == seq.pos:
+                    tok[i] = seq.last_tok
+                else:
+                    from_host[i] = False
+            launch = any(seq is not None for seq in fed)
+            if launch:
+                self.metrics.resident_tokens.observe(int(pos.sum()))
+                tok = jnp.asarray(tok)
+                inputs = (
+                    tok, self._flat, *self._decode_extra(fed),
+                    jnp.asarray(pos), jnp.float32(self.temperature),
+                    jnp.asarray(keys),
+                    tok if prev is None else prev.nxt,
+                    jnp.asarray(from_host),
+                )
         with profiler.RecordEvent("serving::decode_step",
                                   step=self.step_count):
-            nxt, self._flat, counted = self._run(
-                ("decode",), self._decode_fn,
-                self._params, self._buffers, *inputs,
-            )
-            # the uploads die here, with the launch, as temporaries
-            # would: freeing a device array lets go of the GIL, and
-            # after emit that hands it to every woken stream thread
-            # while the front end's lock is still held
-            del inputs
-            nxt = np.asarray(nxt)
-            self.metrics.observe_step_counters(counted)
-            # device arrays too: they die here, for the same reason
-            del counted
+            # set before the read: a row that ends there on EOS as the
+            # last one drops the step just launched (_finish)
+            self._in_flight = None
+            if launch:
+                if self._read_done is not None:
+                    self.metrics.host_gap.observe(
+                        self.clock() - self._read_done)
+                    self._read_done = None
+                nxt, self._flat, counted = self._run(
+                    ("decode",), self._decode_fn,
+                    self._params, self._buffers, *inputs,
+                )
+                self._in_flight = _Launched(nxt, counted, fed,
+                                            self.step_count)
+                if prev is not None:
+                    self.metrics.steps_overlapped.inc()
+                # the uploads die here, with the launch, as temporaries
+                # would: freeing a device array lets go of the GIL, and
+                # after emit that hands it to every woken stream thread
+                # while the front end's lock is still held. The step's
+                # outputs live in the record alone, until their read
+                del inputs, tok, nxt, counted
+            if prev is not None:
+                toks = self._read(prev)
+        if prev is not None:
+            self._emit(prev, toks)
+
+    def _read(self, launched):
+        """Block until a launched step's tokens are on the host: the
+        one place the driver waits for the device. With the next step
+        already launched the device goes on under everything the host
+        does until it comes back here."""
+        t0 = self.clock()
+        toks = np.asarray(launched.nxt)
+        self.metrics.observe_step_counters(launched.counted)
+        # the device arrays die here, right after the read (see the
+        # launch for why not later)
+        launched.nxt = launched.counted = None
         self._read_done = self.clock()
-        dt = self._read_done - t0
+        self.metrics.read_wait.observe(self._read_done - t0)
+        return toks
+
+    def _emit(self, launched, toks):
+        """Hand a read step's tokens to its rows: the row's inter-token
+        sample (read returned to read returned; from its first token's
+        time for a row's first decode token), ``_append``, ``on_token``.
+        A row that is no longer the one the step was launched for gets
+        nothing."""
+        now = self._read_done
         with profiler.RecordEvent("serving::emit"):
-            if self._traced_live:
-                # ONE bounded-ring event per traced request per step
-                # (the O(1)-spans discipline: a 500-step decode stays
-                # one span); sampled-out runs never reach this branch —
-                # the single integer check above is the whole hot-path
-                # cost
-                occ = len(active)
-                for i in active:
-                    sp = self._seqs[i].handle._decode_span
-                    if sp is not None:
-                        sp.event("decode_step", step=self.step_count,
-                                 occupancy=occ, dt_s=dt)
-            for i in active:
-                seq = self._seqs[i]
-                if seq is None:
-                    continue  # finished by an earlier row this step
+            # sampled-out runs never look for a span: this one integer
+            # is the whole hot-path cost of request tracing
+            traced = self._traced_live
+            occ = traced and sum(seq is not None for seq in launched.seqs)
+            for i, seq in enumerate(launched.seqs):
+                if seq is None or self._seqs[i] is not seq:
+                    continue
+                dt = now - seq.t_tok
+                seq.t_tok = now
+                sp = seq.handle._decode_span if traced else None
+                if sp is not None:
+                    # ONE bounded-ring event per traced request per
+                    # step (the O(1)-spans discipline: a 500-step
+                    # decode stays one span)
+                    sp.event("decode_step", step=launched.step,
+                             occupancy=occ, dt_s=dt)
                 # per-class child bound at admission: no label
                 # resolution (and no allocation) on this per-token path
                 (seq.slo_itl or self.metrics.itl).observe(dt)
-                self._append(i, nxt[i])
+                self._append(i, toks[i])
+
+    def _settle(self):
+        """Read and emit the step in flight and leave nothing in
+        flight: what an admission waits for."""
+        launched, self._in_flight = self._in_flight, None
+        with profiler.RecordEvent("serving::settle", step=launched.step):
+            toks = self._read(launched)
+        self._emit(launched, toks)
 
     def run_until_idle(self, max_steps=100_000):
         """Drive ``step()`` until queue and slab are empty."""
@@ -1023,10 +1166,11 @@ class ServingEngine:
         B = self.max_batch_size
         return (
             self._params, self._buffers, jnp.zeros((B,), jnp.int32),
-            self._flat, *self._decode_extra(),
+            self._flat, *self._decode_extra(self._seqs),
             jnp.zeros((B,), jnp.int32),
             jnp.float32(self.temperature),
             jnp.zeros((B, 2), jnp.uint32),
+            jnp.zeros((B,), jnp.int32), jnp.ones((B,), bool),
         )
 
     def _adopt_example_args(self, flat_block, bucket):
@@ -1254,6 +1398,7 @@ class ServingEngine:
             self._seqs[i] = None
             self._release_slot(i)
             h._fire_terminal()
+        self._in_flight = None
         self._flat = None
         self._decode_fn = None
         if self.sessions is not None:

@@ -12,9 +12,10 @@ series), so one Prometheus scrape covers serving alongside training
 and analysis telemetry. What the serving loop was DOING at a moment is
 not here but in the ``profiler.RecordEvent`` phase spans of
 ``serving/engine.py`` and ``http_frontend.py``, which a jax profiler
-trace holds on the device's clock; the three histograms ``host_gap``,
-``prefill`` and ``submit_wait`` carry the same phases' totals over a
-whole run, where a few seconds of trace hold too few requests.
+trace holds on the device's clock; the histograms ``host_gap``,
+``read_wait``, ``prefill`` and ``submit_wait`` and the counter
+``steps_overlapped`` carry the same phases' totals over a whole run,
+where a few seconds of trace hold too few requests.
 """
 from __future__ import annotations
 
@@ -128,7 +129,11 @@ class ServingMetrics:
             help="time to first token")
         self.itl = Histogram(             # inter-token latency
             "itl", prom_name=f"{ns}_itl_seconds",
-            help="inter-token latency")
+            help="inter-token latency: per row and token, from the "
+                 "return of the read that brought the row's token "
+                 "before (its first token's time for the first decode "
+                 "token) to the return of the read that brought this "
+                 "one")
         self.e2e = Histogram(             # submit -> finished
             "e2e", prom_name=f"{ns}_e2e_seconds",
             help="end-to-end request latency")
@@ -148,8 +153,24 @@ class ServingMetrics:
         self.host_gap = Histogram(
             "host_gap", prom_name=f"{ns}_host_gap_seconds",
             help="driver thread, per decode step: return of the last "
-                 "step's blocking read to the launch of this step's "
-                 "decode program (an idle engine starts no sample)")
+                 "blocking read to the next launch of the decode "
+                 "program: the host's work a step, which runs under "
+                 "the program launched before that read (an idle "
+                 "engine starts no sample, nor does a launch that no "
+                 "read came before)")
+        self.read_wait = Histogram(
+            "read_wait", prom_name=f"{ns}_read_wait_seconds",
+            help="driver thread, per decode step read: how long the "
+                 "blocking read of a launched step's tokens waited; "
+                 "above zero the host finished first and the device "
+                 "never waited for it")
+        self.steps_overlapped = Counter(
+            "steps_overlapped",
+            prom_name=f"{ns}_steps_overlapped_total",
+            help="decode programs launched while the step before was "
+                 "still unread (the host's work for it ran under a "
+                 "program); the rest followed an admission, an idle "
+                 "engine or a speculative round")
         self.prefill = Histogram(
             "prefill", prom_name=f"{ns}_prefill_seconds",
             help="per admission: gather, prefill, the blocking "
@@ -208,7 +229,8 @@ class ServingMetrics:
             self.guard_fires, self.reloads, self.reload_ttft_spike,
             self.ttft, self.itl, self.e2e,
             self.queue_wait, self.queue_depth, self.slot_occupancy,
-            self.host_gap, self.prefill, self.submit_wait,
+            self.host_gap, self.read_wait, self.steps_overlapped,
+            self.prefill, self.submit_wait,
             self.resident_tokens, self.experts_touched,
             self.spec_rounds, self.spec_proposed, self.spec_accepted,
             self.spec_accept_length,
@@ -260,6 +282,7 @@ class ServingMetrics:
                 "speculative_rounds": self.spec_rounds.value,
                 "speculative_proposed": self.spec_proposed.value,
                 "speculative_accepted": self.spec_accepted.value,
+                "steps_overlapped": self.steps_overlapped.value,
             },
             "speculative_accept_length":
                 self.spec_accept_length.snapshot(),
@@ -271,6 +294,7 @@ class ServingMetrics:
             "queue_depth": self.queue_depth.snapshot(),
             "slot_occupancy": self.slot_occupancy.snapshot(),
             "host_gap": self.host_gap.snapshot(),
+            "read_wait": self.read_wait.snapshot(),
             "prefill": self.prefill.snapshot(),
             "submit_wait": self.submit_wait.snapshot(),
             "resident_tokens": self.resident_tokens.snapshot(),
@@ -291,7 +315,7 @@ class ServingMetrics:
         for k, v in r["counters"].items():
             lines.append(f"{k:>20}: {v}")
         for name in ("ttft", "itl", "e2e", "queue_wait", "submit_wait",
-                     "prefill", "host_gap", "queue_depth",
+                     "prefill", "host_gap", "read_wait", "queue_depth",
                      "slot_occupancy"):
             s = r[name]
             if not s.get("count"):

@@ -422,9 +422,13 @@ class PagedServingEngine(ServingEngine):
 
     # ------------------------------------------------- compiled programs
     def _decode_body(self, params, buffers, tok, flat, tbl, pos,
-                     temperature, key):
+                     temperature, key, prev, from_host):
+        # a later trace puts the weights back too (base engine)
+        self._traced.discard(("decode",))
         self.net.load_functional_state(params, buffers)
         self.net.eval()
+        # the device's own token for a continuing row (base engine)
+        tok = jnp.where(from_host, tok, prev)
         logits, caches = decode_step(
             self.net, tok[:, None], _unflatten(flat, self.config), pos,
             page_table=tbl,
@@ -436,8 +440,17 @@ class PagedServingEngine(ServingEngine):
                            self.top_k, self.top_p, key)
         return nxt, _flatten(caches), step_counters(self.net)
 
-    def _decode_extra(self):
-        return (jnp.asarray(self._tables),)
+    def _decode_extra(self, fed):
+        # an active row the launch feeds nothing writes its garbage
+        # where a free row does, into page 0, not into its own pages
+        # (they may be shared, and are published when it finishes)
+        unfed = [i for i, seq in enumerate(self._seqs)
+                 if seq is not None and fed[i] is None]
+        tables = self._tables
+        if unfed:
+            tables = tables.copy()
+            tables[unfed] = 0
+        return (jnp.asarray(tables),)
 
     def _adopt_fn(self, bucket):
         """Scatter a prefilled [1, bucket] block into the arena as
@@ -1040,17 +1053,21 @@ class PagedServingEngine(ServingEngine):
 
     # ------------------------------------------------------ decode loop
     def _grow_pages(self):
-        """Demand growth: before the decode step, any row whose next
-        write position crosses into an unallocated page claims one
-        (evicting cold prefixes if needed). A claim that still fails
-        sheds THAT request with ``pages_exhausted`` — partial tokens
-        kept, terminal event fired, nobody else's pages touched."""
+        """Demand growth: before the decode launch, any row whose write
+        position IN THAT LAUNCH (``_launch_pos``: one past the host's
+        while its last token is in flight) crosses into an unallocated
+        page claims one (evicting cold prefixes if needed). A claim
+        that still fails sheds THAT request with ``pages_exhausted`` —
+        partial tokens kept (one in flight is dropped), terminal event
+        fired, nobody else's pages touched. A page claimed for a step
+        that is dropped goes back with the row."""
         ps = self.page_size
-        for i, seq in enumerate(self._seqs):
-            if seq is None:
+        for i in range(self.max_batch_size):
+            pos = self._launch_pos(i)
+            if pos is None:
                 continue
             pages = self._row_pages[i]
-            while seq.pos // ps >= len(pages):
+            while pos // ps >= len(pages):
                 try:
                     new = self._claim_pages(1)
                 except PagesExhausted:

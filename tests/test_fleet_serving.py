@@ -482,6 +482,12 @@ def test_router_midstream_death_sheds_with_reason(net):
             {"input_ids": [1, 2, 3], "max_new_tokens": 8})
         assert [e for e, _ in ev] == ["token", "token", "error"]
         assert ev[-1][1]["reason"] == "replica_failed"
+        # the router counts the abort after it has written the event
+        # this client already holds: give its thread its turn
+        deadline = time.monotonic() + 5.0
+        while not router.metrics.stream_aborts.by_label() \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert router.metrics.stream_aborts.by_label().get(
             "replica_failed") == 1
     finally:

@@ -167,13 +167,18 @@ def test_histogram_counts_follow_from_the_run(traced):
     assert rep["submit_wait"]["count"] == 3       # one a request
     assert rep["prefill"]["count"] == 3           # one an admission
     assert rep["counters"]["admitted"] == 3
-    # every step ran the decode program, and each follows a blocking
-    # read but the first after the engine was idle: three requests
-    # make one to three busy stretches (the exact count is pinned in
+    # every launched step is read once (no request ends on EOS, so
+    # none is dropped); a launch starts a host_gap sample when a read
+    # returned since the launch before it: not the first two of a busy
+    # stretch, nor the one after an admission's launch, and a row's
+    # last step launches nothing (the exact count is pinned in
     # test_no_host_gap_sample_across_an_idle_engine)
     assert rep["slot_occupancy"]["count"] == steps
-    assert steps - 3 <= rep["host_gap"]["count"] <= steps - 1
-    for name in ("host_gap", "prefill", "submit_wait"):
+    launches = rep["resident_tokens"]["count"]
+    assert rep["read_wait"]["count"] == launches < steps
+    assert 1 <= rep["host_gap"]["count"] <= launches - 2
+    assert 1 <= rep["counters"]["steps_overlapped"] <= launches - 1
+    for name in ("host_gap", "read_wait", "prefill", "submit_wait"):
         assert rep[name]["sum"] > 0.0
         assert rep[name]["unit"] == "s"
 
@@ -184,13 +189,19 @@ def test_no_host_gap_sample_across_an_idle_engine(net):
                         min_bucket=8, clock=lambda: float(next(ticks)))
     eng.generate([np.arange(1, 6)[None]], max_new_tokens=4)
     first = eng.step_count
-    assert eng.metrics.host_gap.count == first - 1
+    # four tokens: the prefill's and three decode launches, of which
+    # the third alone follows a read (the second is launched before
+    # the first is read); the fourth step reads the last and launches
+    # nothing
+    assert first == 4
+    assert eng.metrics.host_gap.count == 1
     assert eng._read_done is None     # the last row left: idle
+    assert eng._in_flight is None
     for _ in range(50):               # the clock runs on meanwhile
         eng.clock()
     eng.generate([np.arange(1, 8)[None]], max_new_tokens=4)
-    second = eng.step_count - first
-    assert eng.metrics.host_gap.count == (first - 1) + (second - 1)
+    assert eng.step_count - first == 4
+    assert eng.metrics.host_gap.count == 2
     # on the engine's clock, and never the idle stretch in between
     assert eng.metrics.host_gap.snapshot()["max"] < 20
     eng.close()

@@ -634,6 +634,352 @@ def test_paged_sampling_reproducible(net):
     assert run() == run()
 
 
+# ------------------------------------------- one decode step in flight
+def _lag_engine(kind, net, **kw):
+    kw.setdefault("max_seq_len", 64)
+    kw.setdefault("min_bucket", 8)
+    if kind == "slab":
+        return ServingEngine(net, **kw)
+    kw.setdefault("page_size", 8)
+    kw.setdefault("max_prefills_per_step", None)
+    return PagedServingEngine(net, **kw)
+
+
+def _drive_serially(eng):
+    """The engine's order before it kept a step in flight: every
+    launched step is read before the next launch, so every input token
+    visits the host (``from_host`` on all rows, nothing overlapped)."""
+    while eng.scheduler.depth or eng.active_slots:
+        eng.step()
+        if eng._in_flight is not None:
+            eng._settle()
+
+
+def _ref(net, prompt, max_new):
+    return np.asarray(net.generate(
+        Tensor(jnp.asarray(prompt)), max_new_tokens=max_new).numpy())[0]
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "sampled"])
+@pytest.mark.parametrize("kind", ["slab", "paged", "paged-demand"])
+def test_lagged_streams_equal_serial_order_and_generate(net, kind,
+                                                        sampled):
+    """Rows of unequal ``max_new_tokens`` (finishes fall on different
+    steps, slots turn over mid-run) through the loop that launches
+    step n+1 before it reads step n: token for token what the serial
+    order gives (each continuing row's token fed from the device, not
+    from the host), greedy equal to ``generate()``; sampled too,
+    because keys are addressed by position."""
+    kw = dict(max_batch_size=3)
+    if sampled:
+        kw.update(do_sample=True, temperature=0.8, top_k=8, seed=11)
+    if kind == "paged-demand":
+        kw.update(demand_paging=True)
+    prompts = [RNG.randint(0, 64, (1, L)) for L in (6, 5, 7, 9, 4, 8)]
+    max_news = [3, 9, 6, 8, 1, 12]
+
+    def run(drive):
+        eng = _lag_engine(kind.split("-")[0], net, **kw)
+        hs = [eng.submit(p, m) for p, m in zip(prompts, max_news)]
+        drive(eng)
+        assert eng._in_flight is None and eng.active_slots == 0
+        assert eng.pool.occupancy == 0
+        if kind != "slab":
+            assert eng.page_pool.pages_in_use == 0
+        rep = eng.metrics.report()
+        eng.close()
+        return hs, rep
+
+    lagged, rep = run(lambda eng: eng.run_until_idle())
+    serial, rep_s = run(_drive_serially)
+    assert rep["counters"]["steps_overlapped"] > 0
+    assert rep_s["counters"]["steps_overlapped"] == 0
+    # the same work (the positions fed, summed over all launches): no
+    # row runs a step more for its last token being known late
+    assert rep["resident_tokens"]["sum"] == rep_s["resident_tokens"]["sum"]
+    assert rep["counters"]["tokens_out"] == sum(max_news)
+    for h, hs, p, m in zip(lagged, serial, prompts, max_news):
+        assert h.status == "DONE" and len(h.tokens) == m
+        assert h.tokens == hs.tokens
+        if not sampled:
+            np.testing.assert_array_equal(h.output_ids, _ref(net, p, m))
+
+
+@pytest.mark.parametrize("kind", ["slab", "paged"])
+def test_lagged_eos_extra_step_is_dropped(net, kind):
+    """A row that ends on EOS is found one step late: the step launched
+    for it meanwhile is dropped (its token never emitted, its page
+    given back), whether another row keeps the engine going or the
+    engine goes idle under it."""
+    prompts = [RNG.randint(0, 64, (1, L)) for L in (6, 7)]
+    free = [_ref(net, p, 12) for p in prompts]
+    eos = int(free[0][6 + 2])            # row 0's 3rd generated token
+    stop = list(free[0][6:]).index(eos) + 1
+    kw = dict(max_batch_size=2)
+    if kind == "paged":
+        kw.update(demand_paging=True, num_pages=8)
+    for others in (True, False):
+        eng = _lag_engine(kind, net, **kw)
+        seen = []
+        h0 = eng.submit(prompts[0], 12, eos_token_id=eos,
+                        on_token=lambda t, h: seen.append(int(t)))
+        h1 = eng.submit(prompts[1], 12) if others else None
+        eng.run_until_idle()
+        assert h0.status == "DONE"
+        assert h0.tokens == list(free[0][6:6 + stop]) == seen
+        want = stop
+        if others:
+            assert h1.status == "DONE"
+            np.testing.assert_array_equal(h1.output_ids, free[1])
+            want += 12
+        assert eng.metrics.tokens_out.value == want
+        assert eng._in_flight is None
+        assert eng.pool.occupancy == 0
+        if kind == "paged":
+            st = eng.page_pool.stats()
+            assert st["pages_in_use"] == 0
+            assert eng.page_pool.free_pages == 8
+            assert st["claims"] == st["releases"] > 0
+        eng.close()
+
+
+@pytest.mark.parametrize("kind", ["slab", "paged"])
+def test_lagged_token_never_reaches_a_readmitted_slot(net, kind):
+    """The step in flight remembers the rows it was launched for by
+    identity: a row finished while its step ran (a cancel here), whose
+    slot is admitted again before that step is read, hands the new
+    row nothing of the old one's."""
+    from paddle_tpu.serving.scheduler import CANCELLED
+
+    eng = _lag_engine(kind, net, max_batch_size=2)
+    pa, pc, pb = (RNG.randint(0, 64, (1, L)) for L in (6, 7, 5))
+    ha, hc = eng.submit(pa, 12), eng.submit(pc, 12)
+    for _ in range(3):
+        eng.step()
+    slot = next(i for i, s in enumerate(eng._seqs)
+                if s is not None and s.handle is ha)
+    assert eng._in_flight.seqs[slot].handle is ha
+    n_a = len(ha.tokens)
+    eng._finish(slot, CANCELLED, reason="client_gone")
+    hb = eng.submit(pb, 9)
+    # around _admit, which would read the step first: the slot is taken
+    # again while the old row's token is still on the device
+    eng._admit_one(eng.scheduler.pop_next())
+    assert eng._seqs[slot].handle is hb
+    assert eng._in_flight.seqs[slot].handle is ha
+    eng.run_until_idle()
+    assert ha.status == "CANCELLED" and len(ha.tokens) == n_a
+    np.testing.assert_array_equal(hb.output_ids, _ref(net, pb, 9))
+    np.testing.assert_array_equal(hc.output_ids, _ref(net, pc, 12))
+    assert eng.pool.occupancy == 0
+    eng.close()
+
+
+@pytest.mark.parametrize("kind", ["slab", "paged"])
+def test_deadline_timeout_with_a_step_in_flight(net, kind):
+    """A running row whose deadline passes while its step is on the
+    device: TIMEOUT, its unread token dropped, the other row exact,
+    nothing leaked."""
+    t = [0.0]
+    eng = _lag_engine(kind, net, max_batch_size=2, clock=lambda: t[0])
+    pa, pb = RNG.randint(0, 64, (1, 6)), RNG.randint(0, 64, (1, 7))
+    ha = eng.submit(pa, 12, deadline_s=5.0)
+    hb = eng.submit(pb, 12)
+    for _ in range(4):
+        eng.step()
+    assert eng._in_flight is not None
+    assert any(s is not None and s.handle is ha
+               for s in eng._in_flight.seqs)
+    n_a = len(ha.tokens)
+    t[0] = 10.0
+    eng.run_until_idle()
+    assert ha.status == "TIMEOUT" and ha.reason == REASON_TIMEOUT
+    assert ha.tokens == list(_ref(net, pa, 12)[6:6 + n_a])
+    np.testing.assert_array_equal(hb.output_ids, _ref(net, pb, 12))
+    assert eng.metrics.timeouts.value == 1
+    assert eng.metrics.tokens_out.value == n_a + 12
+    assert eng._in_flight is None and eng.pool.occupancy == 0
+    if kind == "paged":
+        assert eng.page_pool.pages_in_use == 0
+    eng.close()
+
+
+def test_pages_exhausted_shed_with_a_step_in_flight(net):
+    """Demand growth looks one step ahead of the host (the launch
+    writes one position past the unread token): an arena too small for
+    both rows sheds the one that cannot grow with ``pages_exhausted``
+    while its step is in flight; the survivor's stream is exact and
+    every page comes back."""
+    from paddle_tpu.serving.scheduler import REASON_PAGES_EXHAUSTED
+
+    eng = _lag_engine("paged", net, max_batch_size=2, num_pages=5,
+                      demand_paging=True)
+    pa, pb = RNG.randint(0, 64, (1, 10)), RNG.randint(0, 64, (1, 10))
+    ha, hb = eng.submit(pa, 30), eng.submit(pb, 30)
+    shed_with_lag = []
+    finish = eng._finish
+
+    def spy(slot, status, reason=None):
+        if reason == REASON_PAGES_EXHAUSTED:
+            fl = eng._in_flight
+            shed_with_lag.append(
+                fl is not None and fl.seqs[slot] is eng._seqs[slot])
+        finish(slot, status, reason=reason)
+
+    eng._finish = spy
+    eng.run_until_idle()
+    assert shed_with_lag == [True]
+    shed, winner, pw = (ha, hb, pb) if ha.status == "CANCELLED" \
+        else (hb, ha, pa)
+    assert shed.status == "CANCELLED"
+    assert shed.reason == REASON_PAGES_EXHAUSTED and shed.tokens
+    assert winner.status == "DONE"
+    np.testing.assert_array_equal(winner.output_ids, _ref(net, pw, 30))
+    assert eng.metrics.sheds.by_label() == {REASON_PAGES_EXHAUSTED: 1}
+    assert eng.metrics.tokens_out.value == len(shed.tokens) + 30
+    assert eng._in_flight is None
+    st = eng.page_pool.stats()
+    assert st["pages_in_use"] == 0 and st["claims"] == st["releases"]
+    eng.close()
+
+
+def test_unfed_row_leaves_its_pages_alone(net):
+    """A row whose last token is in flight rides the next launch as a
+    free row does: fed nothing, writing into the garbage page. Its own
+    pages stay what its prefill and decode made them: a second request
+    with the same prompt adopts them from the prefix cache and is
+    exact."""
+    eng = _lag_engine("paged", net, max_batch_size=2, prefix_cache=True)
+    pa = RNG.randint(0, 64, (1, 16))
+    pb = RNG.randint(0, 64, (1, 7))
+    ha, hb = eng.submit(pa, 4), eng.submit(pb, 12)
+    eng.step()
+    slot = next(i for i, s in enumerate(eng._seqs) if s.handle is ha)
+    first_page = eng._row_pages[slot][0]
+    before = eng._tier_read_page(first_page)
+    eng.run_until_idle()   # ha's last step: hb launched, ha unfed
+    # a full prompt page: published, so still ha's bytes and no one's
+    for was, now in zip(before, eng._tier_read_page(first_page)):
+        np.testing.assert_array_equal(was, now)
+    hits0 = eng.prefix_cache.hits.value
+    hc = eng.submit(pa, 6)
+    eng.run_until_idle()
+    assert eng.prefix_cache.hits.value == hits0 + 1
+    np.testing.assert_array_equal(ha.output_ids, _ref(net, pa, 4))
+    np.testing.assert_array_equal(hb.output_ids, _ref(net, pb, 12))
+    np.testing.assert_array_equal(hc.output_ids, _ref(net, pa, 6))
+    eng.close()
+
+
+@pytest.mark.parametrize("kind", ["slab", "paged"])
+def test_full_batch_overlaps_every_step_but_the_first(net, kind):
+    """A full batch and no admission after the first iteration: every
+    decode launch but the first is made with a step in flight, and the
+    clock readings get a sample a step."""
+    rows, new = 4, 12
+    eng = _lag_engine(kind, net, max_batch_size=rows)
+    hs = [eng.submit(RNG.randint(0, 64, (1, 5 + i)), new)
+          for i in range(rows)]
+    steps = eng.run_until_idle()
+    assert all(len(h.tokens) == new for h in hs)
+    rep = eng.metrics.report()
+    launches = rep["resident_tokens"]["count"]
+    # the prefill gives the first token, the last step launches nothing
+    assert launches == new - 1 == steps - 1
+    assert rep["counters"]["steps_overlapped"] == launches - 1
+    assert rep["counters"]["steps_overlapped"] / launches > 0.8
+    assert rep["read_wait"]["count"] == launches
+    assert rep["itl"]["count"] == rows * launches
+    # a sample starts at a read's return: not at the first two launches
+    assert rep["host_gap"]["count"] == launches - 2
+    assert rep["read_wait"]["sum"] >= 0.0
+    eng.close()
+
+
+@pytest.mark.parametrize("kind", ["slab", "paged"])
+def test_second_decode_trace_leaves_the_net_concrete(kind):
+    """Weights placed over a mesh: the first launch's ``prev`` is an
+    upload, the second's comes back from the program, placed as the
+    weights are, and jit traces the decode body again. The net holds
+    concrete weights after that trace too, and the stream is exact."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    paddle.seed(5)
+    mine = LlamaForCausalLM(LlamaConfig.tiny(
+        vocab_size=64, hidden_size=32, intermediate_size=64,
+        num_hidden_layers=2, num_attention_heads=4))
+    mine.eval()
+    over = NamedSharding(Mesh(np.array(jax.devices()), ("mp",)), P())
+    for p in mine.parameters():
+        p.value = jax.device_put(p.value, over)
+    prompt = RNG.randint(0, 64, (1, 6))
+    eng = _lag_engine(kind, mine, max_batch_size=2)
+    h = eng.generate([prompt], 6)[0]
+    eng.close()
+    for _, p in mine.named_parameters():
+        assert isinstance(p.value, jax.Array)
+        np.asarray(p.value)          # a tracer would raise here
+    np.testing.assert_array_equal(h.output_ids, _ref(mine, prompt, 6))
+
+
+def test_speculation_never_has_a_step_in_flight(net):
+    from paddle_tpu.serving import SpeculativeDecoder
+
+    eng = ServingEngine(net, max_batch_size=2, max_seq_len=64,
+                        min_bucket=8,
+                        speculative=SpeculativeDecoder(exit_layer=1, k=2))
+    hs = [eng.submit(RNG.randint(0, 64, (1, 6)), 8) for _ in range(3)]
+    while eng.scheduler.depth or eng.active_slots:
+        eng.step()
+        assert eng._in_flight is None
+    assert all(h.status == "DONE" for h in hs)
+    rep = eng.metrics.report()
+    assert rep["counters"]["steps_overlapped"] == 0
+    assert rep["read_wait"]["count"] == 0
+    assert rep["counters"]["speculative_rounds"] > 0
+    eng.close()
+
+
+def test_idle_engine_has_nothing_in_flight_and_reloads(net, tmp_path):
+    """After ``run_until_idle`` no step is in flight, so a staged
+    reload applies at once; one committed mid-run waits for the rows
+    AND their last lagged tokens."""
+    from paddle_tpu.checkpoint import CheckpointManager
+
+    prompt = RNG.randint(0, 64, (1, 6))
+    nets, refs = [], []
+    for seed in (5, 9):     # a reload rewrites its net: none shared
+        paddle.seed(seed)
+        nets.append(LlamaForCausalLM(net.config))
+        nets[-1].eval()
+        refs.append(_ref(nets[-1], prompt, 8))
+    mgr = CheckpointManager(str(tmp_path), network=nets[1],
+                            async_saves=False)
+    mgr.save(1, blocking=True)
+    mgr.close()
+    eng = _lag_engine("paged", nets[0], max_batch_size=2)
+    h_old = eng.submit(prompt, 8)
+    for _ in range(3):
+        eng.step()
+    assert eng._in_flight is not None
+    staged = eng.commit_reload(eng.prepare_reload(str(tmp_path)))
+    assert eng.reload_in_progress and eng.weights_version == "v0"
+    eng.run_until_idle()
+    assert eng._in_flight is None and eng._read_done is None
+    assert staged.outcome == "applied" and not eng.reload_in_progress
+    np.testing.assert_array_equal(h_old.output_ids, refs[0])
+    h_new = eng.generate([prompt], 8)[0]
+    assert eng._in_flight is None
+    np.testing.assert_array_equal(h_new.output_ids, refs[1])
+    assert not np.array_equal(refs[0], refs[1])
+    # idle again: the next one applies inside commit_reload itself
+    again = eng.reload_weights(str(tmp_path))
+    assert again.outcome == "applied" and eng.generation == 2
+    eng.close()
+
+
 # ------------------------------------------------------------- int8 KV
 def test_cache_dtype_validated_at_api_seam(net):
     """An unknown cache_dtype must fail AT THE SEAM with the allowed

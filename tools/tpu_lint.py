@@ -273,6 +273,7 @@ def graph_reports(config=None, verbose=False, memory=False,
         jnp.zeros((B,), jnp.int32), eng._flat,
         jnp.zeros((B,), jnp.int32), jnp.float32(1.0),
         jax.random.PRNGKey(0),
+        jnp.zeros((B,), jnp.int32), jnp.ones((B,), bool),
         graph="serving_decode_step",
         donate_argnums=(3,),  # the accelerator path donates the slab
         config=cfg,
@@ -283,6 +284,7 @@ def graph_reports(config=None, verbose=False, memory=False,
         jnp.zeros((B,), jnp.int32), eng._flat,
         jnp.zeros((B,), jnp.int32), jnp.float32(1.0),
         jax.random.PRNGKey(0),
+        jnp.zeros((B,), jnp.int32), jnp.ones((B,), bool),
         graph="serving_decode_step", donate_argnums=(3,),
     )
     restore()
