@@ -548,13 +548,14 @@ def lower_7b_check():
 
 
 def tune_kernels():
-    """``--tune``: measured-search the kernel block configs over the
-    flagship + serving-decode shapes and print ONE self-describing JSON
+    """``--tune``: measured-search flash attention's block configs at
+    the flagship shapes (and the fp8 matmul's fp8-vs-bf16 verdict) and
+    print ONE self-describing JSON
     record — chosen configs, per-candidate timings, and cache
     accounting (a repeat run on a tuned device reports 100% cache hits
     and zero re-measurements). Results persist in the tune cache
-    (tools/kernel_tune_cache.json or PADDLE_TPU_TUNE_CACHE), which the
-    kernels' selection paths read at trace time."""
+    (tools/kernel_tune_cache.json or PADDLE_TPU_TUNE_CACHE), which
+    flash attention's selection reads at trace time."""
     from tools.kernel_tune import run_tune
 
     from paddle_tpu.parallel import layout as layout_mod

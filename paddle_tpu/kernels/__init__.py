@@ -6,18 +6,13 @@ a composed-jnp fallback (CPU/CI); call sites pick automatically.
 
 Selection is measurement-driven: ``autotune`` holds the block-size
 autotuner (measured search + persistent per-device result cache, see
-``tools/kernel_tune.py``); flash attention and the fusion kernels
-(``fused_rope_attention``, ``fused_norm_matmul``) resolve their block
-configs through it, and publish selection/fallback decisions as
-``paddle_kernels_*`` registry metrics.
+``tools/kernel_tune.py``); flash attention resolves its block config
+through it and publishes its selection, and every kernel its
+fallbacks, as ``paddle_kernels_*`` registry metrics.
 """
 from . import autotune  # noqa: F401
 from . import flash_attention  # noqa: F401
 from . import fused_adam  # noqa: F401
-from . import fused_norm_matmul  # noqa: F401
-from . import fused_rope_attention  # noqa: F401
-from . import int8_matmul  # noqa: F401
-from . import paged_attention  # noqa: F401
 from . import rms_norm  # noqa: F401
 from . import rope  # noqa: F401
 

@@ -8,8 +8,8 @@ measured-search-over-schedules (PAPERS.md):
 
 - **Keys.** Results are stored per ``(kernel, shape-signature,
   device-kind)``. Shape signatures are canonical strings built by the
-  per-kernel helpers below (``flash_sig`` / ``rope_attention_sig`` /
-  ``norm_matmul_sig``); device kinds are normalized
+  per-kernel helpers below (``flash_sig``, ``fp8_matmul_sig``); device
+  kinds are normalized
   (``jax.devices()[0].device_kind`` lowercased, spaces -> dashes, known
   aliases folded: a v5e chip reports "TPU v5 lite").
 - **Measurement.** :func:`measured_search` times every candidate with
@@ -105,30 +105,6 @@ def spmd_refusal(kernel):
 
 def flash_sig(b, sq, sk, h, d, causal):
     return f"b{b}_sq{sq}_sk{sk}_h{h}_d{d}_c{int(bool(causal))}"
-
-
-def rope_attention_sig(b, s, h, d):
-    return f"b{b}_s{s}_h{h}_d{d}"
-
-
-def norm_matmul_sig(rows, hidden, n_out):
-    return f"r{rows}_h{hidden}_n{n_out}"
-
-
-def paged_attention_sig(b, pages, page_size, h, kvh, d, quant=False):
-    """Paged decode attention: B decode rows, a [B, pages] page table
-    over page_size-token pages, H query heads over kvh KV heads.
-    ``quant=True`` tags the int8-arena flavor (its own tuning entry —
-    int8 page loads + in-VMEM dequant have a different profile)."""
-    base = f"b{b}_p{pages}_ps{page_size}_h{h}_kv{kvh}_d{d}"
-    return base + ("_q8" if quant else "")
-
-
-def int8_matmul_sig(rows, hidden, n_out):
-    """Weight-only int8 matmul (decode projections / lm_head): rows x
-    hidden activations against an int8 [hidden, n_out] weight with
-    per-output-channel scales."""
-    return f"r{rows}_h{hidden}_n{n_out}"
 
 
 def fp8_matmul_sig(m, k, n):
@@ -419,93 +395,11 @@ def flash_config_legal(sq, sk, config):
     return sq % bq == 0 and sk % bkm == 0 and sk % bk == 0 and bkm % bk == 0
 
 
-def rope_attention_candidates(s, h=None, d=None):
-    """block_q candidates for the fused rope+attention kernel (one
-    q-row block per grid step; k/v ride whole). Smaller blocks bound the
-    bq x S score tile's VMEM footprint; larger amortize the k/v loads."""
-    return [{"block_q": b} for b in _divisors(s, (512, 256, 128, 64, 32,
-                                                  16, 8))]
-
-
-def rope_attention_config_legal(s, config):
-    try:
-        bq = int(config["block_q"])
-    except (KeyError, TypeError, ValueError):
-        return False
-    return bq >= 1 and s % bq == 0
-
-
-def norm_matmul_candidates(rows, n_out):
-    """(block_rows, block_cols) candidates for the rms_norm+matmul
-    epilogue kernel."""
-    brs = _divisors(rows, (256, 128, 64, 32, 16, 8, 4, 2, 1))
-    bcs = _divisors(n_out, (2048, 1024, 512, 256, 128))
-    return [{"block_rows": br, "block_cols": bc}
-            for br in brs for bc in bcs]
-
-
-def norm_matmul_config_legal(rows, n_out, config):
-    try:
-        br = int(config["block_rows"])
-        bc = int(config["block_cols"])
-    except (KeyError, TypeError, ValueError):
-        return False
-    return (br >= 1 and bc >= 1 and rows % br == 0 and n_out % bc == 0)
-
-
-def paged_attention_candidates(kv_heads, quant=False):
-    """``block_kvh`` candidates for the paged decode attention kernel:
-    KV heads handled per grid step. A page block is
-    ``[page_size, block_kvh, D]`` of a ``[.., page_size, kvH, D]``
-    arena, and the chip's compiler takes a second-minor block dim only
-    when it is the whole axis or a multiple of 8 — so the candidates
-    are all of ``kvH`` and its multiple-of-8 divisors. Smaller blocks
-    bound the per-step VMEM footprint; larger ones amortize the
-    per-page table-indexed loads across more heads. An int8 arena's
-    scale block is ``[page_size, block_kvh]`` with the heads on the
-    MINOR axis, where only the whole axis (or a multiple of 128) is
-    taken: ``quant`` leaves the one candidate."""
-    if quant:
-        return [{"block_kvh": kv_heads}]
-    return [{"block_kvh": b}
-            for b in sorted({kv_heads, *_divisors(kv_heads, (32, 16, 8))},
-                            reverse=True)]
-
-
-def paged_attention_config_legal(kv_heads, config, quant=False):
-    try:
-        bk = int(config["block_kvh"])
-    except (KeyError, TypeError, ValueError):
-        return False
-    return {"block_kvh": bk} in paged_attention_candidates(kv_heads, quant)
-
-
-def int8_matmul_candidates(rows, n_out):
-    """(block_rows, block_cols) candidates for the weight-only int8
-    matmul — same output-tiling space as the norm+matmul epilogue
-    kernel (the contraction dim rides whole either way)."""
-    return norm_matmul_candidates(rows, n_out)
-
-
-def int8_matmul_config_legal(rows, n_out, config):
-    return norm_matmul_config_legal(rows, n_out, config)
-
-
 def fp8_matmul_candidates(m=None, k=None, n=None):
     """The fp8 train-matmul path has no block-size knob (XLA owns the
     tiling of a plain fp8 dot); the single candidate exists so the
     tuner can record the measured fp8-vs-bf16 verdict for the shape."""
     return [{"format": "e4m3"}]
-
-
-CANDIDATE_GENERATORS = {
-    "flash_attention": flash_block_candidates,
-    "rope_attention": rope_attention_candidates,
-    "rms_norm_matmul": norm_matmul_candidates,
-    "paged_attention": paged_attention_candidates,
-    "int8_matmul": int8_matmul_candidates,
-    "fp8_matmul": fp8_matmul_candidates,
-}
 
 
 # ---------------------------------------------------------- measured search
@@ -584,6 +478,6 @@ def measured_search(candidates, build, *, iters=3, windows=3, warmup=1,
 # The cache-or-measure driver lives in tools/kernel_tune.py
 # (``tune_shape``): it owns the composed-baseline interleaving and the
 # fused-vs-composed verdict (entries carry ``fused_beats_composed``;
-# the selection paths refuse to activate a fused kernel the tuner
-# measured as slower), and this module stays the mechanism layer
+# flash's selection keeps composed where the tuner measured the
+# kernel slower), and this module stays the mechanism layer
 # (search + cache + metrics) with exactly one home for each piece.

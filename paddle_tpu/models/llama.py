@@ -28,6 +28,7 @@ from .. import nn
 from ..nn import functional as F
 from ..nn.functional.attention import grouped_query_cache_attention
 from ..incubate.nn import functional as IF
+from ..quantization import kv as qkv
 
 
 @dataclass
@@ -99,6 +100,11 @@ def causal_lm_loss(logits, labels, ignore_index=-100):
     )
 
 
+def _value(x):
+    """The jax value of a Tensor, or ``x`` itself."""
+    return x.value if hasattr(x, "value") else x
+
+
 def _cache_attention(q, kk, vv, mask, attn_mask):
     """The attention every cache branch ends in: ``q`` ``[B, S, H, D]``
     over the dense cache view ``kk``/``vv`` ``[B, S_k, kvH, D]`` under
@@ -111,9 +117,7 @@ def _cache_attention(q, kk, vv, mask, attn_mask):
     streams are pinned equal (paged == slab == ``generate``) and a
     grouped contraction is not bitwise the repeated one."""
     if attn_mask is not None:
-        am = (attn_mask.value if hasattr(attn_mask, "value")
-              else jnp.asarray(attn_mask))
-        mask = mask + am
+        mask = mask + jnp.asarray(_value(attn_mask))
     if kk.shape[2] == q.shape[2]:
         return F.scaled_dot_product_attention(
             q, kk, vv, attn_mask=Tensor(mask), is_causal=False,
@@ -147,8 +151,8 @@ class LlamaAttention(nn.Layer):
         the step's k/v is scattered at each row's (page, offset) and
         attention runs over the table-gathered logical cache (S must be
         1 — the paged decode step). Page id 0 is the reserved garbage
-        page; a tuned Pallas paged-attention kernel replaces the
-        HBM-materializing gather when the tune cache selects one.
+        page. The addressing of all three modes is
+        ``quantization.kv.write_and_view``.
 
         Under GQA every cache path contracts the query heads, grouped
         by their KV head, against the cache as it is stored
@@ -173,145 +177,45 @@ class LlamaAttention(nn.Layer):
 
     def _attn_core(self, q, k, v, rope_cos, rope_sin, attn_mask, cache,
                    pos, page_table):
-        """Rope, cache write, page gather and the attention itself
-        (with a cache: ``_cache_attention``, grouped under GQA, or a
-        selected kernel; without: GQA repeat + SDPA/flash): ``(out [B,
-        S, H, D], new_cache)``, ``new_cache`` None without a cache."""
+        """Rope, then the attention itself: without a cache GQA repeat
+        + SDPA/flash; with one the cache write and view
+        (``kv.write_and_view``: slab, per-row slab or page arena),
+        the position mask and ``_cache_attention``, grouped under GQA.
+        Returns ``(out [B, S, H, D], new_cache)``, ``new_cache`` None
+        without a cache."""
         cfg = self.cfg
-        B, S = int(q.shape[0]), int(q.shape[1])
-        if (cache is None and attn_mask is None
-                and cfg.kv_heads == cfg.num_attention_heads
-                and rope_cos is not None and rope_sin is not None):
-            # tune-cache OPT-IN fused rope+attention (rotation applied
-            # inside the attention kernel's q/k load — no rotated
-            # copies in HBM); with no measured entry for this shape the
-            # unfused path below runs unchanged
-            from ..kernels.fused_rope_attention import (
-                rope_attention_apply,
-                rope_attention_select,
-            )
-
-            sel = rope_attention_select(B, S, cfg.num_attention_heads,
-                                        cfg.head_dim)
-            if sel is not None:
-                out = rope_attention_apply(
-                    q, k, v, rope_cos, rope_sin, causal=True,
-                    block_q=sel["block_q"],
-                )
-                return out, None
+        S = int(q.shape[1])
         pos_ids = None
         if cache is not None:
-            p0 = jnp.asarray(pos.value if hasattr(pos, "value") else pos)
-            if p0.ndim:  # per-row decode depths: gather rope rows by id
-                pos_ids = p0[:, None] + jnp.arange(S)[None, :]
+            p = jnp.asarray(_value(pos))
+            if p.ndim:  # per-row decode depths: gather rope rows by id
+                pos_ids = p[:, None] + jnp.arange(S)[None, :]
         q, k, _ = IF.fused_rotary_position_embedding(
             q, k, None, sin=rope_sin, cos=rope_cos,
             position_ids=pos_ids, rotary_emb_base=cfg.rope_theta,
         )
-        if cache is not None and page_table is not None:
-            if S != 1:
-                raise ValueError(
-                    f"paged decode feeds one token per row (S == 1), "
-                    f"got S={S}"
-                )
-            from ..kernels import autotune
-            from ..kernels.paged_attention import (
-                gather_pages_dense,
-                paged_attention_apply,
-                paged_attention_select,
+        if cache is None:
+            if cfg.kv_heads != cfg.num_attention_heads:
+                rep = cfg.num_attention_heads // cfg.kv_heads
+                k = k.repeat_interleave(rep, axis=2)
+                v = v.repeat_interleave(rep, axis=2)
+            out = F.scaled_dot_product_attention(
+                q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
+                training=self.training,
             )
-            from ..quantization import kv as qkv
-
-            k_pages, v_pages = cache
-            tbl = jnp.asarray(
-                page_table.value if hasattr(page_table, "value")
-                else page_table
-            )
-            ps = int(k_pages.shape[1])
-            P = int(tbl.shape[1])
-            p = jnp.asarray(pos.value if hasattr(pos, "value") else pos)
-            # scatter this step's k/v at each row's (page, offset);
-            # free rows land on the reserved garbage page 0 (an int8
-            # arena quantizes-on-scatter — quantization/kv.py). The
-            # scattered bytes must be BITWISE what prefilling this
-            # position would write: the serving prefix cache publishes
-            # decode-written pages as reusable prefix KV (a bf16 arena
-            # re-rounds per position; int8 pins via the quantizer's
-            # bf16-grid scales — tests/test_prefix_cache.py)
-            pp = jnp.take_along_axis(tbl, (p // ps)[:, None],
-                                     axis=1)[:, 0]
-            po = p % ps
-            k_pages = qkv.write_paged(k_pages, k.value[:, 0], pp, po)
-            v_pages = qkv.write_paged(v_pages, v.value[:, 0], pp, po)
-            # the fused kernel bakes in pure positional masking — an
-            # explicit attn_mask must decode through the composed path
-            sel = None if attn_mask is not None else (
-                paged_attention_select(
-                    B, P, ps, cfg.num_attention_heads, cfg.kv_heads,
-                    cfg.head_dim,
-                    quantized=qkv.is_quantized(k_pages),
-                )
-            )
-            if sel is not None:
-                out = paged_attention_apply(
-                    q, k_pages, v_pages, tbl, p, config=sel
-                )
-                return out, (k_pages, v_pages)
-            # default: composed gather + the SAME _cache_attention the
-            # slab branches below decode through — token streams stay
-            # bit-identical to the slab engine and net.generate (extra
-            # masked columns contribute exact zeros; int8 arenas
-            # dequant-on-gather to the compute dtype)
-            autotune.note_selection("paged_attention", "composed:gather")
-            kk = Tensor(gather_pages_dense(k_pages, tbl, q.value.dtype))
-            vv = Tensor(gather_pages_dense(v_pages, tbl, q.value.dtype))
-            S_virt = P * ps
-            cols = p[:, None] + jnp.arange(S)[None, :]
-            valid = jnp.arange(S_virt)[None, None, :] <= cols[:, :, None]
-            mask = jnp.where(valid, 0.0, -jnp.inf)[:, None, :, :]
-            out = _cache_attention(q, kk, vv, mask, attn_mask)
-            return out, (k_pages, v_pages)
-        if cache is not None:
-            from ..quantization import kv as qkv
-
-            k_cache, v_cache = cache
-            S_max = k_cache.shape[1]
-            p = jnp.asarray(pos.value if hasattr(pos, "value") else pos)
-            if p.ndim == 0:
-                # whole-batch position (generate's prefill + scan)
-                k_cache = qkv.write_at_pos(k_cache, k.value, p)
-                v_cache = qkv.write_at_pos(v_cache, v.value, p)
-                # mask[t, s]: token (p+t) may read cache slot s iff s <= p+t
-                valid = (
-                    jnp.arange(S_max)[None, :]
-                    <= (p + jnp.arange(S))[:, None]
-                )
-                mask = jnp.where(valid, 0.0, -jnp.inf)[None, None, :, :]
-            else:
-                # per-row positions [B] (continuous batching: each batch
-                # slot sits at its own decode depth) — scatter the new
-                # k/v at every row's own offset
-                rows = jnp.arange(B)[:, None]
-                cols = p[:, None] + jnp.arange(S)[None, :]  # [B, S]
-                k_cache = qkv.write_at_rows(k_cache, k.value, rows, cols)
-                v_cache = qkv.write_at_rows(v_cache, v.value, rows, cols)
-                valid = jnp.arange(S_max)[None, None, :] <= cols[:, :, None]
-                mask = jnp.where(valid, 0.0, -jnp.inf)[:, None, :, :]
-            # int8 caches dequantize-on-read to the compute dtype; plain
-            # caches pass through untouched (SDPA upcasts at the matmul)
-            kk = Tensor(qkv.read_dense(k_cache, q.value.dtype))
-            vv = Tensor(qkv.read_dense(v_cache, q.value.dtype))
-            out = _cache_attention(q, kk, vv, mask, attn_mask)
-            return out, (k_cache, v_cache)
-        if cfg.kv_heads != cfg.num_attention_heads:
-            rep = cfg.num_attention_heads // cfg.kv_heads
-            k = k.repeat_interleave(rep, axis=2)
-            v = v.repeat_interleave(rep, axis=2)
-        out = F.scaled_dot_product_attention(
-            q, k, v, attn_mask=attn_mask, is_causal=attn_mask is None,
-            training=self.training,
-        )
-        return out, None
+            return out, None
+        # every cache mode ends in the SAME _cache_attention, so paged,
+        # slab and net.generate token streams stay bit-identical (masked
+        # columns contribute exact zeros); int8 caches come back
+        # dequantized to the compute dtype, plain ones as stored (the
+        # attention upcasts at the matmul)
+        table = None if page_table is None else jnp.asarray(
+            _value(page_table))
+        cache, (kk, vv), cols = qkv.write_and_view(
+            cache, (k.value, v.value), p, table, q.value.dtype)
+        mask = qkv.position_mask(cols, kk.shape[1])
+        out = _cache_attention(q, Tensor(kk), Tensor(vv), mask, attn_mask)
+        return out, cache
 
 
 class LlamaMLP(nn.Layer):
@@ -370,8 +274,9 @@ class LlamaModel(nn.Layer):
         With ``page_table`` the caches are per-layer page arenas
         ([num_pages, page_size, kvH, D] x2) and decode attention runs
         through the table (serving's paged KV pool).
-        ``apply_final_norm=False`` returns the pre-norm hidden state so
-        a fused norm+matmul head can absorb ``self.norm``.
+        ``apply_final_norm=False`` returns the pre-norm hidden state
+        (a caller that wants the caches alone skips the norm:
+        ``serving/speculative.py``'s chunk-write pass).
         ``exit_layer=N`` runs only the first N decoder layers (the
         self-speculative draft seam: the truncated stack + the shared
         head IS the draft model — ``caches`` then carries N entries)."""
@@ -392,7 +297,7 @@ class LlamaModel(nn.Layer):
             cos, sin = build_rope_cache(
                 S_max, cfg.head_dim, base=cfg.rope_theta
             )
-            p = jnp.asarray(pos.value if hasattr(pos, "value") else pos)
+            p = jnp.asarray(_value(pos))
             if p.ndim == 0:
                 # rope rows for the tokens being fed: [p, p+S)
                 cos = jax.lax.dynamic_slice_in_dim(cos, p, S, axis=1)
@@ -445,57 +350,25 @@ class LlamaForCausalLM(LlamaFlopsMixin, nn.Layer):
                 config.hidden_size, config.vocab_size, bias_attr=False
             )
 
-    def _head_fusion(self, n_rows):
-        """Tune-cache OPT-IN fused rms_norm+lm_head config (None keeps
-        the unfused norm -> linear path byte-identical). A quantized
-        head (``quantize_for_serving``: int8 weight + scale buffers, no
-        dense ``.weight``) owns its own fused/composed selection — the
-        float norm+matmul fusion cannot absorb it."""
-        if self.lm_head is None or getattr(
-            self.lm_head, "weight", None
-        ) is None:
-            return None
-        from ..kernels.fused_norm_matmul import head_fusion_select
-
-        return head_fusion_select(
-            n_rows, self.config.hidden_size, self.config.vocab_size
-        )
-
-    def _fused_head(self, h, sel):
-        from ..kernels.fused_norm_matmul import rms_norm_matmul_apply
-
-        return rms_norm_matmul_apply(
-            h, self.model.norm.weight, self.lm_head.weight,
-            eps=self.config.rms_norm_eps,
-            block_rows=sel["block_rows"], block_cols=sel["block_cols"],
-        )
-
-    def _head(self, h, sel):
+    def _head(self, h):
         """Hidden state to logits, always under scope ``lm_head``: the
-        ``lm_head`` layer opens it itself; the fused norm+matmul kernel
-        and the tied head (the embedding's transpose) are no layer."""
-        if sel is None and self.lm_head is not None:
+        ``lm_head`` layer opens it itself; the tied head (the
+        embedding's transpose) is no layer."""
+        if self.lm_head is not None:
             return self.lm_head(h)
         with jax.named_scope("lm_head"):
-            if sel is not None:
-                return self._fused_head(h, sel)
             return F.linear(h, self.model.embed_tokens.weight.t())
 
     def forward(self, input_ids, attn_mask=None, caches=None, pos=None,
                 page_table=None, exit_layer=None):
-        B, S = int(input_ids.shape[0]), int(input_ids.shape[1])
-        sel = self._head_fusion(B * S)
         if caches is not None:
             h, new_caches = self.model(
                 input_ids, attn_mask, caches=caches, pos=pos,
-                apply_final_norm=sel is None, page_table=page_table,
-                exit_layer=exit_layer,
+                page_table=page_table, exit_layer=exit_layer,
             )
-            return self._head(h, sel), new_caches
-        h = self.model(input_ids, attn_mask,
-                       apply_final_norm=sel is None,
-                       exit_layer=exit_layer)
-        return self._head(h, sel)
+            return self._head(h), new_caches
+        h = self.model(input_ids, attn_mask, exit_layer=exit_layer)
+        return self._head(h)
 
     def generate(self, input_ids, max_new_tokens=32, do_sample=False,
                  temperature=1.0, top_k=0, top_p=1.0, eos_token_id=None,

@@ -55,6 +55,7 @@ from ..core.tensor import Tensor
 from ..incubate.nn import functional as IF
 from ..kernels.flash_attention import flash_attention_fwd
 from ..nn import initializer as I
+from ..quantization import kv as qkv
 
 _F32 = jnp.float32
 
@@ -270,7 +271,7 @@ def mla_core(q, ckv, k_rope, w_kvb, cos, sin, *, cfg, cache=None, pos=None,
     more materialised (``absorbed`` overrides). Returns ``(out [B, S,
     H, dv], new_cache)``."""
     dn = cfg.qk_nope_head_dim
-    b, s = q.shape[:2]
+    s = q.shape[1]
     q_nope = q[..., :dn]
     q_rope = _rope(q[..., dn:], cos[:, :, None], sin[:, :, None])
     latent = jnp.concatenate([ckv, _rope(k_rope, cos, sin)], -1)
@@ -284,39 +285,19 @@ def mla_core(q, ckv, k_rope, w_kvb, cos, sin, *, cfg, cache=None, pos=None,
             mask = jnp.where(jnp.tril(jnp.ones((s, s), bool)), 0.0,
                              -jnp.inf)[None, None]
         return attend(q_nope, q_rope, latent, w_kvb, mask, scale), None
-    p = jnp.asarray(pos)
     fresh = latent
     latent = jnp.pad(latent.astype(cache.dtype), (
         (0, 0), (0, 0), (0, cache.shape[-1] - latent.shape[-1])))
-    if page_table is not None:
-        if s != 1:
-            raise ValueError(
-                f"paged decode feeds one token per row (S == 1), got S={s}"
-            )
-        ps = cache.shape[1]
-        page = jnp.take_along_axis(page_table, (p // ps)[:, None],
-                                   axis=1)[:, 0]
-        cache = cache.at[page, p % ps].set(latent[:, 0])
-        view = cache[page_table].reshape(b, -1, cache.shape[-1])
-        cols = p[:, None]
-    elif p.ndim == 0:
-        z = jnp.zeros((), p.dtype)
-        cache = jax.lax.dynamic_update_slice(cache, latent, (z, p, z))
-        view = cache
-        cols = (p + jnp.arange(s))[None]
-        if s == cache.shape[1] and not absorbed:
-            # a chunk as long as its block can only start at 0 (the
-            # engines' prefill programs): plain causal attention among
-            # the fresh tokens, no mask over the block
-            return attend(q_nope, q_rope, fresh, w_kvb, None,
-                          scale), cache
-    else:
-        cols = p[:, None] + jnp.arange(s)[None]
-        cache = cache.at[jnp.arange(b)[:, None], cols].set(latent)
-        view = cache
-    # token t of a row may read slot k iff k <= its position
-    valid = jnp.arange(view.shape[1])[None, None, :] <= cols[:, :, None]
-    mask = jnp.where(valid, 0.0, -jnp.inf)[:, None]
+    p = jnp.asarray(pos)
+    (cache,), (view,), cols = qkv.write_and_view(
+        (cache,), (latent,), p, page_table)
+    if (page_table is None and p.ndim == 0 and s == cache.shape[1]
+            and not absorbed):
+        # a chunk as long as its block can only start at 0 (the
+        # engines' prefill programs): plain causal attention among
+        # the fresh tokens, no mask over the block
+        return attend(q_nope, q_rope, fresh, w_kvb, None, scale), cache
+    mask = qkv.position_mask(cols, view.shape[1])
     return attend(q_nope, q_rope, view, w_kvb, mask, scale), cache
 
 

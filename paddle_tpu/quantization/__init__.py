@@ -7,8 +7,8 @@ graph with a straight-through estimator, exactly the reference's
 QAT/PTQ training semantics; ``convert`` bakes the final scales into
 ObservedLayers. SERVING-time quantization is REAL narrow-dtype
 execution: ``quantize_for_serving`` converts the weights to
-(int8, per-channel scale) pairs executed by the Pallas weight-only
-matmul (``kernels/int8_matmul``), and ``kv.QuantizedKV`` stores the
+(int8, per-channel scale) pairs executed as dequant -> matmul
+(``serving.int8_matmul_composed``), and ``kv.QuantizedKV`` stores the
 serving KV caches as int8 values + per-token scales (the paged pools'
 ``cache_dtype="int8"``), halving weight and KV HBM again under bf16.
 """

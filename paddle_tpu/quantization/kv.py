@@ -21,10 +21,10 @@ Design contract (every call site shares these invariants):
   — so the SAME token quantizes identically in ``net.generate``, the
   slab engine and the paged engine (quantized token streams stay
   exact-equal across all three; tier-1-pinned).
-- **Dequant-on-read**: the composed attention paths dequantize the
-  gathered cache to the compute dtype right before the masked SDPA;
-  the tuned paged-attention kernel dequantizes page blocks in VMEM
-  instead (the int8 arrays are what crosses HBM either way).
+- **Dequant-on-read**: the attention paths dequantize the cache view
+  (the slab itself, or the table-gathered pages) to the compute dtype
+  right before the masked attention; the int8 arrays are what crosses
+  HBM.
 - Zero-initialized storage dequantizes to exact zeros (garbage pages /
   masked columns keep contributing exact 0 through the fp32 softmax —
   the discipline that makes recycled slots safe without scrubbing).
@@ -139,9 +139,11 @@ def kv_token_bytes(kv_heads, head_dim, dtype):
 
 # ------------------------------------------------------------- cache writes
 #
-# Each helper mirrors one existing bf16 write site in models/llama.py /
-# the serving adopt programs, handling both plain cache arrays (exactly
-# today's op sequence — byte-identical behavior) and QuantizedKV.
+# One helper a write or read of ONE cache array, plain (cast to the
+# cache's dtype, nothing else) or QuantizedKV (quantize-on-write,
+# dequant-on-read), whatever its trailing shape: ``[.., kvH, D]`` or one
+# ``[.., cache_dim]`` latent array. The decoders reach them through
+# :func:`write_and_view`, which owns the addressing.
 
 
 def write_at_pos(cache, val, pos):
@@ -191,6 +193,94 @@ def read_dense(cache, dtype):
     if is_quantized(cache):
         return dequantize_kv(cache.q, cache.scale, dtype)
     return cache
+
+
+def gather_pages(pages, page_table):
+    """``[N, ps, ...]`` arena + ``[B, P]`` table -> ``[B, P * ps, ...]``
+    logical cache: the paged read, a copy in HBM of every page the
+    table names."""
+    b, p = page_table.shape
+    return pages[page_table].reshape((b, p * pages.shape[1])
+                                     + pages.shape[2:])
+
+
+def gather_pages_dense(pages, page_table, dtype):
+    """The paged read for either arena flavor. Plain arrays: exactly
+    :func:`gather_pages` (no cast; attention upcasts at the matmul).
+    Quantized arenas: gather the int8 values and their scales, then
+    dequantize-on-gather to the compute ``dtype`` — the int8 bytes are
+    what crossed HBM."""
+    if not is_quantized(pages):
+        return gather_pages(pages, page_table)
+    return dequantize_kv(gather_pages(pages.q, page_table),
+                         gather_pages(pages.scale, page_table),
+                         dtype)  # tpu-lint: quant
+
+
+# --------------------------------------------------------- cache addressing
+
+
+def write_and_view(caches, fresh, pos, page_table=None, dtype=None):
+    """THE cache addressing, for every decoder: write a step's new
+    tokens into one layer's cache arrays and give back what attention
+    reads. ``caches`` is the layer's tuple of arrays (Llama: K and V,
+    plain or :class:`QuantizedKV`; a latent-attention net: one array),
+    ``fresh`` the matching tuple of ``[B, S, ...]`` payloads. Three
+    modes, told apart by what the caller holds:
+
+    - scalar ``pos``: a block or slab ``[B, S_max, ...]``, the tokens
+      land at ``[pos, pos + S)`` of every row (prefill, a chunk at an
+      offset, whole-batch decode); the view is the cache.
+    - ``[B]`` ``pos``: a slab, row ``r``'s tokens land at ``[pos[r],
+      pos[r] + S)`` (continuous batching); the view is the cache.
+    - ``page_table`` ``[B, P]`` with ``[B]`` ``pos``: a page arena
+      ``[pages, page_size, ...]`` shared by all rows; row ``r``'s ONE
+      token lands in page ``table[r, pos[r] // page_size]`` at offset
+      ``pos[r] % page_size``, and the view is the table-gathered
+      ``[B, P * page_size, ...]``. Page 0 is the garbage page: free
+      rows (a zeroed table row) write there, and nothing reads it but
+      through columns :func:`position_mask` closes. The bytes written
+      are BITWISE what :func:`write_at_pos` writes for that position:
+      the serving prefix cache publishes decode-written pages as
+      reusable prefix KV (``tests/test_prefix_cache.py``).
+
+    Views come in the compute ``dtype`` where the storage is int8 and
+    as stored otherwise. Returns ``(new_caches, views, cols)``,
+    ``cols`` ``[B or 1, S]`` the cache column of each fresh token."""
+    b, s = fresh[0].shape[:2]
+    if page_table is not None:
+        if s != 1:
+            raise ValueError(
+                f"paged decode feeds one token per row (S == 1), got S={s}"
+            )
+        ps = caches[0].shape[1]
+        page = jnp.take_along_axis(page_table, (pos // ps)[:, None],
+                                   axis=1)[:, 0]
+        offset = pos % ps
+        caches = tuple(write_paged(c, f[:, 0], page, offset)
+                       for c, f in zip(caches, fresh))
+        views = tuple(gather_pages_dense(c, page_table, dtype)
+                      for c in caches)
+        return caches, views, pos[:, None]
+    if pos.ndim == 0:
+        caches = tuple(write_at_pos(c, f, pos)
+                       for c, f in zip(caches, fresh))
+        cols = (pos + jnp.arange(s))[None]
+    else:
+        rows = jnp.arange(b)[:, None]
+        cols = pos[:, None] + jnp.arange(s)[None]
+        caches = tuple(write_at_rows(c, f, rows, cols)
+                       for c, f in zip(caches, fresh))
+    return caches, tuple(read_dense(c, dtype) for c in caches), cols
+
+
+def position_mask(cols, width):
+    """Additive mask ``[B or 1, 1, S, width]`` over a cache view: the
+    token at column ``cols[r, t]`` may read slot ``k`` iff ``k <=
+    cols[r, t]`` — everything later (stale slots, pad tokens, the
+    garbage page, other requests' leftovers) adds an exact zero."""
+    valid = jnp.arange(width)[None, None, :] <= cols[:, :, None]
+    return jnp.where(valid, 0.0, -jnp.inf)[:, None]
 
 
 def slab_row_block(cache, slot):

@@ -8,9 +8,9 @@ path: every eligible ``nn.Linear`` (and every PTQ/QAT-converted
 per-output-channel fp32 scale** — registered as persistable buffers,
 so the narrow weights flow unchanged through ``state_dict``,
 ``jit.save`` artifacts (``Predictor.into_engine()`` serves them), and
-the serving engines' weight snapshots. Forward runs through
-``kernels/int8_matmul``: composed dequant->matmul by default, the
-fused dequant-epilogue Pallas kernel when the tune cache opts it in.
+the serving engines' weight snapshots. Forward is the composed
+dequant -> matmul (:func:`int8_matmul_composed`): XLA fuses the
+dequantization into the matmul's weight load.
 
 The pass is IDEMPOTENT: quantizing an already-quantized model returns
 it unchanged (already-int8 weights must never be re-quantized — a
@@ -29,9 +29,45 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from ..core import dispatch
 from ..core.tensor import Tensor
 from ..nn.layer.layers import Layer
 from .qat import ObservedLayer, _swap_layers
+
+
+def quantize_weight_with_scales(w, scale):
+    """The ONE home of the int8 weight rounding rule: float ``[in,
+    out]`` weight + per-out-channel fp32 ``[out]`` scales -> int8
+    values. Fresh-absmax and PTQ-calibrated callers both round here,
+    so the two deploy paths can never drift apart."""
+    wf = jnp.asarray(w).astype(jnp.float32)
+    s = jnp.maximum(jnp.asarray(scale, jnp.float32), 1e-8)
+    q = jnp.clip(
+        jnp.round(wf / s[None, :]), -127, 127
+    ).astype(jnp.int8)  # tpu-lint: quant
+    return q, s
+
+
+def quantize_weight(w):
+    """Float ``[in, out]`` weight -> (int8 values, fp32 per-out-channel
+    scales ``[out]``). Symmetric absmax over the contraction axis."""
+    wf = jnp.asarray(w).astype(jnp.float32)
+    absmax = jnp.max(jnp.abs(wf), axis=0)
+    return quantize_weight_with_scales(wf, absmax / 127.0)
+
+
+def int8_matmul_composed(x, w_q, scale):
+    """``x @ dequant(w_q, scale)``: x ``[..., H]`` float, w_q int8
+    ``[H, N]``, scale fp32 ``[N]``; returns ``[..., N]`` in x's dtype.
+    Dequantize the whole weight (int8 -> fp32 * scale -> x's dtype),
+    then ONE dot over the full contraction dim."""
+    shape = x.shape
+    h = int(shape[-1])
+    x2d = x.reshape(-1, h)
+    n_out = int(w_q.shape[1])
+    sc = scale.reshape(1, n_out).astype(jnp.float32)
+    w = (w_q.astype(jnp.float32) * sc).astype(x2d.dtype)  # tpu-lint: quant
+    return jnp.dot(x2d, w).reshape(tuple(shape[:-1]) + (n_out,))
 
 
 class QuantizedLinear(Layer):
@@ -39,9 +75,7 @@ class QuantizedLinear(Layer):
 
     ``weight_q`` (int8 ``[in, out]``) and ``weight_scale`` (fp32
     ``[out]``) are persistable BUFFERS — not parameters — so optimizer
-    walks skip them while snapshots/exports carry them. Kernel choice
-    is per-call-shape tune-cache opt-in (``int8_matmul_select``): with
-    no measured entry the composed dequant->matmul runs."""
+    walks skip them while snapshots/exports carry them."""
 
     def __init__(self, weight_q, weight_scale, bias=None):
         super().__init__()
@@ -69,18 +103,13 @@ class QuantizedLinear(Layer):
             self.bias = None
 
     def forward(self, x):
-        from ..kernels.int8_matmul import (
-            int8_matmul_apply,
-            int8_matmul_select,
+        # weight-only decode is a no-grad path: the op registers
+        # nondiff (train-time quantization goes through the QAT
+        # fake-quant STE instead)
+        y = dispatch.apply(
+            "int8_matmul", int8_matmul_composed,
+            (x, self.weight_q, self.weight_scale), nondiff=True,
         )
-
-        rows = 1
-        for s in x.shape[:-1]:
-            rows *= int(s)
-        cfg = int8_matmul_select(rows, self.in_features,
-                                 self.out_features)
-        y = int8_matmul_apply(x, self.weight_q, self.weight_scale,
-                              config=cfg)
         if self.bias is not None:
             y = y + self.bias
         return y
@@ -90,31 +119,6 @@ class QuantizedLinear(Layer):
                 f"out_features={self.out_features}, dtype=int8")
 
 
-def quantize_linear_weight(weight):
-    """Float ``[in, out]`` weight -> (int8 values, fp32 ``[out]``
-    per-output-channel scales) — the kernel module's symmetric absmax
-    quantizer (ONE home for the rounding rule)."""
-    from ..kernels.int8_matmul import quantize_weight
-
-    w = weight.value if isinstance(weight, Tensor) else jnp.asarray(
-        weight
-    )
-    return quantize_weight(w)
-
-
-def _requantize_with_scales(weight, scales):
-    """Quantize ``[in, out]`` with CALIBRATED per-channel scales (the
-    PTQ/QAT observed absmax path — divide by the frozen scale instead
-    of deriving a fresh one; the rounding rule itself lives in
-    ``kernels/int8_matmul.quantize_weight_with_scales``)."""
-    from ..kernels.int8_matmul import quantize_weight_with_scales
-
-    w = weight.value if isinstance(weight, Tensor) else jnp.asarray(
-        weight
-    )
-    return quantize_weight_with_scales(w, scales)
-
-
 def _is_linear(layer):
     from ..nn.layer.common import Linear
 
@@ -122,7 +126,7 @@ def _is_linear(layer):
 
 
 def _from_linear(lin):
-    wq, ws = quantize_linear_weight(lin.weight)
+    wq, ws = quantize_weight(lin.weight.value)
     return QuantizedLinear(wq, ws, bias=lin.bias)
 
 
@@ -138,7 +142,9 @@ def _from_observed(obs):
         and np.shape(ws)[0] == int(inner.weight.shape[-1])
     )
     if per_channel:
-        wq, s = _requantize_with_scales(inner.weight, ws)
+        # CALIBRATED scales (the PTQ/QAT observed absmax): divide by
+        # the frozen scale instead of deriving a fresh one
+        wq, s = quantize_weight_with_scales(inner.weight.value, ws)
         return QuantizedLinear(wq, s, bias=inner.bias)
     # per-tensor / non-8-bit observed scales: fall back to fresh
     # per-channel absmax (strictly tighter than a per-tensor scale)
@@ -147,7 +153,7 @@ def _from_observed(obs):
 
 def quantize_for_serving(model, inplace=False):
     """Convert a trained / PTQ'd / QAT-converted model's Linear weights
-    to ``(int8, scale)`` pairs executed by the int8 matmul kernels.
+    to ``(int8, scale)`` pairs executed as dequant -> matmul.
 
     Returns the converted model (a deep copy unless ``inplace=True``).
     Calling it again on the result is a no-op (idempotent)."""

@@ -95,3 +95,49 @@ def test_tuned_blocks_prefer_cache_entry(tmp_path, monkeypatch):
     bs = fa._tuned_block_sizes(2048, 2048, b=4, h=16, d=128)
     assert (bs.block_q, bs.block_k_major, bs.block_k) == (256, 512, 256)
     autotune.reset_cache()
+
+
+# What flash selects at the shapes the benchmark's cells run
+# (BENCHMARK.json), q and k/v as ``[B, S, H, D]``; the cells' cache
+# holds no entry for any of them, so a Pallas pick is the seeded triple.
+CELL_SHAPES = {
+    "mistral train b2 s2048 h32 d128": (
+        (2, 2048, 32, 128), (2, 2048, 32, 128), None, "pallas:seed"),
+    "the same under the dp2 x mp2 mesh": (
+        (2, 2048, 32, 128), (2, 2048, 32, 128), (2, 2),
+        "fallback:unpartitionable"),
+    "xing4 prefill b1 s4096 h32 d256": (
+        (1, 4096, 32, 256), (1, 4096, 32, 256), None, "pallas:seed"),
+    "a 256-token prefill bucket": (
+        (1, 256, 32, 128), (1, 256, 32, 128), None,
+        "policy:below-threshold"),
+    "one-token decode over 4096": (
+        (32, 1, 32, 128), (32, 4096, 32, 128), None,
+        "policy:cross-length-causal"),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_SHAPES)
+def test_selection_at_the_cells_shapes(force_tpu, cell):
+    import warnings
+
+    import jax
+    from jax.sharding import Mesh
+
+    from paddle_tpu.parallel import mesh as mesh_mod
+
+    q_shape, k_shape, mesh, want = CELL_SHAPES[cell]
+    q, k = np.zeros(q_shape, np.float32), np.zeros(k_shape, np.float32)
+    if mesh is not None:
+        devs = np.array(jax.local_devices()[:4]).reshape(mesh)
+        mesh_mod.set_mesh(Mesh(devs, ("dp", "mp")))
+    try:
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore", RuntimeWarning)
+            use, cfg, reason = fa._select(q, k, k, causal=True)
+    finally:
+        mesh_mod.set_mesh(None)
+    assert reason == want
+    assert use == want.startswith("pallas:")
+    if use:
+        assert cfg == fa._seed_config(q_shape[1], k_shape[1])

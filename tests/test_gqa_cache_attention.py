@@ -14,14 +14,14 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.core.tensor import Tensor
-from paddle_tpu.kernels.paged_attention import (
-    gather_pages_dense,
-    paged_attention_composed,
-)
 from paddle_tpu.models import LlamaConfig
 from paddle_tpu.models.llama import LlamaAttention
 from paddle_tpu.nn.functional.attention import _sdpa_grouped_ref, _sdpa_ref
-from paddle_tpu.quantization.kv import QuantizedKV, quantize_kv
+from paddle_tpu.quantization.kv import (
+    QuantizedKV,
+    gather_pages_dense,
+    quantize_kv,
+)
 
 B, S, H, KVH, D = 3, 2, 8, 2, 16
 PS, PAGES = 4, 5                       # a row's table spans 20 columns
@@ -86,25 +86,6 @@ def test_grouped_matches_repeat_then_sdpa(dtype, int8, extra):
         causal=False, scale=SCALE, dropout_p=0.0, key=None)
     assert got.shape == (B, S, H, D) and got.dtype == dtype
     _close(got, want, dtype)
-
-
-@pytest.mark.parametrize("int8", [False, True], ids=["plain", "int8"])
-def test_paged_composed_follows_the_grouped_helper(int8):
-    """``paged_attention_composed`` documents itself as the op order of
-    the engine's default paged path: under GQA that is the grouped
-    helper, bit for bit."""
-    rng = np.random.RandomState(12)
-    k_pages, v_pages, tbl, pos = _arena(rng, jnp.bfloat16, int8)
-    q = jnp.asarray(rng.randn(B, 1, H, D), jnp.bfloat16)
-    got = paged_attention_composed(q, k_pages, v_pages, tbl, pos)
-    valid = jnp.arange(PAGES * PS)[None, None, None, :] \
-        <= pos[:, None, None, None]
-    want = _sdpa_grouped_ref(
-        q, gather_pages_dense(k_pages, tbl, q.dtype),
-        gather_pages_dense(v_pages, tbl, q.dtype),
-        jnp.where(valid, 0.0, -jnp.inf), scale=SCALE)
-    np.testing.assert_array_equal(np.asarray(got, np.float32),
-                                  np.asarray(want, np.float32))
 
 
 def _twin_layers():

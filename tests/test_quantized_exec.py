@@ -140,7 +140,7 @@ def test_quantized_linear_validates_inputs():
         QuantizedLinear(jnp.zeros((4, 8), jnp.int8),
                         jnp.ones((4,), jnp.float32))
     # well-formed: composed forward matches manual dequant matmul
-    from paddle_tpu.kernels.int8_matmul import quantize_weight
+    from paddle_tpu.quantization.serving import quantize_weight
 
     w = jnp.asarray(rng.randn(8, 16), jnp.float32)
     wq, sc = quantize_weight(w)

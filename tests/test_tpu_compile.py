@@ -1,8 +1,9 @@
 """The chip's compiler, asked without the chip.
 
 Every Pallas kernel in ``paddle_tpu/kernels`` at Llama-2-7B widths
-(hidden 4096, 32 heads x 128, intermediate 11008, vocab 32000), plus the
-paged decode step program at depth 1, compiled for a DESCRIBED v5e chip
+(hidden 4096, 32 heads x 128) and at the shapes the benchmark's cells
+run them at, plus the paged decode step program at depth 1, compiled
+for a DESCRIBED v5e chip
 (``jax.experimental.topologies``): what the compiler refuses here it
 refuses on the chip — a block the tiling cannot take, more VMEM than a
 kernel may use, a primitive Mosaic does not lower. Interpret-mode tests
@@ -24,17 +25,13 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.kernels import (
     autotune,
     flash_attention as fa,
-    fused_norm_matmul as nm,
-    fused_rope_attention as ra,
-    int8_matmul as i8,
-    paged_attention as pa,
     rms_norm as rn,
     rope as rp,
 )
 
-HID, HEADS, HD, FFN, VOCAB = 4096, 32, 128, 11008, 32000
+HID, HEADS, HD = 4096, 32, 128
 B, S = 4, 1024          # train rows
-ROWS, CTX = 8, 2048     # decode rows, context a page table spans
+ROWS = 8                # decode rows
 
 
 @pytest.fixture(scope="module")
@@ -104,16 +101,19 @@ def _flash(q, k, v):
         block_sizes=fa._tuned_block_sizes(seq, seq))
 
 
-def _paged(ps, kvh=HEADS):
-    pages = CTX // ps
-    arena = [((ROWS * pages + 1, ps, kvh, HD), BF)] * 2
-    return [((ROWS, 1, HEADS, HD), BF), *arena,
-            ((ROWS, pages), I32), ((ROWS,), I32)]
+def _flash_entry(q, k, v):
+    """The models' own entry, ``[B, S, H, D]``: selection, the
+    transposes and the kernel (the test shows it the chip)."""
+    return fa.flash_attention_fwd(q, k, v, causal=True)
+
+
+def _rope_tables(rows, s):
+    return [((rows, s, 1, HD // 2), F32)] * 2
 
 
 # name -> (function, [(shape, dtype), ...]). Shapes only: nothing here
 # touches a device or the topology while the module is imported.
-BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+BF, F32 = jnp.bfloat16, jnp.float32
 TRAIN, DEC = (B, S, HID), (ROWS, 1, HID)
 QKV = [((B, S, HEADS, HD), BF)] * 3
 TABLE = [((1, S, 1, HD // 2), F32)] * 2
@@ -139,70 +139,44 @@ KERNEL_CASES = {
                         *[((ROWS, 1, 1, HD // 2), F32)] * 2]),
     "flash fwd S=2048": (_flash, FLASH),
     "flash bwd S=2048": (_grad(_flash, (0, 1, 2)), FLASH),
-    "int8_matmul ffn": (
-        i8.int8_matmul,
-        [((ROWS, HID), BF), ((HID, FFN), jnp.int8), ((FFN,), F32)]),
-    "int8_matmul head": (
-        i8.int8_matmul,
-        [((ROWS, HID), BF), ((HID, VOCAB), jnp.int8), ((VOCAB,), F32)]),
-    "rms_norm_matmul head": (
-        nm.rms_norm_matmul,
-        [((ROWS, HID), BF), ((HID,), BF), ((HID, VOCAB), BF)]),
-    "rope_attention_fused": (
-        ra.rope_attention_fused,
-        [*QKV, ((S, HD // 2), F32), ((S, HD // 2), F32)]),
-    "paged_attention ps=8": (pa.paged_attention_fused, _paged(8)),
-    "paged_attention ps=16": (pa.paged_attention_fused, _paged(16)),
-    "paged_attention ps=128": (pa.paged_attention_fused, _paged(128)),
-    "paged_attention ps=16 gqa kvh=8": (
-        pa.paged_attention_fused, _paged(16, kvh=8)),
+    # -- the shapes the benchmark's cells run (BENCHMARK.json): xing4
+    # serving (hidden 3584, latent norms 768 and 512 wide; 32 decode
+    # rows, a 4096-token prefill whose flash heads are zero-padded to
+    # 256), Mistral-7B training (B=2 x S=2048, 32 heads over 8 KV heads)
+    "rms_norm fwd xing4 decode": (_rms, [((32, 1, 3584), BF),
+                                         ((3584,), BF)]),
+    "rms_norm fwd xing4 prefill": (_rms, [((1, 4096, 3584), BF),
+                                          ((3584,), BF)]),
+    "rms_norm fwd xing4 q latent": (_rms, [((1, 4096, 768), BF),
+                                           ((768,), BF)]),
+    "rms_norm fwd xing4 kv latent": (_rms, [((32, 1, 512), BF),
+                                            ((512,), BF)]),
+    "rms_norm fwd+bwd mistral train": (
+        _grad(_rms, (0, 1)), [((2, 2048, HID), BF), ((HID,), F32)]),
+    "flash fwd xing4 prefill": (
+        _flash_entry, [((1, 4096, HEADS, 256), BF)] * 3),
+    "flash fwd+bwd mistral train": (
+        _grad(_flash_entry, (0, 1, 2)), [((2, 2048, HEADS, HD), BF)] * 3),
+    "rope fwd+bwd mistral train q": (
+        _grad(rp.rope_fused, 0),
+        [((2, 2048, HEADS, HD), BF), *_rope_tables(1, 2048)]),
+    "rope fwd+bwd mistral train k": (
+        _grad(rp.rope_fused, 0),
+        [((2, 2048, 8, HD), BF), *_rope_tables(1, 2048)]),
+    "rope per-row decode 32 rows": (
+        rp.rope_fused, [((32, 1, HEADS, HD), BF), *_rope_tables(32, 1)]),
 }
 
 
 @pytest.mark.parametrize("name", KERNEL_CASES)
-def test_kernel_compiles_for_v5e_at_7b_widths(chip_compile, name):
+def test_kernel_compiles_for_v5e_at_7b_widths(chip_compile, topo,
+                                              monkeypatch, name):
     fn, shapes = KERNEL_CASES[name]
+    # flash's selection asks jax.devices() what it runs on
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
     compiled = chip_compile(fn, *(chip_compile.sds(*s) for s in shapes))
     assert "tpu_custom_call" in compiled.as_text(), (
         "no Mosaic kernel in the compiled program")
-
-
-def test_paged_attention_int8_arena_compiles(chip_compile):
-    """The int8 flavour: int8 page blocks + their fp32 scale blocks."""
-    from paddle_tpu.quantization.kv import QuantizedKV
-
-    sds = chip_compile.sds
-    ps, pages = 16, CTX // 16
-    n = ROWS * pages + 1
-    arena = QuantizedKV(sds((n, ps, HEADS, HD), jnp.int8),
-                        sds((n, ps, HEADS), jnp.float32))
-    compiled = chip_compile(
-        pa.paged_attention_fused, sds((ROWS, 1, HEADS, HD)), arena, arena,
-        sds((ROWS, pages), jnp.int32), sds((ROWS,), jnp.int32))
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_paged_block_the_chip_refuses_is_not_a_candidate(chip_compile):
-    """``block_kvh`` that is neither the whole kvH axis nor a multiple
-    of 8 is what the seed shipped as its default; the compiler refuses
-    it, and the tuner no longer offers it."""
-    sds = chip_compile.sds
-    ps, pages = 16, CTX // 16
-    n = ROWS * pages + 1
-    with pytest.raises(ValueError, match="divisible by 8 and 128"):
-        chip_compile(
-            lambda q, k, v, t, p: pa.paged_attention_fused(
-                q, k, v, t, p, block_kvh=1),
-            sds((ROWS, 1, HEADS, HD)), sds((n, ps, HEADS, HD)),
-            sds((n, ps, HEADS, HD)), sds((ROWS, pages), jnp.int32),
-            sds((ROWS,), jnp.int32))
-    assert not autotune.paged_attention_config_legal(
-        HEADS, {"block_kvh": 1})
-    assert all(
-        autotune.paged_attention_config_legal(HEADS, c)
-        for c in autotune.paged_attention_candidates(HEADS))
-    assert autotune.paged_attention_candidates(HEADS, quant=True) == [
-        {"block_kvh": HEADS}]
 
 
 def test_rms_norm_row_block_fits_vmem_budget():

@@ -1,10 +1,11 @@
 """kernel_tune — measured-search block-config tuning for the Pallas kernels.
 
-Drives ``paddle_tpu.kernels.autotune`` over the shapes that matter in
-production — the flagship train step's attention/head geometry and the
-serving decode head — and records the winners in the persistent tune
-cache (``tools/kernel_tune_cache.json`` by default, checked in for v5e
-like the lint baseline; ``PADDLE_TPU_TUNE_CACHE`` overrides).
+Drives ``paddle_tpu.kernels.autotune`` over the one Pallas kernel with
+block sizes to choose, flash attention, and over the fp8 train matmul
+(AMP O3; no block sizes, the fp8-vs-bf16 verdict alone), and records the
+winners in the persistent tune cache (``tools/kernel_tune_cache.json``
+by default, checked in for v5e like the lint baseline;
+``PADDLE_TPU_TUNE_CACHE`` overrides).
 
     python tools/kernel_tune.py              # tune this device's standard shapes
     python tools/kernel_tune.py --json       # machine-readable report
@@ -19,12 +20,10 @@ candidate. A shape with a cache entry is a HIT: zero measurements, the
 entry is reported as-is (re-tune by deleting the entry or pointing
 ``--cache`` elsewhere).
 
-``--smoke`` is the ``make tune-smoke`` gate: tiny shapes, CPU-safe (the
-fusion kernels run in pallas interpret mode; the stock flash kernel
-needs a chip and is skipped), a throwaway cache file. It asserts
-candidate-generator legality, a cache write/read round trip, a
-100%-cache-hit re-run with zero re-measurements, and fused-vs-composed
-parity for both fusion kernels.
+``--smoke`` is the ``make tune-smoke`` gate: a tiny shape, CPU-safe (the
+stock flash kernel needs a chip and is skipped there), a throwaway
+cache file. It asserts candidate-generator legality, a cache write/read
+round trip and a 100%-cache-hit re-run with zero re-measurements.
 """
 from __future__ import annotations
 
@@ -49,56 +48,21 @@ def _on_tpu():
 
 def standard_specs(on_tpu):
     """(kernel, spec) list for this backend. TPU: the flagship
-    llama-748M geometry (B=4, H=16, D=128, hidden 2048, vocab 32k) at
-    the train S and the long-context S values BENCH_NOTES measured,
-    plus the serving decode head. CPU: tiny interpret-mode shapes (a
-    smoke of the machinery, not a performance measurement)."""
+    llama-748M geometry (B=4, H=16, D=128, hidden 2048) at the train S
+    and the long-context S values BENCH_NOTES measured. CPU: one tiny
+    shape (a smoke of the machinery, not a performance measurement)."""
     if on_tpu:
         return [
             ("flash_attention",
              {"b": 4, "s": 2048, "h": 16, "d": 128, "causal": True}),
             ("flash_attention",
              {"b": 4, "s": 4096, "h": 16, "d": 128, "causal": True}),
-            ("rope_attention", {"b": 4, "s": 1024, "h": 16, "d": 128}),
-            ("rope_attention", {"b": 4, "s": 2048, "h": 16, "d": 128}),
-            # flagship train head: B*S rows x hidden -> vocab
-            ("rms_norm_matmul",
-             {"rows": 4096, "hidden": 2048, "n_out": 32000}),
-            # serving decode head: one token per resident slot
-            ("rms_norm_matmul",
-             {"rows": 8, "hidden": 2048, "n_out": 32000}),
-            # paged serving decode: 32 rows over an S=2048 logical
-            # window of 16-token pages (flagship head geometry)
-            ("paged_attention",
-             {"b": 32, "pages": 128, "page_size": 16, "h": 16,
-              "kvh": 16, "d": 128}),
-            # int8-KV flavor of the same decode shape (its own entry:
-            # int8 page loads + in-VMEM dequant profile differently)
-            ("paged_attention",
-             {"b": 32, "pages": 128, "page_size": 16, "h": 16,
-              "kvh": 16, "d": 128, "quant": True}),
-            # weight-only int8 decode projections: qkv/o-sized and the
-            # serving lm_head (rows = resident decode slots)
-            ("int8_matmul", {"rows": 32, "hidden": 2048, "n_out": 2048}),
-            ("int8_matmul",
-             {"rows": 32, "hidden": 2048, "n_out": 32000}),
             # fp8 train matmul (AMP O3): the flagship gemm shapes —
             # records the measured fp8-vs-bf16 verdict for the device
             ("fp8_matmul", {"m": 4096, "k": 2048, "n": 8192}),
             ("fp8_matmul", {"m": 4096, "k": 2048, "n": 2048}),
         ]
-    return [
-        ("rope_attention", {"b": 2, "s": 64, "h": 2, "d": 16}),
-        ("rms_norm_matmul", {"rows": 16, "hidden": 64, "n_out": 256}),
-        ("paged_attention",
-         {"b": 2, "pages": 4, "page_size": 8, "h": 4, "kvh": 2,
-          "d": 16}),
-        ("paged_attention",
-         {"b": 2, "pages": 4, "page_size": 8, "h": 4, "kvh": 2,
-          "d": 16, "quant": True}),
-        ("int8_matmul", {"rows": 8, "hidden": 64, "n_out": 256}),
-        ("fp8_matmul", {"m": 16, "k": 64, "n": 128}),
-    ]
+    return [("fp8_matmul", {"m": 16, "k": 64, "n": 128})]
 
 
 # ------------------------------------------------------------ tune drivers
@@ -111,26 +75,6 @@ def _sig_and_candidates(kernel, spec):
         sig = autotune.flash_sig(spec["b"], spec["s"], spec["s"],
                                  spec["h"], spec["d"], spec["causal"])
         cands = autotune.flash_block_candidates(spec["s"], spec["s"])
-    elif kernel == "rope_attention":
-        sig = autotune.rope_attention_sig(spec["b"], spec["s"],
-                                          spec["h"], spec["d"])
-        cands = autotune.rope_attention_candidates(spec["s"])
-    elif kernel == "rms_norm_matmul":
-        sig = autotune.norm_matmul_sig(spec["rows"], spec["hidden"],
-                                       spec["n_out"])
-        cands = autotune.norm_matmul_candidates(spec["rows"],
-                                                spec["n_out"])
-    elif kernel == "paged_attention":
-        sig = autotune.paged_attention_sig(
-            spec["b"], spec["pages"], spec["page_size"], spec["h"],
-            spec["kvh"], spec["d"], quant=spec.get("quant", False))
-        cands = autotune.paged_attention_candidates(
-            spec["kvh"], quant=spec.get("quant", False))
-    elif kernel == "int8_matmul":
-        sig = autotune.int8_matmul_sig(spec["rows"], spec["hidden"],
-                                       spec["n_out"])
-        cands = autotune.int8_matmul_candidates(spec["rows"],
-                                                spec["n_out"])
     elif kernel == "fp8_matmul":
         sig = autotune.fp8_matmul_sig(spec["m"], spec["k"], spec["n"])
         cands = autotune.fp8_matmul_candidates()
@@ -150,171 +94,39 @@ def _build_factory(kernel, spec):
     rng = np.random.RandomState(0)
     dtype = jnp.bfloat16 if _on_tpu() else jnp.float32
 
-    if kernel in ("flash_attention", "rope_attention"):
+    if kernel == "flash_attention":
+        from paddle_tpu.kernels import flash_attention as fa
+
         b, s, h, d = spec["b"], spec["s"], spec["h"], spec["d"]
         causal = spec.get("causal", True)
         q = jnp.asarray(rng.randn(b, s, h, d), dtype)
         k = jnp.asarray(rng.randn(b, s, h, d), dtype)
         v = jnp.asarray(rng.randn(b, s, h, d), dtype)
-        if kernel == "flash_attention":
-            from paddle_tpu.kernels import flash_attention as fa
-
-            def build(config):
-                if config.get("path") == "composed":
-                    def f(qv, kv, vv):
-                        return fa._composed(
-                            qv, kv, vv, causal=causal,
-                            scale=1.0 / float(np.sqrt(d)),
-                        ).astype(jnp.float32).sum()
-                else:
-                    pallas_fa = fa._pallas_fa()
-                    bs = fa._tuned_block_sizes(s, s, config=config)
-
-                    def f(qv, kv, vv):
-                        out = pallas_fa(
-                            jnp.swapaxes(qv, 1, 2),
-                            jnp.swapaxes(kv, 1, 2),
-                            jnp.swapaxes(vv, 1, 2),
-                            causal=causal,
-                            sm_scale=1.0 / float(np.sqrt(d)),
-                            block_sizes=bs,
-                        )
-                        return out.astype(jnp.float32).sum()
-
-                step = jax.jit(jax.grad(f, argnums=(0, 1, 2)))
-                return lambda: step(q, k, v)
-
-            return build
-
-        from paddle_tpu.kernels import flash_attention as fa
-        from paddle_tpu.kernels import fused_rope_attention as fra
-        from paddle_tpu.kernels.rope import build_rope_cache, rope_fused
-
-        cos, sin = build_rope_cache(s, d)
 
         def build(config):
             if config.get("path") == "composed":
-                # the baseline is today's PRODUCTION unfused path —
-                # rope kernel + flash_attention_fwd (which selects the
-                # tuned pallas flash kernel where eligible), not bare
-                # composed attention: the fused_beats_composed verdict
-                # gates replacing this exact path in llama.py, so
-                # beating a slower strawman must not count as a win
                 def f(qv, kv, vv):
-                    qr = rope_fused(qv, cos, sin)
-                    kr = rope_fused(kv, cos, sin)
-                    return fa.flash_attention_fwd(
-                        qr, kr, vv, causal=causal
+                    return fa._composed(
+                        qv, kv, vv, causal=causal,
+                        scale=1.0 / float(np.sqrt(d)),
                     ).astype(jnp.float32).sum()
             else:
-                bq = config["block_q"]
+                pallas_fa = fa._pallas_fa()
+                bs = fa._tuned_block_sizes(s, s, config=config)
 
                 def f(qv, kv, vv):
-                    return fra.rope_attention_fused(
-                        qv, kv, vv, cos, sin, causal=causal, block_q=bq
-                    ).astype(jnp.float32).sum()
+                    out = pallas_fa(
+                        jnp.swapaxes(qv, 1, 2),
+                        jnp.swapaxes(kv, 1, 2),
+                        jnp.swapaxes(vv, 1, 2),
+                        causal=causal,
+                        sm_scale=1.0 / float(np.sqrt(d)),
+                        block_sizes=bs,
+                    )
+                    return out.astype(jnp.float32).sum()
 
             step = jax.jit(jax.grad(f, argnums=(0, 1, 2)))
             return lambda: step(q, k, v)
-
-        return build
-
-    if kernel == "rms_norm_matmul":
-        from paddle_tpu.kernels import fused_norm_matmul as fnm
-
-        rows, hidden, n_out = spec["rows"], spec["hidden"], spec["n_out"]
-        x = jnp.asarray(rng.randn(rows, hidden), dtype)
-        w = jnp.asarray(rng.randn(hidden), jnp.float32)
-        wm = jnp.asarray(rng.randn(hidden, n_out), dtype)
-
-        def build(config):
-            if config.get("path") == "composed":
-                def f(xv, wv, mv):
-                    return fnm.rms_norm_matmul_composed(
-                        xv, wv, mv
-                    ).astype(jnp.float32).sum()
-            else:
-                br, bc = config["block_rows"], config["block_cols"]
-
-                def f(xv, wv, mv):
-                    return fnm.rms_norm_matmul(
-                        xv, wv, mv, block_rows=br, block_cols=bc
-                    ).astype(jnp.float32).sum()
-
-            step = jax.jit(jax.grad(f, argnums=(0, 1, 2)))
-            return lambda: step(x, w, wm)
-
-        return build
-
-    if kernel == "paged_attention":
-        from paddle_tpu.kernels import paged_attention as pa
-
-        b, pages, ps = spec["b"], spec["pages"], spec["page_size"]
-        h, kvh, d = spec["h"], spec["kvh"], spec["d"]
-        n = b * pages + 1  # full coverage + garbage page 0
-        q = jnp.asarray(rng.randn(b, 1, h, d), dtype)
-        kp = jnp.asarray(rng.randn(n, ps, kvh, d), dtype)
-        vp = jnp.asarray(rng.randn(n, ps, kvh, d), dtype)
-        if spec.get("quant"):
-            from paddle_tpu.quantization.kv import (
-                QuantizedKV,
-                quantize_kv,
-            )
-
-            kp = QuantizedKV(*quantize_kv(kp))
-            vp = QuantizedKV(*quantize_kv(vp))
-        # disjoint per-row tables (the serving layout), rows near full
-        tbl = jnp.asarray(
-            1 + np.arange(b * pages).reshape(b, pages), jnp.int32
-        )
-        pos = jnp.full((b,), pages * ps - 1, jnp.int32)
-
-        def build(config):
-            # decode is a no-grad path: time the forward only
-            if config.get("path") == "composed":
-                def f(qv, kv, vv):
-                    return pa.paged_attention_composed(
-                        qv, kv, vv, tbl, pos
-                    ).astype(jnp.float32).sum()
-            else:
-                bk = config["block_kvh"]
-
-                def f(qv, kv, vv):
-                    return pa.paged_attention_fused(
-                        qv, kv, vv, tbl, pos, block_kvh=bk
-                    ).astype(jnp.float32).sum()
-
-            step = jax.jit(f)
-            return lambda: step(q, kp, vp)
-
-        return build
-
-    if kernel == "int8_matmul":
-        from paddle_tpu.kernels import int8_matmul as im
-
-        rows, hidden, n_out = spec["rows"], spec["hidden"], spec["n_out"]
-        x = jnp.asarray(rng.randn(rows, hidden), dtype)
-        wq, sc = im.quantize_weight(
-            jnp.asarray(rng.randn(hidden, n_out), jnp.float32)
-        )
-
-        def build(config):
-            # weight-only decode is fwd-only: time the forward
-            if config.get("path") == "composed":
-                def f(xv):
-                    return im.int8_matmul_composed(
-                        xv, wq, sc
-                    ).astype(jnp.float32).sum()
-            else:
-                br, bc = config["block_rows"], config["block_cols"]
-
-                def f(xv):
-                    return im.int8_matmul(
-                        xv, wq, sc, block_rows=br, block_cols=bc
-                    ).astype(jnp.float32).sum()
-
-            step = jax.jit(f)
-            return lambda: step(x)
 
         return build
 
@@ -369,8 +181,7 @@ def tune_shape(kernel, spec, cache, *, iters=3, windows=3,
 
         if not _on_tpu() or _fa._pallas_fa() is None:
             # the stock pallas flash kernel has no interpret path —
-            # tuning it needs a chip (+ the jax tpu ops lib); the
-            # fusion kernels cover the CPU smoke
+            # tuning it needs a chip (+ the jax tpu ops lib)
             row.update(cache_hit=False, config=None, measured=0,
                        reason="requires-tpu")
             return row
@@ -393,9 +204,8 @@ def tune_shape(kernel, spec, cache, *, iters=3, windows=3,
     if winner is not None:
         # record the best fused config EITHER WAY (so a re-run is a
         # cache hit, not a re-measurement), but store the measured
-        # fused-vs-composed verdict with it: the selection paths
-        # (rope_attention_select / head_fusion_select / flash _select)
-        # refuse to activate a fused kernel whose entry says
+        # fused-vs-composed verdict with it: flash's ``_select`` keeps
+        # composed, in the time regime, where the entry says
         # fused_beats_composed is False — the tuner must never install
         # a measured performance regression.
         timings = {json.dumps(r["config"], sort_keys=True):
@@ -465,15 +275,7 @@ def run_tune(cache_path=None, specs=None, *, iters=3, windows=3,
 
 def smoke():
     """CPU-safe machinery gate (``make tune-smoke``)."""
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-
     from paddle_tpu.kernels import autotune
-    from paddle_tpu.kernels import fused_norm_matmul as fnm
-    from paddle_tpu.kernels import fused_rope_attention as fra
-    from paddle_tpu.kernels.rope import build_rope_cache
 
     # 1. candidate generators: every emitted config is legal; shapes
     # with no MXU-friendly divisor yield empty (-> signalled fallback)
@@ -482,22 +284,11 @@ def smoke():
     for cfg in autotune.flash_block_candidates(2176, 2176):
         assert autotune.flash_config_legal(2176, 2176, cfg), cfg
     assert autotune.flash_block_candidates(2050, 2050) == []
-    for cfg in autotune.rope_attention_candidates(96):
-        assert autotune.rope_attention_config_legal(96, cfg), cfg
-    for cfg in autotune.norm_matmul_candidates(16, 256):
-        assert autotune.norm_matmul_config_legal(16, 256, cfg), cfg
-    for cfg in autotune.paged_attention_candidates(8):
-        assert autotune.paged_attention_config_legal(8, cfg), cfg
-    for cfg in autotune.int8_matmul_candidates(8, 256):
-        assert autotune.int8_matmul_config_legal(8, 256, cfg), cfg
     assert autotune.fp8_matmul_candidates() == [{"format": "e4m3"}]
-    # the quantized paged flavor tunes under its own signature
-    assert autotune.paged_attention_sig(2, 4, 8, 4, 2, 16, quant=True) \
-        .endswith("_q8")
 
     with tempfile.TemporaryDirectory() as td:
         path = os.path.join(td, "tune_cache.json")
-        # 2. measured search over the tiny CPU specs writes the cache
+        # 2. measured search over the tiny CPU spec writes the cache
         # (catalog pinned to the CPU one so the step-3 verification
         # below matches even when the smoke runs on a TPU host)
         smoke_specs = standard_specs(False)
@@ -506,8 +297,7 @@ def smoke():
         assert rec["shapes_measured"] == rec["shapes"] > 0, rec
         assert os.path.exists(path), "cache file not written"
 
-        # 3. a FRESH cache object reads the entries back; every config
-        # is legal for its shape
+        # 3. a FRESH cache object reads the entries back
         cache = autotune.TuneCache(path)
         keys = cache.keys()
         assert len(keys) == rec["shapes"], (keys, rec["shapes"])
@@ -515,20 +305,7 @@ def smoke():
             sig, _ = _sig_and_candidates(kernel, spec)
             cfg = cache.lookup(kernel, sig, count=False)
             assert cfg is not None, f"no entry for {kernel}|{sig}"
-            if kernel == "rope_attention":
-                assert autotune.rope_attention_config_legal(
-                    spec["s"], cfg), cfg
-            elif kernel == "paged_attention":
-                assert autotune.paged_attention_config_legal(
-                    spec["kvh"], cfg, spec.get("quant", False)), cfg
-            elif kernel == "int8_matmul":
-                assert autotune.int8_matmul_config_legal(
-                    spec["rows"], spec["n_out"], cfg), cfg
-            elif kernel == "fp8_matmul":
-                assert cfg.get("format") == "e4m3", cfg
-            else:
-                assert autotune.norm_matmul_config_legal(
-                    spec["rows"], spec["n_out"], cfg), cfg
+            assert cfg.get("format") == "e4m3", cfg
 
         # 4. second run: 100% cache hits, zero re-measurements
         rec2 = run_tune(cache_path=path, specs=smoke_specs,
@@ -536,60 +313,8 @@ def smoke():
         assert rec2["cache_hits"] == rec2["shapes"], rec2
         assert rec2["shapes_measured"] == 0, rec2
 
-    # 5. parity: fused == composed (jitted, bit-exact) for both kernels
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(2, 64, 2, 16), jnp.float32)
-    cos, sin = build_rope_cache(64, 16)
-    f = jax.jit(lambda a: fra.rope_attention_fused(a, a, a, cos, sin,
-                                                   block_q=16))(q)
-    c = jax.jit(lambda a: fra.rope_attention_composed(a, a, a, cos,
-                                                      sin))(q)
-    assert (np.asarray(f) == np.asarray(c)).all(), "rope_attention parity"
-    x = jnp.asarray(rng.randn(16, 64), jnp.float32)
-    w = jnp.asarray(rng.randn(64), jnp.float32)
-    wm = jnp.asarray(rng.randn(64, 256), jnp.float32)
-    f2 = jax.jit(lambda a: fnm.rms_norm_matmul(a, w, wm, block_rows=8,
-                                               block_cols=128))(x)
-    c2 = jax.jit(lambda a: fnm.rms_norm_matmul_composed(a, w, wm))(x)
-    assert (np.asarray(f2) == np.asarray(c2)).all(), "norm_matmul parity"
-    # paged decode attention: kernel bit-exact vs its blocked reference
-    # (the kernel's contract; vs composed gather it agrees to rounding,
-    # which is why engine activation stays tune-cache opt-in)
-    from paddle_tpu.kernels import paged_attention as pa
-
-    qp = jnp.asarray(rng.randn(2, 1, 4, 16), jnp.float32)
-    kp = jnp.asarray(rng.randn(9, 8, 2, 16), jnp.float32)
-    vp = jnp.asarray(rng.randn(9, 8, 2, 16), jnp.float32)
-    tbl = jnp.asarray(1 + np.arange(8).reshape(2, 4), jnp.int32)
-    pos = jnp.asarray([13, 27], jnp.int32)
-    fp = jax.jit(lambda a: pa.paged_attention_fused(
-        a, kp, vp, tbl, pos))(qp)
-    rp = jax.jit(lambda a: pa.paged_attention_reference(
-        a, kp, vp, tbl, pos))(qp)
-    assert (np.asarray(fp) == np.asarray(rp)).all(), \
-        "paged_attention parity"
-    # int8 flavors: weight-only matmul fused == composed bit-exact,
-    # int8-arena paged kernel == its blocked dequant reference
-    from paddle_tpu.kernels import int8_matmul as im
-    from paddle_tpu.quantization.kv import QuantizedKV, quantize_kv
-
-    wq, sc = im.quantize_weight(jnp.asarray(rng.randn(64, 256),
-                                            jnp.float32))
-    xq = jnp.asarray(rng.randn(16, 64), jnp.float32)
-    fi = jax.jit(lambda a: im.int8_matmul(a, wq, sc, block_rows=8,
-                                          block_cols=128))(xq)
-    ci = jax.jit(lambda a: im.int8_matmul_composed(a, wq, sc))(xq)
-    assert (np.asarray(fi) == np.asarray(ci)).all(), "int8_matmul parity"
-    kq = QuantizedKV(*quantize_kv(kp))
-    vq = QuantizedKV(*quantize_kv(vp))
-    fq = jax.jit(lambda a: pa.paged_attention_fused(
-        a, kq, vq, tbl, pos))(qp)
-    rq = jax.jit(lambda a: pa.paged_attention_reference(
-        a, kq, vq, tbl, pos))(qp)
-    assert (np.asarray(fq) == np.asarray(rq)).all(), \
-        "int8 paged_attention parity"
     print("tune-smoke OK: generators legal, cache round-trips, "
-          "re-run is 100% hits with 0 measurements, parity holds")
+          "re-run is 100% hits with 0 measurements")
     return 0
 
 
