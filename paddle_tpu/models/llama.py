@@ -152,7 +152,9 @@ class LlamaAttention(nn.Layer):
         attention runs over the table-gathered logical cache (S must be
         1 — the paged decode step). Page id 0 is the reserved garbage
         page. The addressing of all three modes is
-        ``quantization.kv.write_and_view``.
+        ``quantization.kv.write_and_view`` and, for the arena,
+        ``write_and_attend_paged``, whose read is bounded by the
+        batch's longest row.
 
         Under GQA every cache path contracts the query heads, grouped
         by their KV head, against the cache as it is stored
@@ -179,8 +181,9 @@ class LlamaAttention(nn.Layer):
                    pos, page_table):
         """Rope, then the attention itself: without a cache GQA repeat
         + SDPA/flash; with one the cache write and view
-        (``kv.write_and_view``: slab, per-row slab or page arena),
-        the position mask and ``_cache_attention``, grouped under GQA.
+        (``kv.write_and_view``: slab or per-row slab;
+        ``kv.write_and_attend_paged``: page arena), the position mask
+        and ``_cache_attention``, grouped under GQA.
         Returns ``(out [B, S, H, D], new_cache)``, ``new_cache`` None
         without a cache."""
         cfg = self.cfg
@@ -209,13 +212,23 @@ class LlamaAttention(nn.Layer):
         # columns contribute exact zeros); int8 caches come back
         # dequantized to the compute dtype, plain ones as stored (the
         # attention upcasts at the matmul)
-        table = None if page_table is None else jnp.asarray(
-            _value(page_table))
-        cache, (kk, vv), cols = qkv.write_and_view(
-            cache, (k.value, v.value), p, table, q.value.dtype)
-        mask = qkv.position_mask(cols, kk.shape[1])
-        out = _cache_attention(q, Tensor(kk), Tensor(vv), mask, attn_mask)
-        return out, cache
+        def attend(views, mask):
+            kk, vv = views
+            return _cache_attention(q, Tensor(kk), Tensor(vv), mask,
+                                    attn_mask)
+
+        fresh = (k.value, v.value)
+        if page_table is None:
+            cache, views, cols = qkv.write_and_view(
+                cache, fresh, p, q.value.dtype)
+            return attend(views, qkv.position_mask(
+                cols, views[0].shape[1])), cache
+        # the read stops at the batch's longest row (the span ladder
+        # of kv.write_and_attend_paged)
+        cache, out = qkv.write_and_attend_paged(
+            cache, fresh, p, jnp.asarray(_value(page_table)),
+            lambda views, mask: attend(views, mask).value, q.value.dtype)
+        return Tensor(out), cache
 
 
 class LlamaMLP(nn.Layer):

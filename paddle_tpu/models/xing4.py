@@ -289,10 +289,16 @@ def mla_core(q, ckv, k_rope, w_kvb, cos, sin, *, cfg, cache=None, pos=None,
     latent = jnp.pad(latent.astype(cache.dtype), (
         (0, 0), (0, 0), (0, cache.shape[-1] - latent.shape[-1])))
     p = jnp.asarray(pos)
-    (cache,), (view,), cols = qkv.write_and_view(
-        (cache,), (latent,), p, page_table)
-    if (page_table is None and p.ndim == 0 and s == cache.shape[1]
-            and not absorbed):
+    if page_table is not None:
+        # the read stops at the batch's longest row (the span ladder
+        # of kv.write_and_attend_paged)
+        (cache,), out = qkv.write_and_attend_paged(
+            (cache,), (latent,), p, page_table,
+            lambda views, mask: attend(q_nope, q_rope, views[0], w_kvb,
+                                       mask, scale))
+        return out, cache
+    (cache,), (view,), cols = qkv.write_and_view((cache,), (latent,), p)
+    if p.ndim == 0 and s == cache.shape[1] and not absorbed:
         # a chunk as long as its block can only start at 0 (the
         # engines' prefill programs): plain causal attention among
         # the fresh tokens, no mask over the block

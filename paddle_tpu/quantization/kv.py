@@ -35,6 +35,10 @@ max-abs-err of int8-KV decode against the bf16 baseline.
 """
 from __future__ import annotations
 
+import functools
+
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
@@ -143,7 +147,8 @@ def kv_token_bytes(kv_heads, head_dim, dtype):
 # cache's dtype, nothing else) or QuantizedKV (quantize-on-write,
 # dequant-on-read), whatever its trailing shape: ``[.., kvH, D]`` or one
 # ``[.., cache_dim]`` latent array. The decoders reach them through
-# :func:`write_and_view`, which owns the addressing.
+# :func:`write_and_view` and :func:`write_and_attend_paged`, which own
+# the addressing.
 
 
 def write_at_pos(cache, val, pos):
@@ -220,48 +225,25 @@ def gather_pages_dense(pages, page_table, dtype):
 # --------------------------------------------------------- cache addressing
 
 
-def write_and_view(caches, fresh, pos, page_table=None, dtype=None):
-    """THE cache addressing, for every decoder: write a step's new
-    tokens into one layer's cache arrays and give back what attention
-    reads. ``caches`` is the layer's tuple of arrays (Llama: K and V,
-    plain or :class:`QuantizedKV`; a latent-attention net: one array),
-    ``fresh`` the matching tuple of ``[B, S, ...]`` payloads. Three
-    modes, told apart by what the caller holds:
+def write_and_view(caches, fresh, pos, dtype=None):
+    """The cache addressing of a block or a slab, for every decoder:
+    write a step's new tokens into one layer's cache arrays and give
+    back what attention reads. ``caches`` is the layer's tuple of arrays
+    (Llama: K and V, plain or :class:`QuantizedKV`; a latent-attention
+    net: one array), ``fresh`` the matching tuple of ``[B, S, ...]``
+    payloads. Two modes, told apart by ``pos`` (the third, a page
+    arena, is :func:`write_and_attend_paged`):
 
     - scalar ``pos``: a block or slab ``[B, S_max, ...]``, the tokens
       land at ``[pos, pos + S)`` of every row (prefill, a chunk at an
       offset, whole-batch decode); the view is the cache.
     - ``[B]`` ``pos``: a slab, row ``r``'s tokens land at ``[pos[r],
       pos[r] + S)`` (continuous batching); the view is the cache.
-    - ``page_table`` ``[B, P]`` with ``[B]`` ``pos``: a page arena
-      ``[pages, page_size, ...]`` shared by all rows; row ``r``'s ONE
-      token lands in page ``table[r, pos[r] // page_size]`` at offset
-      ``pos[r] % page_size``, and the view is the table-gathered
-      ``[B, P * page_size, ...]``. Page 0 is the garbage page: free
-      rows (a zeroed table row) write there, and nothing reads it but
-      through columns :func:`position_mask` closes. The bytes written
-      are BITWISE what :func:`write_at_pos` writes for that position:
-      the serving prefix cache publishes decode-written pages as
-      reusable prefix KV (``tests/test_prefix_cache.py``).
 
     Views come in the compute ``dtype`` where the storage is int8 and
     as stored otherwise. Returns ``(new_caches, views, cols)``,
     ``cols`` ``[B or 1, S]`` the cache column of each fresh token."""
     b, s = fresh[0].shape[:2]
-    if page_table is not None:
-        if s != 1:
-            raise ValueError(
-                f"paged decode feeds one token per row (S == 1), got S={s}"
-            )
-        ps = caches[0].shape[1]
-        page = jnp.take_along_axis(page_table, (pos // ps)[:, None],
-                                   axis=1)[:, 0]
-        offset = pos % ps
-        caches = tuple(write_paged(c, f[:, 0], page, offset)
-                       for c, f in zip(caches, fresh))
-        views = tuple(gather_pages_dense(c, page_table, dtype)
-                      for c in caches)
-        return caches, views, pos[:, None]
     if pos.ndim == 0:
         caches = tuple(write_at_pos(c, f, pos)
                        for c, f in zip(caches, fresh))
@@ -272,6 +254,80 @@ def write_and_view(caches, fresh, pos, page_table=None, dtype=None):
         caches = tuple(write_at_rows(c, f, rows, cols)
                        for c, f in zip(caches, fresh))
     return caches, tuple(read_dense(c, dtype) for c in caches), cols
+
+
+@functools.lru_cache(maxsize=None)
+def span_ladder(table_width):
+    """The widths, in pages, a paged read may be bounded to: eighths of
+    the table's width rounded up, duplicates dropped (a 16-page table
+    has 8 rungs of 2 pages, a 4-page one 4 of 1), ascending. A
+    function of the table's shape alone: the decode program, the
+    engine's ``span_tokens`` counter (a call a decode step) and the
+    tests all take it from here."""
+    return tuple(sorted({-(-table_width * k // 8) for k in range(1, 9)}))
+
+
+def span_rung(table_width, pos, page_size):
+    """Index into :func:`span_ladder` of the narrowest rung that holds
+    every row's position: the batch's longest row needs ``max(pos) //
+    page_size + 1`` pages. ``pos`` ``[B]`` is a numpy array (the
+    engine's counter) or a traced ``jax.numpy`` one (the decode
+    program): the same expression serves both. A position past the
+    table reads the last rung."""
+    need = pos.max() // page_size + 1
+    return (need > np.asarray(span_ladder(table_width)[:-1])).sum()
+
+
+def write_and_attend_paged(caches, fresh, pos, page_table, attend,
+                           dtype=None):
+    """The cache addressing of a page arena, with the attention it
+    feeds: ``caches`` are one layer's arenas ``[pages, page_size,
+    ...]`` shared by all rows, ``page_table`` ``[B, P]``, ``pos``
+    ``[B]``. Row ``r``'s ONE token lands in page ``table[r, pos[r] //
+    page_size]`` at offset ``pos[r] % page_size``. Page 0 is the
+    garbage page: free rows (a zeroed table row, ``pos`` 0) write
+    there, and nothing reads it but through columns
+    :func:`position_mask` closes. The bytes written are BITWISE what
+    :func:`write_at_pos` writes for that position: the serving prefix
+    cache publishes decode-written pages as reusable prefix KV
+    (``tests/test_prefix_cache.py``).
+
+    The read is bounded by the batch's longest row. Of the
+    :func:`span_ladder` of the table's width the program picks, on the
+    device from ``pos``, the narrowest rung that holds every row
+    (:func:`span_rung`), and one ``jax.lax.switch`` runs that rung's
+    branch: gather the pages of ``page_table[:, :rung]`` into ``[B,
+    rung * page_size, ...]`` views (in the compute ``dtype`` where the
+    storage is int8, as stored otherwise), build their position mask
+    and call the decoder's own contraction ``attend(views, mask)``.
+    Every branch is the whole-table read at a narrower width: the
+    columns left out are columns the mask closes for every row, which
+    add exact zeros, so the result does not depend on the rung. One
+    program serves every span; nothing is chosen on the host. Returns
+    ``(new_caches, attend's result)``."""
+    s = fresh[0].shape[1]
+    if s != 1:
+        raise ValueError(
+            f"paged decode feeds one token per row (S == 1), got S={s}"
+        )
+    ps = caches[0].shape[1]
+    page = jnp.take_along_axis(page_table, (pos // ps)[:, None],
+                               axis=1)[:, 0]
+    offset = pos % ps
+    caches = tuple(write_paged(c, f[:, 0], page, offset)
+                   for c, f in zip(caches, fresh))
+
+    def read(pages, caches, page_table, cols):
+        views = tuple(gather_pages_dense(c, page_table[:, :pages], dtype)
+                      for c in caches)
+        return attend(views, position_mask(cols, pages * ps))
+
+    width = page_table.shape[1]
+    out = jax.lax.switch(
+        span_rung(width, pos, ps),
+        [functools.partial(read, pages) for pages in span_ladder(width)],
+        caches, page_table, pos[:, None])
+    return caches, out
 
 
 def position_mask(cols, width):

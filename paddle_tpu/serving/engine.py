@@ -740,6 +740,12 @@ class ServingEngine:
         table of an active row that is not ``fed`` cleared)."""
         return ()
 
+    def _read_span(self, pos):
+        """Cache columns a row's attention reads in a decode step
+        launched at the rows' positions ``pos`` (numpy ``[B]``): a slab
+        row is read whole."""
+        return self.max_seq_len
+
     def _has_capacity(self):
         return self._slab.free_slots > 0
 
@@ -912,6 +918,7 @@ class ServingEngine:
             launch = any(seq is not None for seq in fed)
             if launch:
                 self.metrics.resident_tokens.observe(int(pos.sum()))
+                self.metrics.span_tokens.observe(self._read_span(pos))
                 tok = jnp.asarray(tok)
                 inputs = (
                     tok, self._flat, *self._decode_extra(fed),

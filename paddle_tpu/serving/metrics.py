@@ -187,6 +187,14 @@ class ServingMetrics:
             prom_name=f"{ns}_resident_tokens",
             help="per decode step: the rows' cache positions summed "
                  "(tokens the step attends over)")
+        self.span_tokens = Histogram(
+            "span_tokens", unit="toks", buckets=_reg.TOKEN_BUCKETS,
+            prom_name=f"{ns}_span_tokens",
+            help="per decode step: cache columns a row's attention read "
+                 "was bounded to (the paged engine: the rung of "
+                 "quantization.kv.span_ladder that holds the longest "
+                 "row; a slab: max_seq_len); mean / max_seq_len is the "
+                 "share of the table read")
         self.experts_touched = Histogram(
             "experts_touched", unit="experts",
             prom_name=f"{ns}_experts_touched",
@@ -231,7 +239,7 @@ class ServingMetrics:
             self.queue_wait, self.queue_depth, self.slot_occupancy,
             self.host_gap, self.read_wait, self.steps_overlapped,
             self.prefill, self.submit_wait,
-            self.resident_tokens, self.experts_touched,
+            self.resident_tokens, self.span_tokens, self.experts_touched,
             self.spec_rounds, self.spec_proposed, self.spec_accepted,
             self.spec_accept_length,
         ])
@@ -298,6 +306,7 @@ class ServingMetrics:
             "prefill": self.prefill.snapshot(),
             "submit_wait": self.submit_wait.snapshot(),
             "resident_tokens": self.resident_tokens.snapshot(),
+            "span_tokens": self.span_tokens.snapshot(),
             "experts_touched": self.experts_touched.snapshot(),
         }
 

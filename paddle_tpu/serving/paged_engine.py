@@ -21,9 +21,13 @@ recompiles — the slab engine's core discipline carries over):
   table-supplied ids (tail ids past the request's claim point at the
   garbage page 0 — no shape variance, no recompiles).
 - **decode step** (exactly one): ``[B]`` tokens + the ``[B, P_max]``
-  page table -> next tokens; attention gathers K/V through the table
-  (``models.llama`` paged path; a tuned Pallas paged-attention kernel
-  replaces the HBM gather when the tune cache opts one in).
+  page table -> next tokens; attention gathers K/V through the table,
+  and only as far as the batch's longest row reaches: the program
+  picks, on the device from ``pos``, the narrowest rung of a ladder of
+  table widths (eighths of ``P_max``) that holds every row, and one
+  ``lax.switch`` runs the gather and the contraction at that width
+  (``quantization.kv.write_and_attend_paged``). One program serves
+  every span; ``metrics.span_tokens`` records the width a launch.
 - **gather-pages** (per bucket, prefix-cache mode): materializes a
   request's cached-prefix pages as a prefill-layout block so the tail
   program can attend over them.
@@ -70,6 +74,7 @@ import jax.numpy as jnp
 from .. import profiler
 from ..models.generation import _select_next, decode_step
 from ..observability.tracing import get_tracer
+from ..quantization import kv as qkv
 from .engine import (
     ServingEngine,
     _Seq,
@@ -451,6 +456,12 @@ class PagedServingEngine(ServingEngine):
             tables = tables.copy()
             tables[unfed] = 0
         return (jnp.asarray(tables),)
+
+    def _read_span(self, pos):
+        # what the decode program picks on the device from the same pos
+        ladder = qkv.span_ladder(self.table_width)
+        return self.page_size * ladder[
+            qkv.span_rung(self.table_width, pos, self.page_size)]
 
     def _adopt_fn(self, bucket):
         """Scatter a prefilled [1, bucket] block into the arena as

@@ -1,8 +1,9 @@
 """The cache addressing both decoders share (``quantization/kv.py``:
-``write_and_view`` and ``position_mask``) against a numpy model of the
-same cache: three modes (a slab at a scalar position, a slab at
-per-row positions, a page arena through a table) over three payloads
-(a bf16 K/V pair, an int8 K/V pair, one latent array).
+``write_and_view``, ``write_and_attend_paged`` and ``position_mask``)
+against a numpy model of the same cache: three modes (a slab at a
+scalar position, a slab at per-row positions, a page arena through a
+table) over three payloads (a bf16 K/V pair, an int8 K/V pair, one
+latent array).
 
 The model keeps every row's LOGICAL cache ``[B, S_max, ...]`` as numpy
 arrays of what is stored (bf16 values, or int8 values and their
@@ -114,15 +115,40 @@ def assert_stored_equal(got, want):
 TABLE = 1 + np.random.default_rng(7).permutation(B * P).reshape(B, P)
 
 
+def spy(views, mask):
+    """An ``attend`` that hands back what the paged read gave it: the
+    views and the mask of the rung the program chose, padded to the
+    table's width (zeros; closed columns) as one ``switch`` needs."""
+    def pad(a, axis, value):
+        widths = [(0, 0)] * a.ndim
+        widths[axis] = (0, S_MAX - a.shape[axis])
+        return jnp.pad(a.astype(jnp.float32), widths,
+                       constant_values=value)
+    return tuple(pad(v, 1, 0.0) for v in views), pad(mask, 3, -jnp.inf)
+
+
 def run(logical, fresh, pos, s, int8, table=None):
-    """``write_and_view`` under jit (as the decoders trace it): the
-    new caches, the views as float32 and ``cols``."""
+    """``write_and_view`` (a slab) or ``write_and_attend_paged`` (an
+    arena through ``table``) under jit, as the decoders trace them:
+    the new caches, the views as float32 and ``cols``; of a paged read
+    the views of the chosen rung, zero-padded, and ``cols`` as its
+    mask has them (a row's last open column)."""
     caches = tuple(to_device(st, int8, table) for st in logical)
     tok = tuple(jnp.asarray(f[:, :s]) for f in fresh)
-    tbl = None if table is None else jnp.asarray(table, jnp.int32)
-    fn = jax.jit(lambda c, f, p: qkv.write_and_view(
-        c, f, p, tbl, jnp.float32))
-    new, views, cols = fn(caches, tok, jnp.asarray(pos, jnp.int32))
+    pos = jnp.asarray(pos, jnp.int32)
+    if table is None:
+        new, views, cols = jax.jit(lambda c, f, p: qkv.write_and_view(
+            c, f, p, jnp.float32))(caches, tok, pos)
+    else:
+        tbl = jnp.asarray(table, jnp.int32)
+        new, (views, mask) = jax.jit(
+            lambda c, f, p: qkv.write_and_attend_paged(
+                c, f, p, tbl, spy, jnp.float32))(caches, tok, pos)
+        assert mask.shape == (B, 1, 1, S_MAX)
+        is_open = np.asarray(mask)[:, 0, 0] == 0
+        cols = is_open.sum(-1, keepdims=True) - 1
+        # open columns are a prefix: slots 0..cols
+        assert (is_open == (np.arange(S_MAX)[None] <= cols)).all()
     return new, [np.asarray(v, np.float32) for v in views], np.asarray(cols)
 
 
@@ -178,11 +204,14 @@ def test_paged_rows_cross_a_page_boundary(payload):
     logical, fresh, int8 = make(payload, seed=1)
     caches = tuple(to_device(st, int8, TABLE) for st in logical)
     tbl = jnp.asarray(TABLE, jnp.int32)
-    step = jax.jit(lambda c, f, p: qkv.write_and_view(c, f, p, tbl))
+    step = jax.jit(lambda c, f, p: qkv.write_and_attend_paged(
+        c, f, p, tbl, spy))
     for t, p in enumerate((PS - 1, PS, PS + 1)):
         tok = tuple(jnp.asarray(f[:, t:t + 1]) for f in fresh)
-        caches, views, _ = step(caches, tok,
-                                jnp.full((B,), p, jnp.int32))
+        caches, (views, _) = step(caches, tok,
+                                  jnp.full((B,), p, jnp.int32))
+    # the read stopped at the second of four pages
+    assert all(not np.asarray(v[:, 2 * PS:]).any() for v in views)
     for cache, view, f in zip(caches, views, fresh):
         stored = f[:, :3].astype(BF16).astype(np.float32)
         for r in range(B):

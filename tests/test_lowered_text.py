@@ -1,5 +1,9 @@
-"""``tools/lowered_text.py diff``: the verdict it gives of two dumps
-of lowered programs (the dumps themselves are jax's text)."""
+"""``tools/lowered_text.py``: the verdict ``diff`` gives of two dumps
+of lowered programs (the dumps themselves are jax's text), and the
+tree's own dump against its record, ``tests/lowered_text.json``."""
+import json
+import os
+
 import pytest
 
 from tools import lowered_text
@@ -39,3 +43,49 @@ def test_diff_verdict(tmp_path, capsys, other, rc, says):
     assert says in out
     if rc:
         assert "- x1 % = stablehlo.add %, % : tensor<4xi32>" in out
+
+
+# ------------------------------------------------- the tree's own programs
+#
+# ``tests/lowered_text.json`` is ``tools/lowered_text.py digest`` of the
+# tree's dump. PR 31 (the span ladder of the paged decode read) wrote it:
+# every program but the four paged decode steps had the text of that PR's
+# parent, so a later change to a slab, block or train program shows here.
+# A PR that means to change one writes the record anew:
+#   JAX_PLATFORMS=cpu python tools/lowered_text.py dump . /tmp/lt
+#   python tools/lowered_text.py digest /tmp/lt tests/lowered_text.json
+
+RECORD = json.load(open(os.path.join(os.path.dirname(__file__),
+                                     "lowered_text.json")))
+LAYERS = {"llama_gqa": 2, "llama_gqa_w8": 2, "xing4": 3}
+
+
+@pytest.fixture(scope="module")
+def dumped(tmp_path_factory):
+    """The tree's dump, made as the tool's own process makes it
+    (conftest turns x64 on; the programs run without it)."""
+    import jax
+
+    out = tmp_path_factory.mktemp("lowered")
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
+    try:
+        lowered_text.dump_programs(
+            os.path.dirname(os.path.dirname(__file__)), str(out))
+    finally:
+        jax.config.update("jax_enable_x64", x64)
+    return lowered_text.digest(str(out))
+
+
+def test_dump_holds_the_recorded_programs(dumped):
+    assert sorted(dumped) == sorted(RECORD)
+
+
+@pytest.mark.parametrize("program", sorted(RECORD))
+def test_program_text_is_the_recorded_one(dumped, program):
+    """Slab, block and train programs: the text PR 31's parent had.
+    A paged decode step: exactly one ``stablehlo.case`` a layer, the
+    span ladder; no other program holds one."""
+    tag, _, rest = program.partition("_decode_paged_")
+    assert dumped[program]["cases"] == (LAYERS[tag] if rest else 0)
+    assert dumped[program] == RECORD[program]

@@ -872,6 +872,54 @@ def test_unfed_row_leaves_its_pages_alone(net):
     eng.close()
 
 
+def test_span_ladder_crosses_rungs_without_a_compile(gqa_net):
+    """The paged decode read is bounded on the device, inside the ONE
+    decode program: a run whose longest row climbs over three rung
+    boundaries compiles nothing after ``warmup()``, its program
+    inventory is what it was before the ladder, the streams are
+    ``generate()``'s, and ``span_tokens`` has a sample a launch, each
+    the rung that holds the launch's longest row."""
+    from paddle_tpu.quantization import kv as qkv
+
+    eng = _lag_engine("paged", gqa_net, max_batch_size=3, page_size=4)
+    rungs = [4 * pages for pages in qkv.span_ladder(eng.table_width)]
+    assert rungs == [8, 16, 24, 32, 40, 48, 56, 64]  # eighths of 64
+    stats = eng.warmup()
+    # decode + (prefill + adopt) per bucket 8..64: one decode program
+    assert stats["programs"] == 1 + 2 * 4
+    before = dict(eng.trace_guard.compile_counts())
+    seen = []
+    observe = eng.metrics.span_tokens.observe
+    eng.metrics.span_tokens.observe = lambda v: (seen.append(v),
+                                                 observe(v))[1]
+    prompts = [RNG.randint(0, 64, (1, n)) for n in (6, 3, 5)]
+    news = [20, 4, 9]
+    hs = [eng.submit(p, m) for p, m in zip(prompts, news)]
+    eng.run_until_idle()
+    assert dict(eng.trace_guard.compile_counts()) == before
+    assert eng.trace_guard.findings == []
+    for h, p, m in zip(hs, prompts, news):
+        np.testing.assert_array_equal(h.output_ids, _ref(gqa_net, p, m))
+    rep = eng.metrics.report()
+    assert rep["span_tokens"]["count"] == rep["resident_tokens"]["count"] \
+        == len(seen) == max(news) - 1
+    # the longest row is launched at positions 6..24: four rungs
+    assert seen == [rungs[(6 + i) // 8] for i in range(len(seen))]
+    assert sorted(set(seen)) == rungs[:4]
+    assert rep["span_tokens"]["sum"] == sum(seen)
+    eng.close()
+
+
+def test_slab_span_is_the_whole_row(net):
+    eng = _lag_engine("slab", net, max_batch_size=2)
+    eng.submit(RNG.randint(0, 64, (1, 5)), 4)
+    eng.run_until_idle()
+    rep = eng.metrics.report()
+    assert rep["span_tokens"]["count"] == rep["resident_tokens"]["count"] == 3
+    assert rep["span_tokens"]["sum"] == 3 * eng.max_seq_len
+    eng.close()
+
+
 @pytest.mark.parametrize("kind", ["slab", "paged"])
 def test_full_batch_overlaps_every_step_but_the_first(net, kind):
     """A full batch and no admission after the first iteration: every

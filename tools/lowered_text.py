@@ -18,8 +18,20 @@ the same files.
 says of each program whether the text is identical, holds the same
 operations in another order (value names taken out, lines compared as
 a multiset), or which operations only one side has.
+
+    python tools/lowered_text.py digest <dump> <file.json>
+
+writes a dump's record: of each program the SHA-256 of its text and the
+number of ``stablehlo.case`` it holds (one a layer in a paged decode
+step: the span ladder of ``quantization.kv.write_and_attend_paged``;
+none anywhere else). ``tests/lowered_text.json`` is the record
+``tests/test_lowered_text.py`` holds the tree to: a PR that means to
+change a program writes it anew from its own dump, and one that does
+not finds out there that it did.
 """
 import argparse
+import hashlib
+import json
 import os
 import re
 import sys
@@ -54,14 +66,32 @@ def diff(a, b):
     return worst
 
 
+def digest(dump):
+    """``{program: {"sha256": ..., "cases": ...}}`` of a dump."""
+    record = {}
+    for name in sorted(os.listdir(dump)):
+        text = open(os.path.join(dump, name)).read()
+        record[name[:-len(".txt")]] = {
+            "sha256": hashlib.sha256(text.encode()).hexdigest(),
+            "cases": len(re.findall(r"\bstablehlo\.case\b", text)),
+        }
+    return record
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("mode", choices=("dump", "diff"))
-    ap.add_argument("first", help="dump: the checkout; diff: a dump")
-    ap.add_argument("second", help="dump: where to write; diff: a dump")
+    ap.add_argument("mode", choices=("dump", "diff", "digest"))
+    ap.add_argument("first", help="dump: the checkout; else: a dump")
+    ap.add_argument("second", help="dump: where to write; diff: a dump; "
+                                   "digest: the record to write")
     args = ap.parse_args()
     if args.mode == "diff":
         return diff(args.first, args.second)
+    if args.mode == "digest":
+        with open(args.second, "w") as f:
+            json.dump(digest(args.first), f, indent=1)
+            f.write("\n")
+        return 0
     return dump_programs(os.path.abspath(args.first), args.second)
 
 
