@@ -25,4 +25,8 @@ from .llama_pipe import (  # noqa: F401
     LlamaDecoderLayerTP,
     LlamaForCausalLMPipe,
 )
+from .solar_open2 import (  # noqa: F401
+    SolarOpen2Config,
+    SolarOpen2ForCausalLM,
+)
 from .xing4 import Xing4Config, Xing4ForCausalLM  # noqa: F401

@@ -121,12 +121,42 @@ def cache_layout(cfg):
             for layer in stated()]
 
 
+def row_layout(cfg):
+    """What one ROW keeps whatever its length, layer by layer: for each
+    layer a tuple of ``(shape, dtype)``, one for every array addressed
+    by row and not by token (a row array is ``[rows, *shape]``, in
+    ``alloc_kv_caches``, in a block and beside a page arena alike;
+    dtype None is the cache's). A config with a recurrent layer states
+    it (``cfg.row_layout()``: the layer's state and its convolution
+    tail); one that states nothing keeps nothing a row."""
+    stated = getattr(cfg, "row_layout", None)
+    if stated is None:
+        return [()] * len(cache_layout(cfg))
+    return [tuple((tuple(int(d) for d in shape), dtype)
+                  for shape, dtype in layer) for layer in stated()]
+
+
+def keeps_row_state(cfg):
+    """True where some layer keeps an array a row (``row_layout``)."""
+    return any(row_layout(cfg))
+
+
+def row_array_mask(cfg):
+    """For the flat list of a net's cache arrays (``[a for layer in
+    caches for a in layer]``): True where the array is addressed by
+    row, False by token."""
+    return [is_row for tokens, rows in zip(cache_layout(cfg),
+                                           row_layout(cfg))
+            for is_row in (False,) * len(tokens) + (True,) * len(rows)]
+
+
 def keeps_kv_pairs(cfg):
-    """True where every layer's cache is a K and a V of ``(kvH, D)``:
-    the layout int8 storage, the prefix cache, tiering and speculation
-    are written for."""
-    return all(len(layer) == 2 and len(layer[0]) == 2
-               and layer[0] == layer[1] for layer in cache_layout(cfg))
+    """True where every layer's cache is a K and a V of ``(kvH, D)``
+    and nothing a row: the layout int8 storage, the prefix cache,
+    tiering and speculation are written for."""
+    return not keeps_row_state(cfg) and all(
+        len(layer) == 2 and len(layer[0]) == 2
+        and layer[0] == layer[1] for layer in cache_layout(cfg))
 
 
 def cache_token_bytes(cfg, cache_dtype):
@@ -138,20 +168,33 @@ def cache_token_bytes(cfg, cache_dtype):
                for layer in cache_layout(cfg) for a in layer)
 
 
+def cache_row_bytes(cfg, cache_dtype):
+    """Bytes ONE row keeps over every layer's row arrays, whatever its
+    length (0 for a net that states none)."""
+    default = jnp.dtype(normalize_cache_dtype(cache_dtype))
+    return sum(math.prod(shape) * jnp.dtype(dtype or default).itemsize
+               for layer in row_layout(cfg) for shape, dtype in layer)
+
+
 def unflatten_caches(flat, cfg):
     """The per-layer tuples of a flat list of cache arrays (the
-    inverse of ``[a for layer in caches for a in layer]``)."""
+    inverse of ``[a for layer in caches for a in layer]``): a layer's
+    token arrays, then its row arrays."""
     out, i = [], 0
-    for layer in cache_layout(cfg):
-        out.append(tuple(flat[i:i + len(layer)]))
-        i += len(layer)
+    for tokens, rows in zip(cache_layout(cfg), row_layout(cfg)):
+        n = len(tokens) + len(rows)
+        out.append(tuple(flat[i:i + n]))
+        i += n
     return out
 
 
-def alloc_kv_caches(cfg, B, S_max, cache_dtype=None):
+def alloc_kv_caches(cfg, B, S_max, cache_dtype=None, rows=None):
     """Per-layer static cache buffers ``[B, S_max, *trailing]``, one
     for each array ``cache_layout(cfg)`` states (Llama: K and V,
-    ``[B, S_max, kvH, D]`` x2 a layer).
+    ``[B, S_max, kvH, D]`` x2 a layer), and behind them in the layer's
+    tuple ``[rows, *shape]`` for each array ``row_layout(cfg)`` states
+    (``rows`` defaults to ``B``; a page arena, whose ``B`` counts
+    pages, says how many rows it serves).
 
     ONE place owns the serving cache layout and dtype: the whole-decode
     programs here, the serving engine's slot slab, and the bucketed
@@ -177,9 +220,12 @@ def alloc_kv_caches(cfg, B, S_max, cache_dtype=None):
             for layer in layout
         ]
     dtype = jnp.dtype(name)
+    rows = B if rows is None else int(rows)
     return [
         tuple(jnp.zeros((B, S_max) + a, dtype) for a in layer)
-        for layer in layout
+        + tuple(jnp.zeros((rows,) + shape, jnp.dtype(kept or dtype))
+                for shape, kept in row_layer)
+        for layer, row_layer in zip(layout, row_layout(cfg))
     ]
 
 
@@ -187,9 +233,13 @@ def prefill(net, ids, caches, length=None, pos=0):
     """Run the prompt through the cache path in one pass (caches filled
     [pos, pos + S)). ``ids`` may be right-padded to a bucket length:
     pass ``length`` (scalar, traceable) and the returned logits row is
-    taken at position ``length - 1`` instead of the last column — pad
-    tokens only ever write cache slots that decode overwrites before
-    reading (causal masking), so bucketed prefill is numerically exact.
+    taken at position ``length - 1`` instead of the last column.
+    Bucketed prefill is numerically exact for both kinds of cache: in
+    an array addressed by TOKEN pad tokens only ever write slots that
+    decode overwrites before reading (causal masking); an array
+    addressed by ROW (a recurrent state, ``row_layout``) has no such
+    slots, so a net that keeps one is handed ``length`` and freezes its
+    row arrays past it.
 
     ``pos`` (scalar, traceable; default 0) starts the chunk at an
     offset: tokens land at cache positions [pos, pos + S) and attend to
@@ -205,6 +255,8 @@ def prefill(net, ids, caches, length=None, pos=0):
     # measured)
     one_row = length is not None and getattr(net, "head_takes_row", False)
     kw = {"head_row": jnp.asarray(length, jnp.int32) - 1} if one_row else {}
+    if length is not None and keeps_row_state(net.config):
+        kw["length"] = jnp.asarray(length, jnp.int32)
     with tape.trace_scope(), tape.no_grad():
         logits, caches = net(
             Tensor(ids), caches=caches, pos=jnp.asarray(pos, jnp.int32),

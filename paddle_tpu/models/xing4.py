@@ -377,15 +377,25 @@ def moe_weights(scores, idx, *, scale, renorm):
     return w * scale
 
 
-def moe_dispatch(h, idx, w, w_gate_up, w_down):
+def moe_dispatch(h, idx, w, w_gate_up, w_down, first=0, held=None):
     """Dropless expert FFN: ``h`` ``[T, C]``, assignments ``idx``/``w``
     ``[T, k]``, experts stacked ``[E, C, 2 I]`` / ``[E, I, C]``. The
     ``T k`` assignments are sorted by expert, each projection is one
     grouped matmul over the sorted rows (group sizes from a bincount),
-    and every row comes back to its token: no capacity, none dropped."""
+    and every row comes back to its token: no capacity, none dropped.
+
+    With ``held`` the stacks are a SHARE of the experts ``idx`` numbers:
+    experts ``[first, first + held)``. An assignment to an absent
+    expert is sorted behind the held groups, belongs to no group of
+    either grouped matmul, and adds nothing: the result is the held
+    experts' part of the sum, under weights made over all ``k``."""
     t, k = idx.shape
     n_exp, _, two_i = w_gate_up.shape
     flat = idx.reshape(-1)
+    if held is not None:
+        local = flat - first
+        here = (local >= 0) & (local < held)
+        flat = jnp.where(here, local, held)
     order = jnp.argsort(flat, stable=True)
     sizes = jnp.bincount(flat, length=n_exp).astype(jnp.int32)
     xs = h[order // k]
@@ -393,6 +403,9 @@ def moe_dispatch(h, idx, w, w_gate_up, w_down):
     act = jax.nn.silu(gu[:, :two_i // 2]) * gu[:, two_i // 2:]
     ys = jax.lax.ragged_dot(act, w_down, sizes)
     back = ys[jnp.argsort(order)].reshape(t, k, -1)
+    if held is not None:
+        # rows of no group hold whatever the grouped matmul left there
+        back = jnp.where(here.reshape(t, k, 1), back, 0)
     return jnp.sum(back.astype(_F32) * w[..., None], axis=1).astype(h.dtype)
 
 

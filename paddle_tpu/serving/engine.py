@@ -50,7 +50,9 @@ from ..models.generation import (
     cache_layout,
     decode_step,
     keeps_kv_pairs,
+    keeps_row_state,
     prefill,
+    row_layout,
     unflatten_caches,
 )
 from ..observability.tracing import get_tracer
@@ -94,12 +96,33 @@ def step_counters(net):
     return pop() if pop is not None else {}
 
 
+# Why each option is refused over a net that keeps a state a ROW beside
+# its pages (``generation.row_layout``), one sentence each
+_ROW_STATE_REFUSALS = {
+    "int8 cache storage":
+        "it quantizes K and V per head and has no form for the state",
+    "speculative decoding":
+        "a rejected draft would have to roll the row's state back",
+    "the prefix cache":
+        "it would have to snapshot the state at page boundaries, and its "
+        "chunked prefill of a tail would start from a state no page holds",
+    "KV tiering": "it spills and restores the prefix cache's pages",
+    "remote prefill": "its wire block carries pages and no row state",
+}
+
+
 def build_prefill_body(net, do_sample, top_k, top_p):
     """The (un-jitted) bucketed-prefill program body every prefill site
     shares: the engines' per-bucket programs and the fleet tier's
     remote :class:`~.fleet.kv_transfer.PrefillWorker` trace the SAME
     function, which is what makes a disaggregated prefill bit-identical
-    to a local one (same weights -> same block, same first token)."""
+    to a local one (same weights -> same block, same first token).
+
+    Over a net that keeps a state a row the block's row arrays come
+    back holding the prompt's final state (``generation.prefill`` hands
+    the net ``length``), and the program is called
+    ``prefill_state_body``: a trace reader can tell it from the other
+    ``jit_body`` programs."""
 
     def body(params, buffers, ids, length, flat_block, temperature, key):
         net.load_functional_state(params, buffers)
@@ -116,6 +139,8 @@ def build_prefill_body(net, do_sample, top_k, top_p):
                            key)
         return nxt, _flatten(caches)
 
+    if keeps_row_state(net.config):
+        body.__name__ = body.__qualname__ = "prefill_state_body"
     return body
 
 
@@ -258,9 +283,17 @@ class ServingEngine:
         self.cache_dtype = self.pool.dtype
         if not keeps_kv_pairs(cfg):
             # a net that states another cache than K and V per head
-            # (a latent page) is served plainly or not at all
+            # (a latent page, a state a row) is served plainly or not
+            # at all
             asked = [what for what, on in self._kv_pair_features(
                 speculative).items() if on]
+            if asked and keeps_row_state(cfg):
+                raise ValueError(
+                    f"{type(net).__name__} keeps a state a row beside "
+                    f"its pages; " + "; ".join(
+                        f"{what} is not supported over it: "
+                        f"{_ROW_STATE_REFUSALS[what]}" for what in asked)
+                )
             if asked:
                 raise ValueError(
                     f"{type(net).__name__} states a cache that is not "
@@ -1002,7 +1035,13 @@ class ServingEngine:
 
     def _settle(self):
         """Read and emit the step in flight and leave nothing in
-        flight: what an admission waits for."""
+        flight: what an admission waits for. That is enough for the
+        arrays a net keeps a ROW too: the step in flight was launched
+        with the row free (or for an occupant that has since finished),
+        so whatever it writes into the row's state it writes BEFORE the
+        adopt program, which takes that step's output arrays as its
+        input and overwrites the row; and no step launched after the
+        adoption feeds the row anything but its new occupant's tokens."""
         launched, self._in_flight = self._in_flight, None
         with profiler.RecordEvent("serving::settle", step=launched.step):
             toks = self._read(launched)
@@ -1205,6 +1244,14 @@ class ServingEngine:
                 "cache": [list(a) for a in cache_layout(cfg)[0]],
             },
         }
+        if keeps_row_state(cfg):
+            # what a row keeps beside its tokens, every layer's (a net
+            # that keeps nothing a row has the key it always had)
+            sig["model"]["cache_by_layer"] = [
+                [list(a) for a in layer] for layer in cache_layout(cfg)]
+            sig["model"]["rows"] = [
+                [[list(shape), str(dtype)] for shape, dtype in layer]
+                for layer in row_layout(cfg)]
         if name.startswith("spec_") and self.speculative is not None:
             # draft geometry/acceptance depth change the traced program
             # — a cache hit across different speculative configs would

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from ..models.generation import (
     DEFAULT_CACHE_DTYPE,
     alloc_kv_caches,
+    cache_row_bytes,
     cache_token_bytes,
     normalize_cache_dtype,
 )
@@ -55,7 +56,9 @@ def bucket_for(seq_len, min_bucket=16, max_seq_len=None):
 class KVBlock:
     """A bucketed per-request cache handle: ``caches`` is the
     ``alloc_kv_caches`` layout ([1, bucket, *trailing] per array the
-    layer's cache statement names; Llama: [1, bucket, kvH, D] x2)."""
+    layer's cache statement names; Llama: [1, bucket, kvH, D] x2; a
+    net with a recurrent layer also the ``[1, *shape]`` arrays it keeps
+    a row, which carry the prompt's final state to adoption)."""
 
     __slots__ = ("bucket", "caches", "_live")
 
@@ -193,8 +196,10 @@ class KVCachePool:
 
     def _bytes(self, bucket, rows=1):
         # int8 counts its per-token fp32 scale overhead — residency
-        # numbers must not flatter quantized caches
-        return rows * bucket * cache_token_bytes(self.config, self.dtype)
+        # numbers must not flatter quantized caches; what a net keeps a
+        # row whatever its length (a recurrent state) counts once a row
+        return rows * (bucket * cache_token_bytes(self.config, self.dtype)
+                       + cache_row_bytes(self.config, self.dtype))
 
     def stats(self):
         free_blocks = sum(len(v) for v in self._freelists.values())

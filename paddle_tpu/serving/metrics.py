@@ -202,9 +202,17 @@ class ServingMetrics:
                  "at least one token, summed over the expert layers "
                  "(counted by the decode program, read with the next "
                  "tokens)")
+        self.local_assignments = Histogram(
+            "local_assignments", unit="assignments",
+            prom_name=f"{ns}_local_assignments",
+            help="per decode step of an expert model that holds a share "
+                 "of its experts: (row, chosen expert) assignments that "
+                 "landed on an expert held here, summed over the expert "
+                 "layers (counted by the decode program)")
         # what a net's decode program may count (``pop_step_counters``),
         # by the name it returns it under
-        self.step_counters = {"experts_touched": self.experts_touched}
+        self.step_counters = {"experts_touched": self.experts_touched,
+                              "local_assignments": self.local_assignments}
         # speculative decoding (serving.speculative): one round = one
         # draft proposal pass + one target verify launch
         self.spec_rounds = Counter(
@@ -240,6 +248,7 @@ class ServingMetrics:
             self.host_gap, self.read_wait, self.steps_overlapped,
             self.prefill, self.submit_wait,
             self.resident_tokens, self.span_tokens, self.experts_touched,
+            self.local_assignments,
             self.spec_rounds, self.spec_proposed, self.spec_accepted,
             self.spec_accept_length,
         ])
@@ -308,6 +317,7 @@ class ServingMetrics:
             "resident_tokens": self.resident_tokens.snapshot(),
             "span_tokens": self.span_tokens.snapshot(),
             "experts_touched": self.experts_touched.snapshot(),
+            "local_assignments": self.local_assignments.snapshot(),
         }
 
     def observe_step_counters(self, counted):

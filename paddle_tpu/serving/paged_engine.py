@@ -19,7 +19,12 @@ recompiles — the slab engine's core discipline carries over):
 - **adopt-pages** (per bucket): scatters the prefilled ``[1, bucket]``
   block into the arena as ``bucket / page_size`` whole pages at
   table-supplied ids (tail ids past the request's claim point at the
-  garbage page 0 — no shape variance, no recompiles).
+  garbage page 0 — no shape variance, no recompiles). Over a net that
+  keeps a state a ROW beside its pages (``generation.row_layout``: a
+  recurrent layer) the block also carries the prompt's final state,
+  and the program, ``adopt_state_body``, copies it into the decode
+  row: two kinds of cache in one manager, arrays addressed by token
+  through the page table and arrays addressed by row.
 - **decode step** (exactly one): ``[B]`` tokens + the ``[B, P_max]``
   page table -> next tokens; attention gathers K/V through the table,
   and only as far as the batch's longest row reaches: the program
@@ -72,7 +77,11 @@ import jax
 import jax.numpy as jnp
 
 from .. import profiler
-from ..models.generation import _select_next, decode_step
+from ..models.generation import (
+    _select_next,
+    decode_step,
+    row_array_mask,
+)
 from ..observability.tracing import get_tracer
 from ..quantization import kv as qkv
 from .engine import (
@@ -260,7 +269,11 @@ class PagedServingEngine(ServingEngine):
                 current_version=lambda: self.weights_version,
             )
         self.table_width = pp.table_width()
-        self._flat = _flatten(pp.alloc_arena_arrays())
+        # a net that keeps a state a row (generation.row_layout) gets
+        # it here, a row of each array a decode row, beside the pages
+        self._flat = _flatten(pp.alloc_arena_arrays(
+            rows=self.max_batch_size))
+        self._row_arrays = row_array_mask(self.config)
         self._tables = np.zeros(
             (self.max_batch_size, self.table_width), np.int32
         )
@@ -300,6 +313,9 @@ class PagedServingEngine(ServingEngine):
         self._row_pages[slot] = None
         self._row_meta[slot] = None
         self._tables[slot, :] = 0  # free row reads/writes garbage page
+        # what the net keeps a ROW (a recurrent state) is not cleared:
+        # a free row's garbage updates stay in the free row, and the
+        # next adoption overwrites state and tail whole
         self._free_rows.append(slot)
 
     def _finish(self, slot, status, reason=None):
@@ -468,21 +484,28 @@ class PagedServingEngine(ServingEngine):
         ``bucket / page_size`` whole pages at traced page ids — one
         program per bucket, ids beyond the request's claim point at the
         garbage page 0 (duplicate scatter indices there are fine: the
-        page is garbage by contract)."""
+        page is garbage by contract). Over a net that keeps a state a
+        row the program (``adopt_state_body``) also takes the decode
+        ``row`` and copies the block's row arrays, the prompt's final
+        state, into it."""
         fn = self._adopt_fns.get(bucket)
         if fn is not None:
             return fn
         ps = self.page_size
         n_pages_b = bucket // ps
+        by_row = self._row_arrays
 
-        def body(flat_arena, flat_block, page_ids):
-            from ..quantization.kv import adopt_into_pages
+        def body(flat_arena, flat_block, page_ids, *row):
+            from ..quantization.kv import adopt_into_pages, adopt_into_slab
 
             return [
-                adopt_into_pages(a, b, page_ids, n_pages_b, ps)
-                for a, b in zip(flat_arena, flat_block)
+                adopt_into_slab(a, b, *row) if is_row
+                else adopt_into_pages(a, b, page_ids, n_pages_b, ps)
+                for a, b, is_row in zip(flat_arena, flat_block, by_row)
             ]
 
+        if any(by_row):
+            body.__name__ = body.__qualname__ = "adopt_state_body"
         fn = jax.jit(
             body, donate_argnums=(0,)
         )
@@ -543,8 +566,16 @@ class PagedServingEngine(ServingEngine):
     def _adopt_example_args(self, flat_block, bucket):
         return (
             self._flat, flat_block,
-            jnp.zeros((bucket // self.page_size,), jnp.int32),
+            *self._adopt_where(
+                np.zeros((bucket // self.page_size,), np.int32), 0),
         )
+
+    def _adopt_where(self, page_ids, row):
+        """Where an adoption lands, as the adopt program takes it: the
+        page ids, and the decode row where the net keeps a state a
+        row."""
+        where = (jnp.asarray(page_ids),)
+        return where + (jnp.int32(row),) if any(self._row_arrays) else where
 
     def _program_signature(self, name):
         sig = super()._program_signature(name)
@@ -941,7 +972,8 @@ class PagedServingEngine(ServingEngine):
                 page_ids[n_ref:k1] = row_pages[n_ref:k1]
                 self._flat = self._run(
                     ("adopt", bucket), self._adopt_fn(bucket),
-                    self._flat, new_flat, jnp.asarray(page_ids),
+                    self._flat, new_flat,
+                    *self._adopt_where(page_ids, row),
                 )
             if asp is not None:
                 asp.finish()

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 
 from ..models.generation import (
     alloc_kv_caches,
+    cache_row_bytes,
     cache_token_bytes,
     normalize_cache_dtype,
 )
@@ -109,19 +110,24 @@ class PagedKVPool:
         slots (the compiled decode step's fixed table shape)."""
         return -(-self.max_seq_len // self.page_size)
 
-    def alloc_arena_arrays(self):
+    def alloc_arena_arrays(self, rows=0):
         """The page arena in the shared cache layout: for every array
         the config's cache statement names
         (``generation.cache_layout``), ``[num_pages + 1, page_size,
         *trailing]`` (row 0 = garbage page), pool dtype — Llama's
         ``[.., kvH, D]`` x2 a layer, a latent net's one ``[.., latent +
-        rope dims]``. The arena IS the batch-of-pages view of
+        rope dims]``. Behind them in a layer's tuple lie the arrays the
+        net keeps a ROW (``generation.row_layout``: a recurrent state),
+        ``[rows, *shape]`` for the engine's ``rows`` decode rows: pages
+        cannot hold them, the page table does not address them, and a
+        net that states none gets none. The arena IS the batch-of-pages
+        view of
         ``alloc_kv_caches``: an int8 pool gets quantized storage there
         (int8 values + per-(slot, kvH) fp32 scales as one
         ``QuantizedKV`` pytree per array; zero scales keep the garbage
         page dequantizing to exact zeros)."""
         return alloc_kv_caches(self.config, self.num_pages + 1,
-                               self.page_size, self.dtype)
+                               self.page_size, self.dtype, rows=rows)
 
     # ------------------------------------------------------- claim flow
     @property
@@ -226,6 +232,16 @@ class PagedKVPool:
         # equal-HBM concurrency comparison must not flatter quantization
         return self.page_size * cache_token_bytes(cfg, self.dtype)
 
+    def row_bytes(self):
+        """HBM bytes ONE decode row keeps beside the pages, whatever
+        its length (``generation.row_layout``); 0 for a net that keeps
+        its whole cache by token. Allocated once a row with the arena
+        and never claimed or released: page accounting does not count
+        them."""
+        if self.config is None:
+            return 0
+        return cache_row_bytes(self.config, self.dtype)
+
     def request_resident_bytes(self, total_tokens):
         """Resident KV bytes one admitted request costs in this pool —
         the number the slab-vs-paged concurrency test compares against
@@ -248,6 +264,7 @@ class PagedKVPool:
             "peak_pages_in_use": self.peak_in_use,
             "increfs": self.increfs,
             "page_bytes": self.page_bytes(),
+            "row_bytes": self.row_bytes(),
             "arena_bytes": self.arena_bytes(),
             "claims": self.claims,
             "releases": self.releases,

@@ -179,6 +179,58 @@ def test_kernel_compiles_for_v5e_at_7b_widths(chip_compile, topo,
         "no Mosaic kernel in the compiled program")
 
 
+def _kda_step(q, k, v, g, beta, state):
+    from paddle_tpu.models import solar_open2
+
+    return solar_open2.kda_step(q, k, v, g, beta, state)
+
+
+def _kda_scan(q, k, v, g, beta, state):
+    from paddle_tpu.models import solar_open2
+
+    return solar_open2.kda_scan(q, k, v, g, beta, state, 64,
+                                length=jnp.int32(1500))
+
+
+def _held_experts(h, idx, w, gate_up, down):
+    from paddle_tpu.models import xing4
+
+    return xing4.moe_dispatch(h, idx, w, gate_up, down, first=0, held=40)
+
+
+# Solar-Open2's serving cell (BENCHMARK.json): 64 decode rows and one
+# 2048-token prefill of 64 KDA heads of 128 with a float32 state a row,
+# 40 of 320 experts of width 1280 held, top-8. No Mosaic kernel among
+# them: what is held here is that the chip's compiler takes the scan's
+# triangular solve, the grouped matmul over rows of no group, and how
+# much each needs beside its arguments.
+_ROW = lambda n, *d: ((n, 64) + d, F32)
+SOLAR_CASES = {
+    "kda step 64 rows": (
+        _kda_step, [_ROW(64, 128)] * 4 + [_ROW(64), _ROW(64, 128, 128)],
+        2 << 30),
+    "kda chunked scan 2048 tokens": (
+        _kda_scan, [((1, 2048, 64, 128), F32)] * 4 + [((1, 2048, 64), F32),
+                                                      _ROW(1, 128, 128)],
+        2 << 30),
+    "held experts decode 64 rows": (
+        _held_experts, [((64, 4096), BF), ((64, 8), jnp.int32),
+                        ((64, 8), F32), ((40, 4096, 2560), BF),
+                        ((40, 1280, 4096), BF)], 1 << 30),
+    "held experts prefill 2048 rows": (
+        _held_experts, [((2048, 4096), BF), ((2048, 8), jnp.int32),
+                        ((2048, 8), F32), ((40, 4096, 2560), BF),
+                        ((40, 1280, 4096), BF)], 2 << 30),
+}
+
+
+@pytest.mark.parametrize("name", SOLAR_CASES)
+def test_row_state_and_share_programs_compile_for_v5e(chip_compile, name):
+    fn, shapes, room = SOLAR_CASES[name]
+    compiled = chip_compile(fn, *(chip_compile.sds(*s) for s in shapes))
+    assert compiled.memory_analysis().temp_size_in_bytes < room
+
+
 def test_rms_norm_row_block_fits_vmem_budget():
     """The row block shrinks with hidden x itemsize, fwd and bwd apart
     (the seed's fixed 256 rows ran the 4096-wide backward out of VMEM),
