@@ -70,6 +70,7 @@ from .llama import _cache_attention
 from .xing4 import (
     Xing4MLP,
     _causal_attention,
+    dispatch_rows,
     experts_touched,
     moe_choose,
     moe_dispatch,
@@ -538,10 +539,12 @@ class SolarOpen2MoE(nn.Layer):
                 cache=False)
             local = idx - first
             here = (local >= 0) & (local < held)
+            n_local = jnp.sum(here).astype(jnp.int32)
             self.last_counts = {
                 "experts_touched": experts_touched(
                     jnp.where(here, local, held), held),
-                "local_assignments": jnp.sum(here).astype(jnp.int32)}
+                "local_assignments": n_local,
+                "dispatch_rows": dispatch_rows(idx.size, n_local)}
         with jax.named_scope("shared_expert"):
             y = y + self.shared_expert(h)
         return y.reshape(shape)
@@ -645,8 +648,10 @@ class SolarOpen2ForCausalLM(nn.Layer):
     def pop_step_counters(self):
         """What the step just traced counted, summed over the layers:
         ``experts_touched``, the HELD experts that got at least one
-        token, and ``local_assignments``, the assignments that landed
-        on held experts."""
+        token, ``local_assignments``, the assignments that landed on
+        held experts, and ``dispatch_rows``, the sorted rows the grouped
+        matmuls were handed (the rung of ``xing4.row_ladder`` that
+        holds a layer's local assignments)."""
         total = {}
         for layer in self.model.layers:
             counts, layer.mlp.last_counts = layer.mlp.last_counts, None

@@ -43,11 +43,13 @@ arXiv 2512.24880's for the residual path. ``x`` below is ``[T, n, C]``:
 """
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import nn
 from ..core import dispatch
@@ -377,6 +379,37 @@ def moe_weights(scores, idx, *, scale, renorm):
     return w * scale
 
 
+# Below this many sorted rows a narrower grouped matmul gains nothing on
+# the chip: at 128 rows the kernel already runs at the speed its
+# experts' weights stream (PERF.md section 6, PR 33).
+_ROW_FLOOR = 128
+
+
+def row_ladder(rows):
+    """The sorted-row counts the dispatch of a held SHARE may be bounded
+    to, ascending: a quarter and a half of the ``rows`` = ``T k``
+    assignments, rounded up, and all of them; no rung under
+    ``_ROW_FLOOR`` rows unless it is the last. 512 rows: 128, 256, 512;
+    128 rows or fewer: the one rung. A function of the row count alone:
+    the dispatch, the ``dispatch_rows`` counter and the tests all take
+    it from here."""
+    return tuple(sorted({max(-(-rows // part), min(rows, _ROW_FLOOR))
+                         for part in (4, 2, 1)}))
+
+
+def row_rung(rows, n_local):
+    """Index into :func:`row_ladder` of the narrowest rung that holds
+    ``n_local`` rows (a traced scalar in the program, a number in the
+    tests). The last rung is every row, so any count is held."""
+    return (n_local > np.asarray(row_ladder(rows)[:-1])).sum()
+
+
+def dispatch_rows(rows, n_local):
+    """The rows :func:`moe_dispatch` hands its grouped matmuls when
+    ``n_local`` of its ``rows`` assignments land on held experts."""
+    return jnp.asarray(row_ladder(rows), jnp.int32)[row_rung(rows, n_local)]
+
+
 def moe_dispatch(h, idx, w, w_gate_up, w_down, first=0, held=None):
     """Dropless expert FFN: ``h`` ``[T, C]``, assignments ``idx``/``w``
     ``[T, k]``, experts stacked ``[E, C, 2 I]`` / ``[E, I, C]``. The
@@ -386,9 +419,19 @@ def moe_dispatch(h, idx, w, w_gate_up, w_down, first=0, held=None):
 
     With ``held`` the stacks are a SHARE of the experts ``idx`` numbers:
     experts ``[first, first + held)``. An assignment to an absent
-    expert is sorted behind the held groups, belongs to no group of
-    either grouped matmul, and adds nothing: the result is the held
-    experts' part of the sum, under weights made over all ``k``."""
+    expert is sorted behind the held groups and belongs to no group, so
+    the ``n_local`` rows that do lie in a group are sorted rows ``[0,
+    n_local)``. Of :func:`row_ladder` the program picks, on the device
+    from ``n_local``, the narrowest rung ``R`` that holds them, and one
+    ``jax.lax.switch`` runs that rung's branch: gather sorted rows
+    ``[0, R)``, both grouped matmuls and SwiGLU over ``R`` rows, and
+    each assignment's row back to its token. The last rung is all ``T
+    k`` rows, so nothing is ever dropped and the result does not depend
+    on the rung: it is the held experts' part of the sum, under weights
+    made over all ``k``. (The chip's grouped matmul multiplies a whole
+    tile of ``min(rows, 512)`` rows for every expert that got a row:
+    handed 512 rows of which 64 lie in groups it is compute-bound on
+    rows of no group.)"""
     t, k = idx.shape
     n_exp, _, two_i = w_gate_up.shape
     flat = idx.reshape(-1)
@@ -398,15 +441,33 @@ def moe_dispatch(h, idx, w, w_gate_up, w_down, first=0, held=None):
         flat = jnp.where(here, local, held)
     order = jnp.argsort(flat, stable=True)
     sizes = jnp.bincount(flat, length=n_exp).astype(jnp.int32)
-    xs = h[order // k]
-    gu = jax.lax.ragged_dot(xs, w_gate_up, sizes)
-    act = jax.nn.silu(gu[:, :two_i // 2]) * gu[:, two_i // 2:]
-    ys = jax.lax.ragged_dot(act, w_down, sizes)
-    back = ys[jnp.argsort(order)].reshape(t, k, -1)
-    if held is not None:
-        # rows of no group hold whatever the grouped matmul left there
-        back = jnp.where(here.reshape(t, k, 1), back, 0)
-    return jnp.sum(back.astype(_F32) * w[..., None], axis=1).astype(h.dtype)
+
+    def experts(rows, h, order, sizes, w_gate_up, w_down):
+        """Sorted rows ``[0, rows)`` through the experts ``sizes``
+        groups them under, each assignment's row back at its place
+        ``[T k, C]`` (an assignment sorted past ``rows`` reads the last
+        row: it lies in no group and is zeroed below)."""
+        # all T k rows: the operations the dispatch always traced
+        whole = rows == t * k
+        xs = h[(order if whole else order[:rows]) // k]
+        gu = jax.lax.ragged_dot(xs, w_gate_up, sizes)
+        act = jax.nn.silu(gu[:, :two_i // 2]) * gu[:, two_i // 2:]
+        ys = jax.lax.ragged_dot(act, w_down, sizes)
+        inv = jnp.argsort(order)
+        return ys[inv if whole else jnp.minimum(inv, rows - 1)]
+
+    if held is None:
+        back = experts(t * k, h, order, sizes, w_gate_up, w_down)
+    else:
+        back = jax.lax.switch(
+            row_rung(t * k, jnp.sum(here)),
+            [functools.partial(experts, rows) for rows in row_ladder(t * k)],
+            h, order, sizes, w_gate_up, w_down)
+        # an absent assignment's row is a row of no group, or another
+        # assignment's: it adds nothing
+        back = jnp.where(here[:, None], back, 0)
+    return jnp.sum(back.reshape(t, k, -1).astype(_F32) * w[..., None],
+                   axis=1).astype(h.dtype)
 
 
 def experts_touched(idx, n_exp):

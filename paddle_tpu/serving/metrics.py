@@ -209,10 +209,21 @@ class ServingMetrics:
                  "of its experts: (row, chosen expert) assignments that "
                  "landed on an expert held here, summed over the expert "
                  "layers (counted by the decode program)")
+        self.dispatch_rows = Histogram(
+            "dispatch_rows", unit="rows", buckets=_reg.TOKEN_BUCKETS,
+            prom_name=f"{ns}_dispatch_rows",
+            help="per decode step of an expert model that holds a share "
+                 "of its experts: sorted rows the grouped matmuls were "
+                 "handed, summed over the expert layers (the rung of "
+                 "models.xing4.row_ladder that holds a layer's local "
+                 "assignments; counted by the decode program); mean / "
+                 "(expert layers x rows x top-k) is the share of rows "
+                 "run")
         # what a net's decode program may count (``pop_step_counters``),
         # by the name it returns it under
         self.step_counters = {"experts_touched": self.experts_touched,
-                              "local_assignments": self.local_assignments}
+                              "local_assignments": self.local_assignments,
+                              "dispatch_rows": self.dispatch_rows}
         # speculative decoding (serving.speculative): one round = one
         # draft proposal pass + one target verify launch
         self.spec_rounds = Counter(
@@ -248,7 +259,7 @@ class ServingMetrics:
             self.host_gap, self.read_wait, self.steps_overlapped,
             self.prefill, self.submit_wait,
             self.resident_tokens, self.span_tokens, self.experts_touched,
-            self.local_assignments,
+            self.local_assignments, self.dispatch_rows,
             self.spec_rounds, self.spec_proposed, self.spec_accepted,
             self.spec_accept_length,
         ])
@@ -318,6 +329,7 @@ class ServingMetrics:
             "span_tokens": self.span_tokens.snapshot(),
             "experts_touched": self.experts_touched.snapshot(),
             "local_assignments": self.local_assignments.snapshot(),
+            "dispatch_rows": self.dispatch_rows.snapshot(),
         }
 
     def observe_step_counters(self, counted):

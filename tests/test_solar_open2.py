@@ -389,6 +389,93 @@ def test_a_dispatch_told_no_share_traces_the_program_it_always_did():
     assert len(told.jaxpr.eqns) > len(plain.jaxpr.eqns)
 
 
+def _share_as_it_stood(h, idx, w, w_gate_up, w_down, first, held):
+    """``moe_dispatch`` told a share before it bounded its rows: every
+    one of the ``T k`` sorted rows through both grouped matmuls."""
+    t, k = idx.shape
+    n_exp, _, two_i = w_gate_up.shape
+    local = idx.reshape(-1) - first
+    here = (local >= 0) & (local < held)
+    flat = jnp.where(here, local, held)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=n_exp).astype(jnp.int32)
+    xs = h[order // k]
+    gu = jax.lax.ragged_dot(xs, w_gate_up, sizes)
+    act = jax.nn.silu(gu[:, :two_i // 2]) * gu[:, two_i // 2:]
+    ys = jax.lax.ragged_dot(act, w_down, sizes)
+    back = ys[jnp.argsort(order)].reshape(t, k, -1)
+    back = jnp.where(here.reshape(t, k, 1), back, 0)
+    return jnp.sum(back.astype(jnp.float32) * w[..., None],
+                   axis=1).astype(h.dtype)
+
+
+@pytest.mark.parametrize("rows, ladder", [
+    (512, (128, 256, 512)), (16384, (4096, 8192, 16384)),
+    (8192, (2048, 4096, 8192)), (256, (128, 256)), (160, (128, 160)),
+    (130, (128, 130)), (128, (128,)), (27, (27,)), (1, (1,))])
+def test_row_ladder_is_a_quarter_a_half_and_all_above_a_floor(rows, ladder):
+    assert xing4.row_ladder(rows) == ladder
+    for n_local in {0, 1, rows, *ladder, *(r + 1 for r in ladder[:-1])}:
+        held = int(xing4.dispatch_rows(rows, n_local))
+        assert held == min(r for r in ladder if r >= n_local)
+
+
+# 5 of 40 experts held, top-8: the benchmark cell's routing at toy widths
+_SHARE = dict(first=10, held=5, n_routed=40, k=8, c=16, i=8)
+_SHARE_SHAPES = {"decode": 64, "prefill": 2048}
+
+
+@pytest.fixture(scope="module")
+def share_programs():
+    """Both dispatches jitted once a shape, with the weights they share."""
+    g = _SHARE
+    r = np.random.default_rng(5)
+    gu = jnp.asarray(r.normal(size=(g["held"], g["c"], 2 * g["i"])) * 0.3,
+                     jnp.float32)
+    dn = jnp.asarray(r.normal(size=(g["held"], g["i"], g["c"])) * 0.3,
+                     jnp.float32)
+    share = dict(first=g["first"], held=g["held"])
+    stood = jax.jit(lambda h, idx, w: _share_as_it_stood(
+        h, idx, w, gu, dn, **share))
+    laddered = jax.jit(lambda h, idx, w: xing4.moe_dispatch(
+        h, idx, w, gu, dn, **share))
+    return stood, laddered
+
+
+@pytest.mark.parametrize("local", [
+    "none", "under_first", "at_first", "over_first", "at_second",
+    "over_second", "one_absent", "every"])
+@pytest.mark.parametrize("shape", _SHARE_SHAPES)
+def test_a_share_bounded_to_a_rung_is_the_dispatch_as_it_stood(
+        share_programs, shape, local):
+    """Whatever rung the local count picks (none local, under, exactly
+    at and one above each rung, every assignment local: the full-length
+    rung), the result is the one the dispatch gave over all ``T k``
+    rows."""
+    g = _SHARE
+    t, k = _SHARE_SHAPES[shape], g["k"]
+    a, b, full = xing4.row_ladder(t * k)
+    n_local, rung = {
+        "none": (0, a), "under_first": (a // 2, a), "at_first": (a, a),
+        "over_first": (a + 1, b), "at_second": (b, b),
+        "over_second": (b + 1, full), "one_absent": (full - 1, full),
+        "every": (full, full)}[local]
+    assert int(xing4.dispatch_rows(t * k, n_local)) == rung
+    r = np.random.default_rng(n_local)
+    absent = np.setdiff1d(np.arange(g["n_routed"]),
+                          np.arange(g["first"], g["first"] + g["held"]))
+    flat = r.choice(absent, size=t * k)
+    at = r.permutation(t * k)[:n_local]
+    flat[at] = r.integers(g["first"], g["first"] + g["held"], size=n_local)
+    idx = jnp.asarray(flat.reshape(t, k), jnp.int32)
+    h = jnp.asarray(r.normal(size=(t, g["c"])), jnp.float32)
+    w = jnp.asarray(r.uniform(0.1, 1.0, size=(t, k)), jnp.float32)
+    stood, laddered = share_programs
+    want, got = np.asarray(stood(h, idx, w)), np.asarray(laddered(h, idx, w))
+    assert np.abs(want).max() > 0 or n_local == 0
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+
+
 def test_the_shares_add_up_to_the_uncut_layer(toy):
     """The routed parts the 4 shares of 4 experts compute, plus the
     shared expert ONCE, equal the uncut reference layer; in the program
@@ -489,6 +576,8 @@ def test_step_counters_equal_a_recount(toy_share):
     assert int(got["local_assignments"]) == sum(int(m.sum()) for m in here)
     assert int(got["experts_touched"]) == sum(
         len(np.unique(c[m])) for c, m in zip(chosen, here))
+    # 5 tokens x top-4 = 20 sorted rows a layer: under the floor, one rung
+    assert int(got["dispatch_rows"]) == 20 * len(chosen)
     assert net.pop_step_counters() == {}
     # and the engine keeps both, a sample a decode step
     eng = PagedServingEngine(net, max_batch_size=2, max_seq_len=32,
@@ -499,9 +588,75 @@ def test_step_counters_equal_a_recount(toy_share):
     eng.close()
     steps = rep["local_assignments"]["count"]
     assert steps == rep["experts_touched"]["count"] >= 3
+    # 2 rows x top-4 = 8 sorted rows a layer: the one rung, 4 layers
+    assert rep["dispatch_rows"]["count"] == steps
+    assert rep["dispatch_rows"]["mean"] == 8 * 4
     # 2 rows x top-4 x 4 layers a step, a quarter of the experts held
     assert 0 <= rep["local_assignments"]["max"] <= 2 * 4 * 4
     assert rep["experts_touched"]["max"] <= 4 * cfg.held
+
+
+@pytest.fixture
+def low_floor(monkeypatch):
+    """The row ladder without its floor, so that a toy dispatch of 8 or
+    64 sorted rows has three rungs as the benchmark cell's 512 have."""
+    monkeypatch.setattr(xing4, "_ROW_FLOOR", 1)
+
+
+def test_dispatch_rows_equal_the_rungs_the_routing_implies(toy_share,
+                                                           low_floor):
+    """``dispatch_rows`` of a step, summed over the layers, against the
+    ladder applied to a numpy recount of each layer's local assignments
+    (44 tokens x top-4 = 176 sorted rows a layer: rungs 44, 88, 176)."""
+    net = toy_share[0]
+    cfg = net.config
+    h = paddle.to_tensor(np.random.default_rng(9).normal(
+        size=(1, 44, cfg.hidden_size)).astype(np.float32))
+    want = []
+    for layer in net.model.layers:
+        layer.mlp(h)
+        local = np.asarray(layer.mlp.route(h.reshape([44, -1]))[0]) \
+            - cfg.experts_first
+        n_local = int(((local >= 0) & (local < cfg.held)).sum())
+        want.append(min(r for r in (44, 88, 176) if r >= n_local))
+    got = net.pop_step_counters()
+    assert xing4.row_ladder(176) == (44, 88, 176)
+    assert int(got["dispatch_rows"]) == sum(want) < 4 * 176
+
+
+@pytest.mark.parametrize("share", ["a_share", "all_experts"])
+def test_an_engine_over_a_laddered_dispatch_serves_the_same_tokens(
+        toy, toy_share, share, monkeypatch):
+    """The paged engine with the ladder engaged in its prefill (16 x 4
+    sorted rows) and decode (2 x 4) programs serves token for token what
+    it serves over the one full-length rung, and counts the rows it ran:
+    every row where every expert is held, fewer where a quarter are."""
+    net = (toy_share if share == "a_share" else toy)[0]
+    prompts = [_ids(9, 7).tolist(), _ids(9, 8).tolist(), _ids(9, 9).tolist()]
+
+    def serve():
+        eng = PagedServingEngine(net, max_batch_size=2, max_seq_len=48,
+                                 page_size=8, min_bucket=16,
+                                 cache_dtype="float32")
+        tokens = [h.tokens for h in eng.generate(prompts, max_new_tokens=6)]
+        rep = eng.metrics.report()
+        eng.close()
+        return tokens, rep
+
+    want, whole = serve()
+    assert whole["dispatch_rows"]["mean"] == 8 * 4
+    monkeypatch.setattr(xing4, "_ROW_FLOOR", 1)
+    assert xing4.row_ladder(8) == (2, 4, 8)
+    got, rep = serve()
+    assert got == want
+    rows = rep["dispatch_rows"]
+    assert rows["count"] == rep["local_assignments"]["count"]
+    if share == "all_experts":
+        assert rows["mean"] == 8 * 4
+    else:
+        # rungs 2, 4, 8 in each of 4 layers
+        assert 2 * 4 <= rows["min"] <= rows["mean"] < 8 * 4
+        assert rows["max"] >= rep["local_assignments"]["max"]
 
 
 @pytest.mark.parametrize("option, why", [

@@ -15,6 +15,8 @@ module is imported: one process at a time may load the TPU's library,
 and under xdist every worker imports every test file. All of these
 tests stay in this one file for the same reason.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -200,10 +202,11 @@ def _held_experts(h, idx, w, gate_up, down):
 
 # Solar-Open2's serving cell (BENCHMARK.json): 64 decode rows and one
 # 2048-token prefill of 64 KDA heads of 128 with a float32 state a row,
-# 40 of 320 experts of width 1280 held, top-8. No Mosaic kernel among
-# them: what is held here is that the chip's compiler takes the scan's
-# triangular solve, the grouped matmul over rows of no group, and how
-# much each needs beside its arguments.
+# 40 of 320 experts of width 1280 held, top-8. No Mosaic kernel of the
+# repo's among them: what is held here is that the chip's compiler
+# takes the scan's triangular solve and the held share's row ladder
+# (three branches of two grouped matmuls each), and how much each needs
+# beside its arguments.
 _ROW = lambda n, *d: ((n, 64) + d, F32)
 SOLAR_CASES = {
     "kda step 64 rows": (
@@ -229,6 +232,26 @@ def test_row_state_and_share_programs_compile_for_v5e(chip_compile, name):
     fn, shapes, room = SOLAR_CASES[name]
     compiled = chip_compile(fn, *(chip_compile.sds(*s) for s in shapes))
     assert compiled.memory_analysis().temp_size_in_bytes < room
+    if fn is not _held_experts:
+        return
+    # the grouped matmuls (the chip's compiler names them
+    # ``ragged-dot-none``; the benchmark's readers count them by that
+    # name) run at every rung of the row ladder, the first a quarter of
+    # the T x 8 sorted rows ...
+    from paddle_tpu.models import xing4
+
+    text = compiled.as_text()
+    for rows in xing4.row_ladder(shapes[0][0][0] * 8):
+        for width in (2560, 4096):
+            assert re.search(
+                rf"%ragged-dot-none\S* = bf16\[{rows},{width}\]", text), (
+                    rows, width)
+    # ... and the conditional hands each branch the expert stacks as
+    # they lie: nothing of their shape but the program's parameters and
+    # the branches' reads of their operand tuple, no copy, no fusion
+    made = re.findall(
+        r"= bf16\[40,(?:4096,2560|1280,4096)\]\S* ([\w-]+)\(", text)
+    assert made and set(made) <= {"parameter", "get-tuple-element"}, made
 
 
 def test_rms_norm_row_block_fits_vmem_budget():
