@@ -150,13 +150,21 @@ def row_array_mask(cfg):
             for is_row in (False,) * len(tokens) + (True,) * len(rows)]
 
 
+def token_arrays_are_kv_pairs(cfg):
+    """True where every layer that keeps anything a TOKEN keeps a K and
+    a V of ``(kvH, D)`` (a layer may keep nothing a token: a recurrent
+    one)."""
+    return all(
+        len(layer) == 2 and len(layer[0]) == 2 and layer[0] == layer[1]
+        for layer in cache_layout(cfg) if layer)
+
+
 def keeps_kv_pairs(cfg):
     """True where every layer's cache is a K and a V of ``(kvH, D)``
     and nothing a row: the layout int8 storage, the prefix cache,
     tiering and speculation are written for."""
-    return not keeps_row_state(cfg) and all(
-        len(layer) == 2 and len(layer[0]) == 2
-        and layer[0] == layer[1] for layer in cache_layout(cfg))
+    return not keeps_row_state(cfg) and all(cache_layout(cfg)) \
+        and token_arrays_are_kv_pairs(cfg)
 
 
 def cache_token_bytes(cfg, cache_dtype):

@@ -87,8 +87,34 @@ def _linear_attn_default():
             "num_kv_heads": None}
 
 
+class KDADims:
+    """What a config with ``linear_attn_config`` derives from it: the
+    KDA mixer's sizes and what a KDA layer keeps a ROW. Every family
+    whose layers are :class:`SolarOpen2KDA` states them through this."""
+
+    @property
+    def kda_heads(self):
+        return int(self.linear_attn_config["num_heads"])
+
+    @property
+    def kda_head_dim(self):
+        return int(self.linear_attn_config["head_dim"])
+
+    @property
+    def kda_conv(self):
+        return int(self.linear_attn_config["short_conv_kernel_size"])
+
+    def kda_row_arrays(self):
+        """``(shape, dtype)`` of the arrays a KDA layer keeps a row
+        (dtype None: the cache's): its state, float32 whatever the
+        cache is stored in, and its convolution tail."""
+        h, d = self.kda_heads, self.kda_head_dim
+        return (((h, d, d), "float32"),
+                ((self.kda_conv - 1, 3 * h * d), None))
+
+
 @dataclass
-class SolarOpen2Config:
+class SolarOpen2Config(KDADims):
     vocab_size: int = 196608
     hidden_size: int = 4096
     intermediate_size: int = 10240       # unused: no layer is dense
@@ -137,18 +163,6 @@ class SolarOpen2Config:
         return self.num_key_value_heads
 
     @property
-    def kda_heads(self):
-        return int(self.linear_attn_config["num_heads"])
-
-    @property
-    def kda_head_dim(self):
-        return int(self.linear_attn_config["head_dim"])
-
-    @property
-    def kda_conv(self):
-        return int(self.linear_attn_config["short_conv_kernel_size"])
-
-    @property
     def held(self):
         """Experts this program holds of a layer's ``n_routed_experts``."""
         return self.n_routed_experts if self.experts_held is None \
@@ -167,13 +181,9 @@ class SolarOpen2Config:
                 for i in range(self.num_hidden_layers)]
 
     def row_layout(self):
-        """What a ROW keeps a layer, ``(shape, dtype)`` an array (dtype
-        None: the cache's): a KDA layer its state, float32 whatever the
-        cache is stored in, and its convolution tail; a GQA layer
-        nothing."""
-        h, d = self.kda_heads, self.kda_head_dim
-        kept = (((h, d, d), "float32"),
-                ((self.kda_conv - 1, 3 * h * d), None))
+        """What a ROW keeps a layer: a KDA layer its state and its
+        convolution tail (``kda_row_arrays``); a GQA layer nothing."""
+        kept = self.kda_row_arrays()
         return [() if self.is_gqa(i) else kept
                 for i in range(self.num_hidden_layers)]
 
@@ -328,7 +338,11 @@ class _UniformThrough(I.Initializer):
 
 
 class SolarOpen2KDA(nn.Layer):
-    def __init__(self, cfg: SolarOpen2Config):
+    """The KDA mixer over any config that states ``hidden_size``,
+    ``kda_heads``, ``kda_head_dim``, ``kda_conv``, ``kda_chunk``,
+    ``kda_allow_neg_eigval`` and ``rms_norm_eps``."""
+
+    def __init__(self, cfg):
         super().__init__()
         self.cfg = cfg
         c, h, d = cfg.hidden_size, cfg.kda_heads, cfg.kda_head_dim
@@ -491,9 +505,13 @@ class SolarOpen2Attention(nn.Layer):
 class SolarOpen2MoE(nn.Layer):
     """Sigmoid-routed experts, ``held`` of ``n_routed_experts`` of them
     here, and the shared expert. The router has every expert's column;
-    the stacked expert weights only the held ones'."""
+    the stacked expert weights only the held ones'. Over any config
+    that states the routing keys it reads (``n_routed_experts``,
+    ``held``, ``experts_first``, ``num_experts_per_tok``,
+    ``norm_topk_prob``, ``routed_scaling_factor``, ``n_shared_experts``,
+    ``moe_intermediate_size``)."""
 
-    def __init__(self, cfg: SolarOpen2Config):
+    def __init__(self, cfg):
         super().__init__()
         self.cfg = cfg
         c, i = cfg.hidden_size, cfg.moe_intermediate_size
@@ -579,14 +597,22 @@ def _array(x):
 
 
 class SolarOpen2Model(nn.Layer):
-    def __init__(self, cfg: SolarOpen2Config):
+    """A stack whose layers differ in kind: ``make_layer`` says which
+    layer stands at ``i`` (a family with other kinds overrides it), and
+    every layer takes ``(x, cache, pos, page_table, length)`` and gives
+    ``(x, new_cache)``, its cache a tuple of its token arrays then its
+    row arrays."""
+
+    def __init__(self, cfg):
         super().__init__()
         self.config = cfg
         self.embed_tokens = nn.Embedding(cfg.vocab_size, cfg.hidden_size)
         self.layers = nn.LayerList([
-            SolarOpen2DecoderLayer(cfg, cfg.is_gqa(i))
-            for i in range(cfg.num_hidden_layers)])
+            self.make_layer(cfg, i) for i in range(cfg.num_hidden_layers)])
         self.norm = nn.RMSNorm(cfg.hidden_size, cfg.rms_norm_eps)
+
+    def make_layer(self, cfg, i):
+        return SolarOpen2DecoderLayer(cfg, cfg.is_gqa(i))
 
     def forward(self, input_ids, caches=None, pos=None, page_table=None,
                 length=None, apply_final_norm=True):
@@ -612,12 +638,14 @@ class SolarOpen2Model(nn.Layer):
 
 
 class SolarOpen2ForCausalLM(nn.Layer):
-    def __init__(self, config: SolarOpen2Config):
+    model_class = SolarOpen2Model
+
+    def __init__(self, config):
         super().__init__()
         if config.tie_word_embeddings:
-            raise ValueError("SolarOpen2: the head is not tied")
+            raise ValueError(f"{type(self).__name__}: the head is not tied")
         self.config = config
-        self.model = SolarOpen2Model(config)
+        self.model = self.model_class(config)
         self.lm_head = nn.Linear(config.hidden_size, config.vocab_size,
                                  bias_attr=False)
 
@@ -631,8 +659,9 @@ class SolarOpen2ForCausalLM(nn.Layer):
         ``head_row`` runs the final norm and the head on that one
         position alone; ``length`` freezes the row state past it."""
         if attn_mask is not None:
-            raise ValueError("SolarOpen2: no explicit attention mask "
-                             "(positions mask the cache, length the state)")
+            raise ValueError(
+                f"{type(self).__name__}: no explicit attention mask "
+                f"(positions mask the cache, length the state)")
         out = self.model(input_ids, caches=caches, pos=pos,
                          page_table=page_table, length=length,
                          apply_final_norm=False)
@@ -646,16 +675,20 @@ class SolarOpen2ForCausalLM(nn.Layer):
         return logits if caches is None else (logits, new_caches)
 
     def pop_step_counters(self):
-        """What the step just traced counted, summed over the layers:
-        ``experts_touched``, the HELD experts that got at least one
-        token, ``local_assignments``, the assignments that landed on
-        held experts, and ``dispatch_rows``, the sorted rows the grouped
-        matmuls were handed (the rung of ``xing4.row_ladder`` that
-        holds a layer's local assignments)."""
+        """What the step just traced counted, summed over the expert
+        layers (a dense FFN counts nothing): ``experts_touched``, the
+        HELD experts that got at least one token, ``local_assignments``,
+        the assignments that landed on held experts, and
+        ``dispatch_rows``, the sorted rows the grouped matmuls were
+        handed (the rung of ``xing4.row_ladder`` that holds a layer's
+        local assignments)."""
         total = {}
         for layer in self.model.layers:
-            counts, layer.mlp.last_counts = layer.mlp.last_counts, None
-            for name, value in (counts or {}).items():
+            counts = getattr(layer.mlp, "last_counts", None)
+            if counts is None:
+                continue
+            layer.mlp.last_counts = None
+            for name, value in counts.items():
                 total[name] = total.get(name, 0) + value
         return total
 

@@ -68,8 +68,36 @@ def _yarn_default():
             "original_max_position_embeddings": 4096}
 
 
+class LatentCacheDims:
+    """What a config with MLA's keys (``kv_lora_rank``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim``, ``rope_scaling``) derives from them: the sizes
+    of a cached token and the softmax scale. Every family whose layers
+    run :func:`mla_core` states them through this."""
+
+    @property
+    def latent_dim(self):
+        """Numbers a cached token is: the normed latent and the key
+        dims every head shares (roped where the family ropes them)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_dim(self):
+        """``latent_dim`` as it is stored: zero-padded to whole lanes
+        of 128. A ``[pages, 16, 576]`` array is no whole number of the
+        chip's tiles; its default device layout then puts the PAGE axis
+        minor, and every decode step copies each layer's arena into a
+        row-major one and back (seen in the program compiled for the
+        chip). ``[pages, 16, 640]`` is row-major as it lies."""
+        return 128 * -(-self.latent_dim // 128)
+
+    @property
+    def softmax_scale(self):
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        return qk ** -0.5 * yarn_mscale(self.rope_scaling) ** 2
+
+
 @dataclass
-class Xing4Config:
+class Xing4Config(LatentCacheDims):
     vocab_size: int = 131072
     hidden_size: int = 3584
     intermediate_size: int = 9216          # the leading dense layers
@@ -100,30 +128,9 @@ class Xing4Config:
     rope_scaling: dict | None = field(default_factory=_yarn_default)
     tie_word_embeddings: bool = False
 
-    @property
-    def latent_dim(self):
-        """Numbers a cached token is: the normed latent and the roped
-        key dims every head shares."""
-        return self.kv_lora_rank + self.qk_rope_head_dim
-
-    @property
-    def cache_dim(self):
-        """``latent_dim`` as it is stored: zero-padded to whole lanes
-        of 128. A ``[pages, 16, 576]`` array is no whole number of the
-        chip's tiles; its default device layout then puts the PAGE axis
-        minor, and every decode step copies each layer's arena into a
-        row-major one and back (seen in the program compiled for the
-        chip). ``[pages, 16, 640]`` is row-major as it lies."""
-        return 128 * -(-self.latent_dim // 128)
-
     def cache_layout(self):
         """One array a layer, one ``cache_dim`` vector a token."""
         return [((self.cache_dim,),)] * self.num_hidden_layers
-
-    @property
-    def softmax_scale(self):
-        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
-        return qk ** -0.5 * yarn_mscale(self.rope_scaling) ** 2
 
     @staticmethod
     def tiny(**kw):
@@ -266,7 +273,9 @@ def mla_core(q, ckv, k_rope, w_kvb, cos, sin, *, cfg, cache=None, pos=None,
     """Rope, the latent write, the page gather and the attention: ``q``
     ``[B, S, H, dn + dr]``, ``ckv`` ``[B, S, kv_lora_rank]`` (normed),
     ``k_rope`` ``[B, S, dr]``; ``cos``/``sin`` ``[B or 1, S, dr / 2]``
-    at the tokens' positions. ``cache`` is the layer's one array: a
+    at the tokens' positions, or both None for a family that does not
+    rotate (NoPE: the query's and the key's ``dr`` dims go through as
+    they are). ``cache`` is the layer's one array: a
     block or slab ``[B, S_max, cache_dim]`` (``pos`` scalar or
     ``[B]``) or, with ``page_table`` ``[B, P]``, a page arena
     ``[pages, page_size, cache_dim]``. One token a row runs absorbed,
@@ -274,9 +283,11 @@ def mla_core(q, ckv, k_rope, w_kvb, cos, sin, *, cfg, cache=None, pos=None,
     H, dv], new_cache)``."""
     dn = cfg.qk_nope_head_dim
     s = q.shape[1]
-    q_nope = q[..., :dn]
-    q_rope = _rope(q[..., dn:], cos[:, :, None], sin[:, :, None])
-    latent = jnp.concatenate([ckv, _rope(k_rope, cos, sin)], -1)
+    q_nope, q_rope = q[..., :dn], q[..., dn:]
+    if cos is not None:
+        q_rope = _rope(q_rope, cos[:, :, None], sin[:, :, None])
+        k_rope = _rope(k_rope, cos, sin)
+    latent = jnp.concatenate([ckv, k_rope], -1)
     scale = cfg.softmax_scale
     if absorbed is None:
         absorbed = s == 1
@@ -310,28 +321,38 @@ def mla_core(q, ckv, k_rope, w_kvb, cos, sin, *, cfg, cache=None, pos=None,
 
 
 class Xing4Attention(nn.Layer):
-    def __init__(self, cfg: Xing4Config):
+    """MLA over any config that states its keys (``LatentCacheDims``).
+    ``q_lora_rank`` None: the query comes from one projection,
+    uncompressed and unnormed."""
+
+    def __init__(self, cfg):
         super().__init__()
         self.cfg = cfg
         c, h = cfg.hidden_size, cfg.num_attention_heads
         dq = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
         lin = lambda i, o: nn.Linear(i, o, bias_attr=False)
-        self.q_a_proj = lin(c, cfg.q_lora_rank)
-        self.q_a_layernorm = nn.RMSNorm(cfg.q_lora_rank, cfg.rms_norm_eps)
-        self.q_b_proj = lin(cfg.q_lora_rank, h * dq)
+        if cfg.q_lora_rank:
+            self.q_a_proj = lin(c, cfg.q_lora_rank)
+            self.q_a_layernorm = nn.RMSNorm(cfg.q_lora_rank,
+                                            cfg.rms_norm_eps)
+            self.q_b_proj = lin(cfg.q_lora_rank, h * dq)
+        else:
+            self.q_proj = lin(c, h * dq)
         self.kv_a_proj = lin(c, cfg.latent_dim)
         self.kv_a_layernorm = nn.RMSNorm(cfg.kv_lora_rank, cfg.rms_norm_eps)
         self.kv_b_proj = lin(cfg.kv_lora_rank,
                              h * (cfg.qk_nope_head_dim + cfg.v_head_dim))
         self.o_proj = lin(h * cfg.v_head_dim, c)
 
-    def forward(self, x, cos, sin, cache=None, pos=None, page_table=None):
-        """``x`` ``[B, S, C]``; returns ``(out, new_cache)``, the cache
-        None without one."""
+    def forward(self, x, cos=None, sin=None, cache=None, pos=None,
+                page_table=None):
+        """``x`` ``[B, S, C]``; ``cos``/``sin`` None: no rotation.
+        Returns ``(out, new_cache)``, the cache None without one."""
         cfg = self.cfg
         b, s = int(x.shape[0]), int(x.shape[1])
-        q = self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x))).reshape(
-            [b, s, cfg.num_attention_heads, -1])
+        q = (self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
+             if cfg.q_lora_rank else self.q_proj(x))
+        q = q.reshape([b, s, cfg.num_attention_heads, -1])
         kv = self.kv_a_proj(x)
         ckv = self.kv_a_layernorm(kv[..., :cfg.kv_lora_rank])
         k_rope = kv[..., cfg.kv_lora_rank:]

@@ -53,6 +53,7 @@ from ..models.generation import (
     keeps_row_state,
     prefill,
     row_layout,
+    token_arrays_are_kv_pairs,
     unflatten_caches,
 )
 from ..observability.tracing import get_tracer
@@ -287,20 +288,28 @@ class ServingEngine:
             # at all
             asked = [what for what, on in self._kv_pair_features(
                 speculative).items() if on]
+            # a net may state both (a latent page in one layer, a state
+            # a row in another): it is told both
+            reasons = []
             if asked and keeps_row_state(cfg):
-                raise ValueError(
+                reasons.append(
                     f"{type(net).__name__} keeps a state a row beside "
                     f"its pages; " + "; ".join(
                         f"{what} is not supported over it: "
                         f"{_ROW_STATE_REFUSALS[what]}" for what in asked)
                 )
-            if asked:
-                raise ValueError(
+            # ... or neither and still no K/V pair in every layer (a
+            # layer that keeps nothing at all): the same sentence
+            if asked and (not token_arrays_are_kv_pairs(cfg)
+                          or not reasons):
+                reasons.append(
                     f"{type(net).__name__} states a cache that is not "
                     f"K and V per head; {', '.join(asked)} "
                     f"{'is' if len(asked) == 1 else 'are'} written for "
                     f"K/V pairs and not supported over it"
                 )
+            if reasons:
+                raise ValueError(". ".join(reasons))
         self.scheduler = scheduler or Scheduler(
             max_queue_size=max_queue_size, clock=clock
         )

@@ -57,7 +57,7 @@ def test_diff_verdict(tmp_path, capsys, other, rc, says):
 
 RECORD = json.load(open(os.path.join(os.path.dirname(__file__),
                                      "lowered_text.json")))
-LAYERS = {"llama_gqa": 2, "llama_gqa_w8": 2, "xing4": 3}
+LAYERS = {"llama_gqa": 2, "llama_gqa_w8": 2, "xing4": 3, "kimi_linear": 1}
 
 
 @pytest.fixture(scope="module")

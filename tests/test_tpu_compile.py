@@ -254,6 +254,97 @@ def test_row_state_and_share_programs_compile_for_v5e(chip_compile, name):
     assert made and set(made) <= {"parameter", "get-tuple-element"}, made
 
 
+def _held_half(h, idx, w, gate_up, down):
+    from paddle_tpu.models import xing4
+
+    return xing4.moe_dispatch(h, idx, w, gate_up, down, first=0, held=128)
+
+
+def _nope_mla_step(q, ckv, k_pe, w_kvb, arena, pos, table):
+    from paddle_tpu.models import KimiLinearConfig, xing4
+
+    return xing4.mla_core(q, ckv, k_pe, w_kvb, None, None,
+                          cfg=KimiLinearConfig(), cache=arena, pos=pos,
+                          page_table=table)
+
+
+def _nope_mla_prefill(q, ckv, k_pe, w_kvb, block):
+    from paddle_tpu.models import KimiLinearConfig, xing4
+
+    return xing4.mla_core(q, ckv, k_pe, w_kvb, None, None,
+                          cfg=KimiLinearConfig(), cache=block,
+                          pos=jnp.int32(0))
+
+
+def _kda_scan_16k(q, k, v, g, beta, state):
+    from paddle_tpu.models import solar_open2
+
+    return solar_open2.kda_scan(q, k, v, g, beta, state, 64,
+                                length=jnp.int32(12345))
+
+
+# Kimi-Linear's serving cell (BENCHMARK.json): 32 decode rows whose
+# page table is 1152 wide (max_seq_len 18432) and one 16384-token
+# prefill; 32 KDA heads of 128, NoPE MLA of 32 heads over a 640-wide
+# latent page, 128 of 256 experts of width 1024 held, top-8: the local
+# assignments of a step (about 128 of 256 sorted rows) lie on the row
+# ladder's first boundary.
+_KROW = lambda n, *d: ((n, 32) + d, F32)
+_KSTACKS = [((128, 2304, 2048), BF), ((128, 1024, 2304), BF)]
+KIMI_CASES = {
+    "kda step 32 rows": (
+        _kda_step, [_KROW(32, 128)] * 4 + [_KROW(32), _KROW(32, 128, 128)],
+        1 << 30),
+    "kda chunked scan 16384 tokens": (
+        _kda_scan_16k, [((1, 16384, 32, 128), F32)] * 4
+        + [((1, 16384, 32), F32), _KROW(1, 128, 128)], 3 << 30),
+    "held half decode 32 rows": (
+        _held_half, [((32, 2304), BF), ((32, 8), jnp.int32),
+                     ((32, 8), F32)] + _KSTACKS, 1 << 30),
+    "held half prefill 16384 rows": (
+        _held_half, [((16384, 2304), BF), ((16384, 8), jnp.int32),
+                     ((16384, 8), F32)] + _KSTACKS, 3 << 30),
+    "nope mla absorbed step over a 1152-page table": (
+        _nope_mla_step, [((32, 1, 32, 192), BF), ((32, 1, 512), BF),
+                         ((32, 1, 64), BF), ((512, 32 * 256), BF),
+                         ((32 * 1152 + 1, 16, 640), BF), ((32,), jnp.int32),
+                         ((32, 1152), jnp.int32)], 2 << 30),
+    "nope mla materialised prefill 16384 tokens": (
+        _nope_mla_prefill, [((1, 16384, 32, 192), BF), ((1, 16384, 512), BF),
+                            ((1, 16384, 64), BF), ((512, 32 * 256), BF),
+                            ((1, 16384, 640), BF)], 3 << 30),
+}
+
+
+@pytest.mark.parametrize("name", KIMI_CASES)
+def test_latent_page_beside_row_state_programs_compile_for_v5e(
+        chip_compile, topo, monkeypatch, name):
+    fn, shapes, room = KIMI_CASES[name]
+    # the prefill's flash selection asks jax.devices() what it runs on
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    compiled = chip_compile(fn, *(chip_compile.sds(*s) for s in shapes))
+    monkeypatch.undo()
+    assert compiled.memory_analysis().temp_size_in_bytes < room
+    text = compiled.as_text()
+    if fn is _nope_mla_prefill:
+        assert "tpu_custom_call" in text     # flash, heads padded to 256
+    if fn is not _held_half:
+        return
+    from paddle_tpu.models import xing4
+
+    ladder = xing4.row_ladder(shapes[0][0][0] * 8)
+    assert ladder == ((128, 256) if shapes[0][0][0] == 32
+                      else (32768, 65536, 131072))
+    for rows in ladder:
+        for width in (2048, 2304):
+            assert re.search(
+                rf"%ragged-dot-none\S* = bf16\[{rows},{width}\]", text), (
+                    rows, width)
+    made = re.findall(
+        r"= bf16\[128,(?:2304,2048|1024,2304)\]\S* ([\w-]+)\(", text)
+    assert made and set(made) <= {"parameter", "get-tuple-element"}, made
+
+
 def test_rms_norm_row_block_fits_vmem_budget():
     """The row block shrinks with hidden x itemsize, fwd and bwd apart
     (the seed's fixed 256 rows ran the 4096-wide backward out of VMEM),

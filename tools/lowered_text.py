@@ -9,7 +9,9 @@ checkout: ``generation.decode_step`` through a page
 table, ``generation.prefill`` (a whole block and a chunk at an
 offset) and the slab decode step at per-row and at scalar positions,
 for a GQA Llama (bf16 pages, int8 pages, int8 weights) and a Xing4;
-one ``CompiledTrainStep`` of a Llama with a tied (MHA) and an untied
+the paged decode step and the block prefill of a Kimi-Linear that holds
+half its experts (a latent page in one layer, a state a row in the
+others); one ``CompiledTrainStep`` of a Llama with a tied (MHA) and an untied
 (GQA) head. A change that claims to leave the programs alone gives
 the same files.
 
@@ -105,7 +107,8 @@ def dump_programs(root, out):
     import jax.numpy as jnp
 
     import paddle_tpu as paddle
-    from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
+    from paddle_tpu.models import (KimiLinearConfig, KimiLinearForCausalLM,
+                                   LlamaConfig, LlamaForCausalLM,
                                    Xing4Config, Xing4ForCausalLM)
     from paddle_tpu.models import generation as gen
     from paddle_tpu.quantization import quantize_for_serving
@@ -120,7 +123,7 @@ def dump_programs(root, out):
 
     B, P, PS, S_MAX, CHUNK = 4, 4, 8, 32, 16
 
-    def serve_programs(tag, net, dtypes):
+    def serve_programs(tag, net, dtypes, only=None):
         cfg = net.config
         net.eval()
         params = {k: p.value for k, p in net.named_parameters()}
@@ -137,7 +140,7 @@ def dump_programs(root, out):
         tbl = jnp.asarray(1 + np.arange(B * P).reshape(B, P), jnp.int32)
         ids = jnp.zeros((1, CHUNK), jnp.int32)
         for dt in dtypes:
-            arena = gen.alloc_kv_caches(cfg, B * P + 1, PS, dt)
+            arena = gen.alloc_kv_caches(cfg, B * P + 1, PS, dt, rows=B)
             slab = gen.alloc_kv_caches(cfg, B, S_MAX, dt)
             whole = gen.alloc_kv_caches(cfg, 1, CHUNK, dt)
             block = gen.alloc_kv_caches(cfg, 1, S_MAX, dt)
@@ -154,6 +157,8 @@ def dump_programs(root, out):
                     net, i, c, length=n, pos=p),
                  (ids, block, jnp.int32(9), jnp.int32(8))),
             ):
+                if only is not None and name not in only:
+                    continue
                 dump(f"{tag}_{name}_{dt}", with_net(body), params, buffers,
                      *a)
         net.load_functional_state(params, buffers)
@@ -180,6 +185,11 @@ def dump_programs(root, out):
         x = paddle.to_tensor(np.zeros((2, 16), "int32"))
         step([x], [x])
         dump(f"train_{tag}", step._step_fn, *step._step_args_sds)
+
+    # after every program PR 31 recorded, so that none of them moves
+    serve_programs("kimi_linear", KimiLinearForCausalLM(
+        KimiLinearConfig.tiny(experts_first=8, experts_held=8)),
+        ("bfloat16",), only=("decode_paged", "prefill_block"))
 
 
 if __name__ == "__main__":
