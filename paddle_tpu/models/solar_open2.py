@@ -25,12 +25,28 @@ untied head.
 
   ``S`` ``[d_k, d_v]`` a head is float32 and zero where a sequence
   starts. Three forms compute it: ``kda_step`` (one token a row: the
-  decode step), ``kda_chunked`` (a scan over chunks of
-  ``kda_chunk`` tokens, the within-chunk triangular system solved once
-  a chunk: prefill and the cacheless forward) and the recurrence as
-  written (the reference's); they agree to rounding. The chunked form
-  never divides by a cumulative decay: ``exp(G_i - G_j)`` is formed
-  for ``i >= j`` alone, where it is at most 1.
+  decode step), ``kda_chunked`` (chunks of ``kda_chunk`` tokens:
+  prefill and the cacheless forward) and the recurrence as written
+  (the reference's); they agree to rounding.
+- **The chunked form**, with ``G`` the decay summed from a chunk's
+  start: only the state a chunk starts from depends on the chunk
+  before, so everything else is made for ALL chunks at once, outside
+  the loop: ``A_ij = sum_c k_i k_j e^{G_i - G_j}`` and ``P`` (the same
+  with ``q_i``) for ``i >= j``, the inverse ``T`` of the unit lower
+  triangular ``I + Diag(beta) A_strict`` by forward substitution, ``W =
+  T (beta k e^G)`` and ``U0 = T (beta v)``. The ``lax.scan`` over
+  chunks carries the state alone: ``u = U0 - W S_0``, ``o = (q e^G) S_0
+  + P u``, ``S_C = Diag(e^{G_C}) S_0 + (k e^{G_C - G})^T u``. No ``[C,
+  C, d]`` tensor of pairwise decays is formed: a chunk is cut into
+  sub-blocks of 16 tokens, and rows ``i`` of sub-block ``I`` meet the
+  columns ``j`` of the sub-blocks before it in one float32 matmul,
+  ``A_ij = (k_i e^{G_i - r_I}) . (k_j e^{r_I - G_j})`` with ``r_I``
+  the row of ``G`` at ``I``'s first token. ``g <= 0``, so ``G`` only
+  falls along a chunk and ``G_i <= r_I <= G_j``: both exponents are
+  ``<= 0``, no factor exceeds 1 and none is the reciprocal of a decay,
+  however strong (the product of the two is ``e^{G_i - G_j}`` itself).
+  Only the four diagonal 16 x 16 blocks form ``e^{G_i - G_j}`` pair by
+  pair, for their ``i >= j``.
 - **What a row keeps** (``SolarOpen2Config.row_layout``): a KDA layer
   the state ``[H, d_k, d_v]`` float32 and the convolution's TAIL, the
   last ``K - 1`` rows of ``[q~ | k~ | v~]``; a GQA layer nothing. What
@@ -259,6 +275,126 @@ def kda_step(q, k, v, g, beta, state):
     return o, new
 
 
+# chunk-heads (chunks x rows x heads) whose chunk-local arrays are made
+# at once: 4 chunks of 64 heads, 8 of 32. Small on purpose: a group's
+# arrays are then 8 MB each and the chip keeps them close; with 2048 (a
+# whole 2048-token prefill of 64 heads) the same operations took half
+# as long again, and 128 gains nothing more (PERF.md section 6, PR 35)
+_KDA_GROUP = 256
+_mm = lambda x, y: jnp.matmul(x, y, precision=_HI)
+_t = lambda x: jnp.swapaxes(x, -1, -2)
+
+
+def _blocks_minor(a, sub):
+    """``[*lead, nb sub, c]`` -> ``[sub, c, nb, L]``: the rows cut into
+    blocks of ``sub`` and every block of every leading index laid
+    minor (``L`` = ``lead`` flattened). What is done a block at a time
+    is then dense over the blocks."""
+    return a.reshape((-1, a.shape[-2] // sub, sub, a.shape[-1])) \
+        .transpose(2, 3, 1, 0)
+
+
+def _block_diagonal(blocks, lead):
+    """``[sub, sub, nb, L]`` (``_blocks_minor``'s layout) -> ``[*lead,
+    nb sub, nb sub]`` with the blocks on the diagonal."""
+    sub, _, nb, _ = blocks.shape
+    wide = jnp.concatenate([
+        jnp.pad(blocks[:, :, i], ((0, 0), (i * sub, (nb - 1 - i) * sub),
+                                  (0, 0))) for i in range(nb)], 0)
+    return jnp.moveaxis(wide, -1, 0).reshape(lead + (nb * sub, nb * sub))
+
+
+def _kda_pairwise(qc, kc, big, sub):
+    """``A_ij = sum_c k_i[c] k_j[c] e^{G_i[c] - G_j[c]}`` and ``P`` (the
+    same with ``q_i``) for ``i >= j``: ``qc``, ``kc``, ``big`` (``G``)
+    ``[..., C, d]``. By sub-blocks of ``sub`` rows. A diagonal block
+    forms ``e^{G_i - G_j}`` for its ``i >= j`` (the blocks laid minor:
+    the sum over channels is then over whole vectors of blocks); rows
+    ``i`` of sub-block ``I`` against the columns ``j`` before it are ONE
+    product ``(x_i e^{G_i - r_I}) . (k_j e^{r_I - G_j})``, ``r_I`` the
+    first row of ``G`` in ``I``: ``G`` only falls, so ``G_i <= r_I <=
+    G_j`` and neither exponent is positive. Returns ``A``'s and ``P``'s
+    diagonal blocks ``[sub, sub, nb, L]`` (``_blocks_minor``'s layout;
+    zero above the diagonal) and their other blocks in place ``[..., C,
+    C]`` (zero elsewhere)."""
+    c, d = kc.shape[-2:]
+    nb = c // sub
+    lead = kc.shape[:-2]
+    qm, km, gm = (_blocks_minor(a, sub) for a in (qc, kc, big))
+    low = jnp.tril(jnp.ones((sub, sub), bool))[..., None, None, None]
+    ke = km[None] * jnp.exp(jnp.where(low, gm[:, None] - gm[None], -jnp.inf))
+    # one reduction with two results, so that one pass forms ``e``
+    p_diag, a_diag = jax.lax.reduce(
+        (qm[:, None] * ke, km[:, None] * ke), (jnp.zeros((), _F32),) * 2,
+        lambda x, y: (x[0] + y[0], x[1] + y[1]), (2,))
+    if nb == 1:
+        none = jnp.zeros(lead + (c, c), _F32)
+        return a_diag, none, p_diag, none
+    # sub-blocks 1 .. nb - 1 (nothing lies before the first), q's rows
+    # above k's so that one product serves P and A
+    blk = lambda a: a.reshape(lead + (nb, sub, d))[..., 1:, :, :]
+    gb = blk(big)
+    first = gb[..., :1, :]
+    before = jnp.arange(c) < (jnp.arange(1, nb) * sub)[:, None]
+    right = _t(kc[..., None, :, :] * jnp.exp(jnp.where(
+        before[..., None], first - big[..., None, :, :], -jnp.inf)))
+    left = jnp.concatenate([blk(qc), blk(kc)], -2) \
+        * jnp.tile(jnp.exp(gb - first), (2, 1))
+    off = _mm(left, right)
+    top = [(0, 0)] * len(lead) + [(1, 0), (0, 0), (0, 0)]
+    p_off, a_off = (jnp.pad(x, top).reshape(lead + (c, c))
+                    for x in (off[..., :sub, :], off[..., sub:, :]))
+    return a_diag, a_off, p_diag, p_off
+
+
+def _unit_lower_inverse(diag, off):
+    """``(I + L)^-1`` for strictly lower triangular ``L`` ``[..., C,
+    C]``, given as its diagonal blocks ``diag`` ``[sub, sub, nb, L]``
+    (``_blocks_minor``'s layout) and the rest ``off`` ``[..., C, C]``.
+    By forward substitution: row by row inside the diagonal blocks
+    (``T_i = e_i - sum_{j<i} L_ij T_j``, a row one dense pass over the
+    blocks), then block by block, doubling: ``[[A, 0], [M, D]]^-1 =
+    [[A^-1, 0], [-D^-1 M A^-1, D^-1]]``. A zero row of ``L`` gives that
+    row of the identity, exactly, at every step."""
+    sub, c = diag.shape[0], off.shape[-1]
+    eye = jnp.eye(sub, dtype=_F32)
+    rows = []
+    for i in range(sub):
+        r = jnp.broadcast_to(eye[i][:, None, None], diag.shape[1:])
+        for j in range(i):
+            r = r - diag[i, j] * rows[j]
+        rows.append(r)
+    inv = _block_diagonal(jnp.stack(rows), off.shape[:-2])
+    at = jnp.arange(c)
+    size = sub
+    while size < c:
+        corner = (at[:, None] // (2 * size) == at // (2 * size)) \
+            & (at[:, None] // size != at // size)
+        inv = inv - _mm(_mm(inv, jnp.where(corner, off, 0.0)), inv)
+        size *= 2
+    return inv
+
+
+def _kda_chunk_local(qc, kc, vc, gc, bc, sub):
+    """What a chunk computes without the state before it, for every
+    chunk given (``[..., C, d]``; ``bc`` ``[..., C, 1]``): ``q e^G``,
+    ``W``, ``U0``, ``P``, ``k e^{G_C - G}`` and ``e^{G_C}`` as a
+    column."""
+    lead = kc.shape[:-2]
+    c = kc.shape[-2]
+    # the running sum as a product: a reduce-window is slow on the chip
+    big = _mm(jnp.tril(jnp.ones((c, c), _F32)), gc)
+    eg = jnp.exp(big)
+    last = big[..., -1:, :]
+    a_diag, a_off, p_diag, p_off = _kda_pairwise(qc, kc, big, sub)
+    strict = jnp.tril(jnp.ones((sub, sub), bool), -1)[..., None, None]
+    inv = _unit_lower_inverse(
+        jnp.where(strict, _blocks_minor(bc, sub) * a_diag, 0.0), bc * a_off)
+    return (qc * eg, _mm(inv, bc * kc * eg), _mm(inv, bc * vc),
+            p_off + _block_diagonal(p_diag, lead),
+            kc * jnp.exp(last - big), _t(jnp.exp(last)))
+
+
 def kda_chunked(q, k, v, g, beta, state, chunk):
     """The same recurrence over ``S`` tokens, ``chunk`` at a time:
     ``q``, ``k``, ``g`` ``[B, S, H, dk]``, ``v`` ``[B, S, H, dv]``,
@@ -271,40 +407,56 @@ def kda_chunked(q, k, v, g, beta, state, chunk):
         o_i = (q_i e^{G_i})^T S_0 + sum_{j<=i} P_ij u_j     (P as A, with q_i)
         S_C = Diag(e^{G_C}) S_0 + sum_j (k_j e^{G_C - G_j}) u_j^T
 
-    ``(I + Diag(beta) A) U = ...`` is unit lower triangular and solved
-    once a chunk. Returns ``(o [B, S, H, dv], new state)``."""
+    Only ``S_0`` depends on the chunk before. Everything else is made
+    for all chunks at once (``_kda_chunk_local``; groups of
+    ``_KDA_GROUP`` chunk-heads where the sequence is longer), as float32
+    matmuls: ``A`` and ``P`` by sub-blocks of ``min(16, chunk)`` tokens
+    (``_kda_pairwise``), ``T = (I + Diag(beta) A_strict)^-1``
+    (``_unit_lower_inverse``), ``W = T (beta k e^G)``, ``U0 = T (beta
+    v)``. The scan over chunks carries the state alone::
+
+        u = U0 - W S_0;   o = (q e^G) S_0 + P u
+        S_C = Diag(e^{G_C}) S_0 + (k e^{G_C - G})^T u
+
+    A frozen position (``beta = 0``, ``g = 0``) has a row of the
+    identity in ``T``, so its ``u`` is exactly 0 and a chunk of them
+    returns ``S_0`` bitwise. Returns ``(o [B, S, H, dv], new state)``."""
     b, s, h, dk = q.shape
     n = s // chunk
-    # [n, B, H, chunk, .]: chunks lead (the scan), heads batch
-    cut = lambda a: jnp.moveaxis(
-        a.reshape((b, n, chunk) + a.shape[2:]), (1, 3), (0, 2))
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    mm = lambda x, y: jnp.matmul(x, y, precision=_HI)
+    sub = min(16, chunk)
+    if chunk % sub:
+        raise ValueError(f"kda_chunked: chunk {chunk} is no multiple of "
+                         f"its sub-block {sub}")
+    m = max(1, min(n, _KDA_GROUP // (b * h)))
+    span, groups = m * chunk, -(-n // m)
+    # chunks added to fill the last group are frozen ones
+    q, k, v, g, beta = (
+        jnp.pad(a, [(0, 0), (0, groups * span - s)]
+                + [(0, 0)] * (a.ndim - 2)) for a in (q, k, v, g, beta))
+
+    def cut(a, i):
+        """Group ``i`` as ``[m, B, H, chunk, .]``: chunks lead (the
+        scan), heads batch."""
+        a = jax.lax.dynamic_slice_in_dim(a, i * span, span, axis=1)
+        return jnp.moveaxis(a.reshape((b, m, chunk) + a.shape[2:]),
+                            (1, 3), (0, 2))
 
     def one(s0, xs):
-        qc, kc, vc, gc, bc = xs
-        big = jnp.cumsum(gc, axis=-2)
-        diff = big[..., :, None, :] - big[..., None, :, :]
-        e = jnp.exp(jnp.where(lower[..., None], diff, -jnp.inf))
-        a = jnp.sum(kc[..., :, None, :] * kc[..., None, :, :] * e, -1)
-        p = jnp.sum(qc[..., :, None, :] * kc[..., None, :, :] * e, -1)
-        eg = jnp.exp(big)
-        rhs = bc * (vc - mm(kc * eg, s0))
-        system = jnp.where(strict, bc * a, 0.0) \
-            + jnp.eye(chunk, dtype=_F32)
-        u = jax.lax.linalg.triangular_solve(
-            system, rhs, left_side=True, lower=True, unit_diagonal=True)
-        o = mm(qc * eg, s0) + mm(p, u)
-        last = big[..., -1:, :]
-        s1 = jnp.swapaxes(jnp.exp(last), -1, -2) * s0 \
-            + mm(jnp.swapaxes(kc * jnp.exp(last - big), -1, -2), u)
-        return s1, o
+        qe, w, u0, p, kd, decay = xs
+        u = u0 - _mm(w, s0)
+        s1 = decay * s0 + jnp.einsum("...cd,...cv->...dv", kd, u,
+                                     precision=_HI)
+        return s1, _mm(qe, s0) + _mm(p, u)
 
-    state, o = jax.lax.scan(
-        one, state.astype(_F32),
-        (cut(q), cut(k), cut(v), cut(g), cut(beta)[..., None]))
-    return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, s, h, -1), state
+    def group(s0, i):
+        s1, o = jax.lax.scan(one, s0, _kda_chunk_local(
+            cut(q, i), cut(k, i), cut(v, i), cut(g, i),
+            cut(beta, i)[..., None], sub))
+        return s1, jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, span, h, -1)
+
+    state, o = jax.lax.scan(group, state.astype(_F32), jnp.arange(groups))
+    return jnp.moveaxis(o, 0, 1).reshape(b, groups * span, h, -1)[:, :s], \
+        state
 
 
 def kda_scan(q, k, v, g, beta, state, chunk, length=None):

@@ -51,6 +51,9 @@ def test_diff_verdict(tmp_path, capsys, other, rc, says):
 # tree's dump. PR 31 (the span ladder of the paged decode read) wrote it:
 # every program but the four paged decode steps had the text of that PR's
 # parent, so a later change to a slab, block or train program shows here.
+# PR 35 (the KDA chunked scan's chunk-local part hoisted out of its
+# loop) wrote ``kimi_linear_prefill_block_bfloat16`` anew, the one
+# recorded program that holds the scan; every other text stayed.
 # A PR that means to change one writes the record anew:
 #   JAX_PLATFORMS=cpu python tools/lowered_text.py dump . /tmp/lt
 #   python tools/lowered_text.py digest /tmp/lt tests/lowered_text.json

@@ -123,34 +123,80 @@ def test_layer_kinds_follow_the_published_period():
 
 
 # ----------------------------------------------------------- the KDA forms
-@pytest.mark.parametrize("s", [1, 5, 8, 16, 19])
-def test_chunked_scan_equals_the_token_scan(s):
-    """Chunks of 8 over lengths that are and are not whole chunks,
-    against the reference's recurrence a token at a time; decays down
-    to exp(-1.6) a token."""
+@pytest.mark.parametrize("s, chunk, atol", [
+    (1, 8, 2e-6), (5, 8, 2e-6), (8, 8, 2e-6), (16, 8, 2e-6), (19, 8, 2e-6),
+    # four sub-blocks of 16 a chunk: whole chunks and a ragged one, so
+    # every diagonal block and every pair of sub-blocks runs. A system
+    # of 64 tokens over 8-wide keys rounds to 1-5e-6 of a state of 2-3
+    # in float32 (the scan as it stood before the sub-blocks read the
+    # same against a float64 recurrence, to a few per cent)
+    (64, 64, 1e-5), (100, 64, 1e-5), (128, 64, 1e-5)])
+def test_chunked_scan_equals_the_token_scan(s, chunk, atol):
+    """Chunks of 8 (one sub-block) and of 64 (four) over lengths that
+    are and are not whole chunks, against the reference's recurrence a
+    token at a time; decays down to exp(-1.6) a token."""
     q, k, v, g, beta = _kda_inputs(s, seed=s)
     zero = jnp.zeros((2, 3, 8, 8), jnp.float32)
-    o, state = jax.jit(lambda *a: solar_open2.kda_scan(*a, zero, 8))(
+    o, state = jax.jit(lambda *a: solar_open2.kda_scan(*a, zero, chunk))(
         q, k, v, g, beta)
     for b in range(2):
         want_o, want_s = ref.kda_recurrence(q[b], k[b], v[b], jnp.exp(g[b]),
                                             beta[b])
-        np.testing.assert_allclose(o[b], want_o, atol=2e-6)
-        np.testing.assert_allclose(state[b], want_s, atol=2e-6)
+        np.testing.assert_allclose(o[b], want_o, atol=atol)
+        np.testing.assert_allclose(state[b], want_s, atol=atol)
 
 
-def test_strong_decay_over_a_long_chunk_stays_finite():
-    """64 tokens of decay exp(-1.6) each are exp(-102) end to end: the
-    chunked form never forms the reciprocal."""
-    q, k, v, g, beta = _kda_inputs(64, seed=3, b=1)
+@pytest.mark.parametrize("s", [64, 160])
+def test_strong_decay_over_a_long_chunk_stays_finite(s):
+    """64 tokens of decay exp(-1.6) each are exp(-102) end to end, and
+    160 cross sub-block and chunk boundaries with a state carried into
+    the second and third chunk: the chunked form never forms the
+    reciprocal of a decay."""
+    q, k, v, g, beta = _kda_inputs(s, seed=3, b=1)
     g = jnp.full_like(g, -1.6)
     zero = jnp.zeros((1, 3, 8, 8), jnp.float32)
     o, state = solar_open2.kda_scan(q, k, v, g, beta, zero, 64)
     want_o, want_s = ref.kda_recurrence(q[0], k[0], v[0], jnp.exp(g[0]),
                                         beta[0])
     assert np.isfinite(np.asarray(o)).all()
+    assert np.abs(np.asarray(want_s)).max() > 0.1
     np.testing.assert_allclose(o[0], want_o, atol=2e-6)
     np.testing.assert_allclose(state[0], want_s, atol=2e-6)
+
+
+@pytest.mark.parametrize("chunk, first, s", [(8, 11, 30), (64, 37, 150)])
+def test_scan_from_a_state_continues_the_token_scan(chunk, first, s):
+    """A scan that starts from the state ``first`` tokens left (not
+    zero) gives the rest of the sequence's outputs and its last
+    state."""
+    q, k, v, g, beta = _kda_inputs(s, seed=first, b=1)
+    want_o, want_s = ref.kda_recurrence(q[0], k[0], v[0], jnp.exp(g[0]),
+                                        beta[0])
+    head = lambda a: a[:, :first]
+    _, state = ref.kda_recurrence(*(head(a)[0] for a in (q, k, v)),
+                                  jnp.exp(head(g)[0]), head(beta)[0])
+    assert np.abs(np.asarray(state)).max() > 0.1
+    rest = lambda a: a[:, first:]
+    o, last = solar_open2.kda_scan(rest(q), rest(k), rest(v), rest(g),
+                                   rest(beta), state[None], chunk)
+    np.testing.assert_allclose(o[0], want_o[first:], atol=2e-6)
+    np.testing.assert_allclose(last[0], want_s, atol=2e-6)
+
+
+@pytest.mark.parametrize("group", [6, 12, 18])
+def test_scan_by_groups_of_chunks_is_the_scan_at_once(group, monkeypatch):
+    """The chunk-local part is made for ``_KDA_GROUP`` chunk-heads at a
+    time: 1, 2 and 3 chunks of the 5 here (2 rows x 3 heads), the last
+    group filled with frozen chunks; every grouping gives the bits of
+    the whole sequence at once."""
+    q, k, v, g, beta = _kda_inputs(37, seed=5)
+    start = jnp.asarray(np.random.default_rng(6).normal(size=(2, 3, 8, 8)),
+                        jnp.float32)
+    want = solar_open2.kda_scan(q, k, v, g, beta, start, 8)
+    monkeypatch.setattr(solar_open2, "_KDA_GROUP", group)
+    got = solar_open2.kda_scan(q, k, v, g, beta, start, 8)
+    for a, b in zip(got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_one_token_step_equals_the_token_scan():

@@ -194,6 +194,20 @@ def _kda_scan(q, k, v, g, beta, state):
                                 length=jnp.int32(1500))
 
 
+def _assert_no_pairwise_tensor(fn, shapes):
+    """The chunked scan as lowered forms no ``[.., H, 64, 64, 128]``
+    float32 array: the decays of every pair of a chunk's tokens, a
+    channel each, which the scan made a chunk at a time before its
+    chunks were cut into sub-blocks of 16. All that is formed pair by
+    pair now is the four diagonal blocks, ``[16, 16, 128, 4, L]`` with
+    the blocks of a group's ``L`` chunk-heads laid minor."""
+    heads = shapes[0][0][2]
+    text = jax.jit(fn).lower(
+        *(jax.ShapeDtypeStruct(*s) for s in shapes)).as_text()
+    assert "<16x16x128x4x256xf32>" in text
+    assert f"x{heads}x64x64x128xf32" not in text
+
+
 def _held_experts(h, idx, w, gate_up, down):
     from paddle_tpu.models import xing4
 
@@ -204,9 +218,9 @@ def _held_experts(h, idx, w, gate_up, down):
 # 2048-token prefill of 64 KDA heads of 128 with a float32 state a row,
 # 40 of 320 experts of width 1280 held, top-8. No Mosaic kernel of the
 # repo's among them: what is held here is that the chip's compiler
-# takes the scan's triangular solve and the held share's row ladder
-# (three branches of two grouped matmuls each), and how much each needs
-# beside its arguments.
+# takes the scan's chunk-local part made for every chunk at once and
+# the held share's row ladder (three branches of two grouped matmuls
+# each), and how much each needs beside its arguments.
 _ROW = lambda n, *d: ((n, 64) + d, F32)
 SOLAR_CASES = {
     "kda step 64 rows": (
@@ -232,6 +246,8 @@ def test_row_state_and_share_programs_compile_for_v5e(chip_compile, name):
     fn, shapes, room = SOLAR_CASES[name]
     compiled = chip_compile(fn, *(chip_compile.sds(*s) for s in shapes))
     assert compiled.memory_analysis().temp_size_in_bytes < room
+    if fn is _kda_scan:
+        _assert_no_pairwise_tensor(fn, shapes)
     if fn is not _held_experts:
         return
     # the grouped matmuls (the chip's compiler names them
@@ -328,6 +344,8 @@ def test_latent_page_beside_row_state_programs_compile_for_v5e(
     text = compiled.as_text()
     if fn is _nope_mla_prefill:
         assert "tpu_custom_call" in text     # flash, heads padded to 256
+    if fn is _kda_scan_16k:
+        _assert_no_pairwise_tensor(fn, shapes)
     if fn is not _held_half:
         return
     from paddle_tpu.models import xing4
