@@ -122,10 +122,11 @@ def build_prefill_body(net, do_sample, top_k, top_p):
     Over a net that keeps a state a row the block's row arrays come
     back holding the prompt's final state (``generation.prefill`` hands
     the net ``length``), and the program is called
-    ``prefill_state_body``: a trace reader can tell it from the other
-    ``jit_body`` programs."""
+    ``prefill_state_body`` where every other net's is ``prefill_body``:
+    a trace reader can tell the two apart."""
 
-    def body(params, buffers, ids, length, flat_block, temperature, key):
+    def prefill_body(params, buffers, ids, length, flat_block, temperature,
+                     key):
         net.load_functional_state(params, buffers)
         net.eval()
         logits, caches = prefill(
@@ -141,8 +142,9 @@ def build_prefill_body(net, do_sample, top_k, top_p):
         return nxt, _flatten(caches)
 
     if keeps_row_state(net.config):
-        body.__name__ = body.__qualname__ = "prefill_state_body"
-    return body
+        prefill_body.__name__ = prefill_body.__qualname__ = \
+            "prefill_state_body"
+    return prefill_body
 
 
 def build_chunk_prefill_body(net, do_sample, top_k, top_p):
@@ -153,8 +155,8 @@ def build_chunk_prefill_body(net, do_sample, top_k, top_p):
     program; the logits row is ``length - 1`` relative to the chunk.
     Tier-1-pinned bitwise-equal to the full-prompt prefill body."""
 
-    def body(params, buffers, ids, length, pos, flat_block, temperature,
-             key):
+    def chunk_prefill_body(params, buffers, ids, length, pos, flat_block,
+                           temperature, key):
         net.load_functional_state(params, buffers)
         net.eval()
         logits, caches = prefill(
@@ -169,7 +171,7 @@ def build_chunk_prefill_body(net, do_sample, top_k, top_p):
                            key)
         return nxt, _flatten(caches)
 
-    return body
+    return chunk_prefill_body
 
 
 class _Seq:
@@ -220,6 +222,31 @@ class _Launched:
         self.counted = counted
         self.seqs = seqs
         self.step = step
+
+
+class _RequestPhase(profiler.RecordEvent):
+    """A phase of the driver thread that serves one request, in one
+    call for both clocks: the profiler span ``serving::<name>`` with
+    the request's ``rid`` among its stats (what varies stays out of the
+    name) and, where ``span`` (a dict of attributes) asks for it, the
+    request's own span ``engine.<name>`` over the same interval, under
+    ``parent`` (the request's trace where None). A request that is
+    sampled out gets no span of its own."""
+
+    def __init__(self, name, handle, span=None, parent=None, **stats):
+        super().__init__(f"serving::{name}",
+                         rid=handle.request.request_id, **stats)
+        self._span = None if span is None else get_tracer().start_span(
+            f"engine.{name}", handle.trace if parent is None else parent,
+            **span)
+
+    def __exit__(self, exc_type, exc, tb):
+        self.end()
+        if self._span is not None:
+            # the request's spans are all admission's
+            self._span.finish(
+                **({"error": "admission_error"} if exc_type else {}))
+        return False
 
 
 class ServingEngine:
@@ -347,6 +374,9 @@ class ServingEngine:
         # read, None once the engine is idle: where the next
         # metrics.host_gap sample starts
         self._read_done = None
+        # this iteration admitted while other rows were resident: its
+        # host_gap sample is a metrics.admit_hold sample too
+        self._admit_held = False
         # the decode step launched and not yet read (_Launched), None
         # when nothing is: before the first launch, after admission
         # settled it, under speculation, on an idle engine
@@ -459,7 +489,7 @@ class ServingEngine:
         if fn is not None:
             return fn
 
-        def body(flat_decode, flat_block, slot):
+        def adopt_body(flat_decode, flat_block, slot):
             from ..quantization.kv import adopt_into_slab
 
             return [
@@ -468,7 +498,7 @@ class ServingEngine:
             ]
 
         fn = jax.jit(
-            body, donate_argnums=(0,)
+            adopt_body, donate_argnums=(0,)
         )
         self._adopt_fns[bucket] = fn
         self.trace_guard.record_compile(
@@ -533,10 +563,10 @@ class ServingEngine:
         if fn is None:
             from ..quantization.kv import slab_row_block
 
-            def body(flat, s):
+            def spec_gather_body(flat, s):
                 return [slab_row_block(a, s) for a in flat]
 
-            fn = self._spec_gather_fn = jax.jit(body)
+            fn = self._spec_gather_fn = jax.jit(spec_gather_body)
             self.trace_guard.record_compile(
                 "serving::spec_gather", self.max_seq_len,
                 origin="serving/engine.py",
@@ -726,11 +756,11 @@ class ServingEngine:
         assert slot is not None  # caller checked free_slots
         key = self._next_key()
         t_pre = self.clock()
-        psp = None if handle.trace is None else get_tracer().start_span(
-            "engine.prefill", handle.trace, mode="local", bucket=bucket
-        )
         try:
-            with profiler.RecordEvent(f"serving::prefill_b{bucket}"):
+            # the request's engine.prefill span covers what the phase
+            # does: the prefill, its adoption and the first-token read
+            with _RequestPhase("prefill", handle, bucket=bucket,
+                               span={"mode": "local", "bucket": bucket}):
                 nxt, new_flat = self._run(
                     ("prefill", bucket), self._prefill_fn(bucket),
                     self._params, self._buffers, jnp.asarray(ids),
@@ -738,22 +768,19 @@ class ServingEngine:
                     jnp.float32(self.temperature), key,
                 )
                 blk.caches = _unflatten(new_flat, self.config)
-                self._flat = self._run(
-                    ("adopt", bucket), self._adopt_fn(bucket),
-                    self._flat, new_flat, jnp.int32(slot),
-                )
+                with _RequestPhase("adopt", handle, bucket=bucket):
+                    self._flat = self._run(
+                        ("adopt", bucket), self._adopt_fn(bucket),
+                        self._flat, new_flat, jnp.int32(slot),
+                    )
                 t0 = int(np.asarray(nxt)[0])
         except BaseException:
-            if psp is not None:
-                psp.finish(error="admission_error")
             self._slab.release(slot)
             # the failed call may already have consumed the block's
             # donated buffers — recycling them would poison the bucket's
             # freelist; drop the block instead
             self.pool.discard(blk)
             raise
-        if psp is not None:
-            psp.finish()
         self.pool.free(blk)
         handle.status = RUNNING
         handle.weights_version = self.weights_version
@@ -817,12 +844,16 @@ class ServingEngine:
         launch one decode step over the whole resident KV state and
         read the one launched before it. Each phase is a
         ``RecordEvent`` span with a fixed name (a phase's own time is
-        its span less the spans inside it): ``serving::admit`` (with
+        its span less the spans inside it; what varies, the bucket, the
+        request and the step, is a stat): ``serving::admit`` (with
         ``serving::settle``, the read of the step in flight that an
-        admission waits for, and that step's ``serving::emit``, inside
-        it), ``serving::decode_inputs``, ``serving::decode_step`` (the
-        launch of step n+1 AND the blocking read of step n),
-        ``serving::emit`` (step n's tokens), ``serving::step_tail``."""
+        admission waits for, that step's ``serving::emit`` and the
+        admission's ``gather``, ``prefill``, ``chunk_prefill`` and
+        ``adopt`` inside it), ``serving::decode_inputs``,
+        ``serving::decode_step`` (own time: the launch of step n+1
+        alone; ``serving::read``, the blocking read of step n, is
+        inside it), ``serving::emit`` (step n's tokens),
+        ``serving::step_tail``."""
         if self._closed:
             raise RuntimeError(f"{type(self).__name__} is closed")
         with profiler.RecordEvent("serving::step", step=self.step_count):
@@ -861,6 +892,7 @@ class ServingEngine:
         set)."""
         cap = self._max_admissions_per_step()
         admitted = 0
+        self._admit_held = False
         # a pending reload pauses admission: in-flight requests drain
         # on the OLD weights, queued ones wait for the swap — zero
         # dropped, one weights version per request
@@ -876,6 +908,8 @@ class ServingEngine:
                                              fits=self._admission_fits())
             if handle is None:
                 break
+            if self.active_slots:
+                self._admit_held = True
             try:
                 self._admit_one(handle)
             except BaseException as e:
@@ -976,8 +1010,10 @@ class ServingEngine:
             self._in_flight = None
             if launch:
                 if self._read_done is not None:
-                    self.metrics.host_gap.observe(
-                        self.clock() - self._read_done)
+                    gap = self.clock() - self._read_done
+                    self.metrics.host_gap.observe(gap)
+                    if self._admit_held:
+                        self.metrics.admit_hold.observe(gap)
                     self._read_done = None
                 nxt, self._flat, counted = self._run(
                     ("decode",), self._decode_fn,
@@ -1002,9 +1038,12 @@ class ServingEngine:
         """Block until a launched step's tokens are on the host: the
         one place the driver waits for the device. With the next step
         already launched the device goes on under everything the host
-        does until it comes back here."""
+        does until it comes back here. The span ``serving::read``
+        carries the number of the step it reads, as that step's launch
+        (``serving::decode_step``) did."""
         t0 = self.clock()
-        toks = np.asarray(launched.nxt)
+        with profiler.RecordEvent("serving::read", step=launched.step):
+            toks = np.asarray(launched.nxt)
         self.metrics.observe_step_counters(launched.counted)
         # the device arrays die here, right after the read (see the
         # launch for why not later)

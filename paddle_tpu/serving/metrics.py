@@ -13,9 +13,9 @@ and analysis telemetry. What the serving loop was DOING at a moment is
 not here but in the ``profiler.RecordEvent`` phase spans of
 ``serving/engine.py`` and ``http_frontend.py``, which a jax profiler
 trace holds on the device's clock; the histograms ``host_gap``,
-``read_wait``, ``prefill`` and ``submit_wait`` and the counter
-``steps_overlapped`` carry the same phases' totals over a whole run,
-where a few seconds of trace hold too few requests.
+``admit_hold``, ``read_wait``, ``prefill`` and ``submit_wait`` and the
+counter ``steps_overlapped`` carry the same phases' totals over a whole
+run, where a few seconds of trace hold too few requests.
 """
 from __future__ import annotations
 
@@ -158,6 +158,12 @@ class ServingMetrics:
                  "the program launched before that read (an idle "
                  "engine starts no sample, nor does a launch that no "
                  "read came before)")
+        self.admit_hold = Histogram(
+            "admit_hold", prom_name=f"{ns}_admit_hold_seconds",
+            help="driver thread, per iteration that admitted a request "
+                 "while other rows were resident: that iteration's "
+                 "host_gap sample, which is how long every resident "
+                 "stream waited for the admission beyond a decode step")
         self.read_wait = Histogram(
             "read_wait", prom_name=f"{ns}_read_wait_seconds",
             help="driver thread, per decode step read: how long the "
@@ -256,8 +262,8 @@ class ServingMetrics:
             self.guard_fires, self.reloads, self.reload_ttft_spike,
             self.ttft, self.itl, self.e2e,
             self.queue_wait, self.queue_depth, self.slot_occupancy,
-            self.host_gap, self.read_wait, self.steps_overlapped,
-            self.prefill, self.submit_wait,
+            self.host_gap, self.admit_hold, self.read_wait,
+            self.steps_overlapped, self.prefill, self.submit_wait,
             self.resident_tokens, self.span_tokens, self.experts_touched,
             self.local_assignments, self.dispatch_rows,
             self.spec_rounds, self.spec_proposed, self.spec_accepted,
@@ -322,6 +328,7 @@ class ServingMetrics:
             "queue_depth": self.queue_depth.snapshot(),
             "slot_occupancy": self.slot_occupancy.snapshot(),
             "host_gap": self.host_gap.snapshot(),
+            "admit_hold": self.admit_hold.snapshot(),
             "read_wait": self.read_wait.snapshot(),
             "prefill": self.prefill.snapshot(),
             "submit_wait": self.submit_wait.snapshot(),
@@ -346,8 +353,8 @@ class ServingMetrics:
         for k, v in r["counters"].items():
             lines.append(f"{k:>20}: {v}")
         for name in ("ttft", "itl", "e2e", "queue_wait", "submit_wait",
-                     "prefill", "host_gap", "read_wait", "queue_depth",
-                     "slot_occupancy"):
+                     "prefill", "host_gap", "admit_hold", "read_wait",
+                     "queue_depth", "slot_occupancy"):
             s = r[name]
             if not s.get("count"):
                 lines.append(f"{name:>20}: (no samples)")

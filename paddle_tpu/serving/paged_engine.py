@@ -86,6 +86,7 @@ from ..observability.tracing import get_tracer
 from ..quantization import kv as qkv
 from .engine import (
     ServingEngine,
+    _RequestPhase,
     _Seq,
     _flatten,
     _unflatten,
@@ -495,7 +496,7 @@ class PagedServingEngine(ServingEngine):
         n_pages_b = bucket // ps
         by_row = self._row_arrays
 
-        def body(flat_arena, flat_block, page_ids, *row):
+        def adopt_body(flat_arena, flat_block, page_ids, *row):
             from ..quantization.kv import adopt_into_pages, adopt_into_slab
 
             return [
@@ -505,9 +506,10 @@ class PagedServingEngine(ServingEngine):
             ]
 
         if any(by_row):
-            body.__name__ = body.__qualname__ = "adopt_state_body"
+            adopt_body.__name__ = adopt_body.__qualname__ = \
+                "adopt_state_body"
         fn = jax.jit(
-            body, donate_argnums=(0,)
+            adopt_body, donate_argnums=(0,)
         )
         self._adopt_fns[bucket] = fn
         self.trace_guard.record_compile(
@@ -528,7 +530,7 @@ class PagedServingEngine(ServingEngine):
         ps = self.page_size
         n_pages_b = bucket // ps
 
-        def body(flat_arena, src_ids):
+        def gather_body(flat_arena, src_ids):
             from ..quantization.kv import gather_block_from_pages
 
             return [
@@ -536,7 +538,7 @@ class PagedServingEngine(ServingEngine):
                 for a in flat_arena
             ]
 
-        fn = jax.jit(body)
+        fn = jax.jit(gather_body)
         self._gather_fns[bucket] = fn
         self.trace_guard.record_compile(
             "serving::gather_pages", bucket,
@@ -687,7 +689,7 @@ class PagedServingEngine(ServingEngine):
         except PagesExhausted:
             return None
         ps = self.page_size
-        with profiler.RecordEvent(f"serving::restore_adopt_b{ps}"):
+        with profiler.RecordEvent("serving::restore_adopt", bucket=ps):
             self._flat = self._run(
                 ("adopt", ps), self._adopt_fn(ps),
                 self._flat, self._page_block(arrays),
@@ -748,7 +750,7 @@ class PagedServingEngine(ServingEngine):
         src = np.zeros((bucket // ps,), np.int32)
         n = min(len(pages), bucket // ps)
         src[:n] = pages[:n]
-        with profiler.RecordEvent(f"serving::spec_gather_b{bucket}"):
+        with profiler.RecordEvent("serving::spec_gather", bucket=bucket):
             flat_block = self._run(
                 ("gather", bucket), self._gather_fn(bucket),
                 self._flat, jnp.asarray(src),
@@ -865,7 +867,9 @@ class PagedServingEngine(ServingEngine):
                 self.prefix_cache.misses.inc()
         # the per-admission prefill span: mode (remote|local|fallback|
         # chunk) plus the prefix-hit/chunk-plan attributes the warm
-        # path decided on — None (zero allocations) when sampled out
+        # path decided on — None (zero allocations) when sampled out.
+        # It also covers the remote attempt and the gather, which no
+        # phase does, so it is opened here and not by a _RequestPhase
         t_pre = self.clock()
         psp = None if handle.trace is None else get_tracer().start_span(
             "engine.prefill", handle.trace, bucket=bucket,
@@ -912,22 +916,17 @@ class PagedServingEngine(ServingEngine):
                 n_gather = -(-c // ps)
                 src = np.zeros((bucket // ps,), np.int32)
                 src[:n_gather] = match.pages[:n_gather]
-                gsp = None if psp is None else get_tracer().start_span(
-                    "engine.gather", psp, pages=n_gather
-                )
-                with profiler.RecordEvent(f"serving::gather_b{bucket}"):
+                with _RequestPhase("gather", handle, bucket=bucket,
+                                   span={"pages": n_gather}, parent=psp):
                     flat_block = self._run(
                         ("gather", bucket), self._gather_fn(bucket),
                         self._flat, jnp.asarray(src),
                     )
-                if gsp is not None:
-                    gsp.finish()
                 tail = np.zeros((1, tb), np.int32)
                 tail[0, :L] = req.input_ids[c:]
                 self.chunk_prefills += 1
-                with profiler.RecordEvent(
-                    f"serving::chunk_prefill_b{bucket}_t{tb}"
-                ):
+                with _RequestPhase("chunk_prefill", handle, bucket=bucket,
+                                   tail=tb):
                     nxt, new_flat = self._run(
                         ("chunk", bucket, tb),
                         self._chunk_fn(bucket, tb),
@@ -935,7 +934,7 @@ class PagedServingEngine(ServingEngine):
                         jnp.int32(L), jnp.int32(c), flat_block,
                         jnp.float32(self.temperature), key,
                     )
-                t0 = int(np.asarray(nxt)[0])
+                    t0 = int(np.asarray(nxt)[0])
                 if c % ps:
                     # recompute boundary inside a cached page: its
                     # content was cloned through the gather into a
@@ -944,7 +943,7 @@ class PagedServingEngine(ServingEngine):
                     self.prefix_cache.cow_clones.inc()
             elif remote is None:
                 self.local_prefills += 1
-                with profiler.RecordEvent(f"serving::prefill_b{bucket}"):
+                with _RequestPhase("prefill", handle, bucket=bucket):
                     nxt, new_flat = self._run(
                         ("prefill", bucket), self._prefill_fn(bucket),
                         self._params, self._buffers, jnp.asarray(ids),
@@ -959,10 +958,8 @@ class PagedServingEngine(ServingEngine):
                 t0, new_flat = remote
             if psp is not None:
                 psp.finish()
-            asp = None if handle.trace is None else \
-                get_tracer().start_span("engine.adopt", handle.trace,
-                                        bucket=bucket)
-            with profiler.RecordEvent(f"serving::adopt_b{bucket}"):
+            with _RequestPhase("adopt", handle, bucket=bucket,
+                               span={"bucket": bucket}):
                 # adopt: the request's FRESH pages within the bucket
                 # span land in the claim; shared by-reference pages
                 # (indices < n_ref) and block pad pages scatter to
@@ -975,8 +972,6 @@ class PagedServingEngine(ServingEngine):
                     self._flat, new_flat,
                     *self._adopt_where(page_ids, row),
                 )
-            if asp is not None:
-                asp.finish()
             if self.prefix_cache is not None:
                 # publish-on-admission: full prompt pages are stable
                 # the moment prefill wrote them (decode writes start at

@@ -1,6 +1,8 @@
 """The program's own instruments, on the CPU: the serving loop's phase
-spans land in a ``jax.profiler`` trace on one host line; the three
-window-wide histograms count what they say; the compiled programs
+spans land in a ``jax.profiler`` trace on one host line, named without
+what varies (bucket, request and step are stats), beside the calls of
+the programs by their own names; the window-wide histograms count what
+they say; the compiled programs
 carry the scopes ``attn_core``, ``lm_head``, ``loss`` and ``optimizer``
 and are still named ``step`` and ``_decode_body``; scopes change no
 result.
@@ -31,7 +33,8 @@ from paddle_tpu.serving import (
 DRIVER_SPANS = (
     "frontend::lock_wait", "serving::step", "serving::admit",
     "serving::grow_pages", "serving::decode_inputs",
-    "serving::decode_step", "serving::emit", "serving::step_tail",
+    "serving::decode_step", "serving::read", "serving::emit",
+    "serving::step_tail", "serving::prefill", "serving::adopt",
 )
 
 
@@ -62,7 +65,7 @@ def _paged(net):
 def traced(net, tmp_path_factory):
     """Three requests through a front end under a profiler trace: the
     host lines as ``{line index: [(name, start, end, stats)]}`` of the
-    ``::`` spans, and the engine's report."""
+    ``::`` spans and the programs' calls, and the engine's report."""
     from jax.profiler import ProfileData
 
     eng = _paged(net)
@@ -96,7 +99,8 @@ def traced(net, tmp_path_factory):
                 (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
                  dict(ev.stats))
                 for ev in ln.events
-                if ev.name.startswith(("serving::", "frontend::"))
+                if ev.name.startswith(("serving::", "frontend::",
+                                       "PjitFunction("))
             ]
             if spans:
                 lines[i] = spans
@@ -115,8 +119,8 @@ def test_driver_spans_share_one_host_line(traced):
     names = {n for n, *_ in driver[0]}
     for span in DRIVER_SPANS:
         assert span in names, span
-    assert any(n.startswith("serving::prefill_b") for n in names)
-    assert any(n.startswith("serving::adopt_b") for n in names)
+    # one key a phase: nothing that varies is part of a name
+    assert not [n for n in names if "::" in n and re.search(r"\d", n)]
     # a handler thread's wait is a histogram sample, not a span: the
     # front end's spans are all the driver's
     for sp in lines.values():
@@ -137,11 +141,17 @@ def test_phases_nest_inside_the_step(traced):
                   "serving::emit", "serving::step_tail"):
         for sp in by[phase]:
             assert _inside(sp, by["serving::step"]), phase
-    prefills = [sp for n, v in by.items()
-                if n.startswith("serving::prefill_b") for sp in v]
-    assert len(prefills) == 3
-    for sp in prefills:
+    assert len(by["serving::prefill"]) == len(by["serving::adopt"]) == 3
+    for sp in by["serving::prefill"] + by["serving::adopt"] \
+            + by["serving::settle"]:
         assert _inside(sp, by["serving::admit"])
+    # the blocking read is a span of its own, inside the launch's span
+    # or inside the settle an admission waits for
+    for sp in by["serving::read"]:
+        assert _inside(sp, by["serving::decode_step"]
+                       + by["serving::settle"])
+    for sp in by["serving::settle"]:
+        assert sum(_inside(r, [sp]) for r in by["serving::read"]) == 1
     for sp in by["frontend::lock_wait"]:
         assert not _inside(sp, by["serving::step"])
     # phases of one step follow one another and do not overlap
@@ -160,6 +170,35 @@ def test_span_keywords_become_event_stats(traced):
     for name in ("serving::step", "serving::decode_step"):
         got = sorted(st["step"] for n, _, _, st in spans if n == name)
         assert got == list(range(steps)), name
+    # a read carries the number of the step it reads, which that step's
+    # launch carried: every read pairs with one launch before it
+    launched = {st["step"]: end for n, _, end, st in spans
+                if n == "serving::decode_step"}
+    reads = [(st["step"], start) for n, start, _, st in spans
+             if n == "serving::read"]
+    assert len({step for step, _ in reads}) == len(reads)
+    for step, start in reads:
+        assert launched[step] <= start
+    # what varies from admission to admission: the bucket and the request
+    for name in ("serving::prefill", "serving::adopt"):
+        stats = [st for n, _, _, st in spans if n == name]
+        assert [st["bucket"] for st in stats] == [8, 8, 8], name
+        assert len({st["rid"] for st in stats}) == 3, name
+    by_rid = {}
+    for n, _, _, st in spans:
+        if n in ("serving::prefill", "serving::adopt"):
+            by_rid.setdefault(st["rid"], set()).add(n)
+    assert all(len(v) == 2 for v in by_rid.values())
+
+
+def test_programs_are_called_by_names_of_their_own(traced):
+    lines, _, _ = traced
+    spans, = [sp for sp in lines.values()
+              if any(n == "serving::step" for n, *_ in sp)]
+    called = {n for n, *_ in spans if n.startswith("PjitFunction(")}
+    assert {"PjitFunction(prefill_body)", "PjitFunction(adopt_body)",
+            "PjitFunction(_decode_body)"} <= called
+    assert "PjitFunction(body)" not in called
 
 
 def test_histogram_counts_follow_from_the_run(traced):

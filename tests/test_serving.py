@@ -261,8 +261,9 @@ def test_metrics_percentiles_and_profiler_export(net):
     prof.stop()
     eng.close()
     assert "serving::itl" not in summary
-    for phase in ("serving::step", "serving::admit", "serving::prefill_b8",
-                  "serving::decode_inputs", "serving::decode_step",
+    for phase in ("serving::step", "serving::admit", "serving::prefill",
+                  "serving::adopt", "serving::decode_inputs",
+                  "serving::decode_step", "serving::read",
                   "serving::emit", "serving::step_tail"):
         assert phase in summary, phase
 

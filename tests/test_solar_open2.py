@@ -731,7 +731,8 @@ def test_programs_of_a_row_state_net_have_names_of_their_own(toy):
     assert sig["rows"][1][0] == [[4, 16, 16], "float32"]
     eng.close()
     llama = paddle.models.LlamaForCausalLM(LlamaConfig.tiny())
-    assert build_prefill_body(llama, False, 0, 1.0).__name__ == "body"
+    assert build_prefill_body(llama, False, 0, 1.0).__name__ == \
+        "prefill_body"
     eng = PagedServingEngine(llama, max_batch_size=2, max_seq_len=32,
                              page_size=8, min_bucket=16)
     assert "adopt_state_body" not in str(eng._adopt_fn(16))
