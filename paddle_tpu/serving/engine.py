@@ -8,8 +8,10 @@ runs UNDER the steps: the loop keeps at most one decode step in flight
 (it launches step n+1 before it reads step n's tokens, see
 ``ServingEngine._decode_once``), so the device goes from one program to
 the next while the host emits, takes the front end's lock and builds
-the next inputs. Only an iteration that admits reads first and
-launches after. Three compiled programs total:
+the next inputs. An admission is two more launches of such an
+iteration: its prefill is the new row's step in flight, and its first
+token is read after the next decode launch, like any other
+(``ServingEngine._seat``). Three compiled programs total:
 
 - **prefill** (one per power-of-two prompt bucket): runs a right-padded
   prompt through the cache path and emits the first token. Bucketing
@@ -18,12 +20,15 @@ launches after. Three compiled programs total:
   overwrites before the mask exposes them.
 - **adopt** (one per bucket): copies a prefill block into a free row of
   the decode slab (``dynamic_update_slice`` at a traced slot index — no
-  per-slot recompiles).
+  per-slot recompiles) and the prefill's first token into the row's
+  place of the tokens the next decode step is fed from the device.
 - **decode step** (exactly one): ``[max_batch]`` tokens at per-row
   positions -> next tokens. Every row sits at its own depth — this is
   what the vector-``pos`` cache path in ``models.llama`` exists for.
   A continuing row's input token is the step before's output, taken
-  on the device; the host supplies it only for a row admitted since.
+  on the device, and a new row's its prefill's; the host supplies it
+  only where it alone has it (a remote prefill's, a speculative
+  round's).
   Free rows ride along as masked garbage (their writes land on slots
   the next adoption overwrites), so admission and retirement NEVER
   trigger a recompile or stall in-flight sequences.
@@ -177,21 +182,26 @@ def build_chunk_prefill_body(net, do_sample, top_k, top_p):
 class _Seq:
     """Host-side state of one running sequence (one slab row)."""
 
-    __slots__ = ("handle", "last_tok", "emitted", "key", "t_tok",
+    __slots__ = ("handle", "first", "last_tok", "emitted", "key", "t_tok",
                  "slo_itl", "slo_e2e")
 
-    def __init__(self, handle, first_tok, key=None, slo_itl=None,
+    def __init__(self, handle, first, key=None, slo_itl=None,
                  slo_e2e=None):
         self.handle = handle
-        self.last_tok = first_tok
+        # the first token while the host has not taken it
+        # (ServingEngine._first_token): what the admission holds (its
+        # prefill's output on the device, or a remote prefill's
+        # integer), the admission's start on engine.clock and the
+        # request's TTFT histogram; None from then on
+        self.first = first
+        self.last_tok = None
         self.emitted = 0  # _append counts (prefill's first token too)
         # the request's base PRNG key (sampling_keys derivation) as a
         # host array — decode steps stack the active rows' keys
         self.key = key
-        # engine.clock when the row's newest token reached the host
-        # (the prefill's first token so far): where its next
-        # inter-token sample starts
-        self.t_tok = handle.first_token_time
+        # engine.clock when the row's newest token reached the host:
+        # where its next inter-token sample starts
+        self.t_tok = None
         # per-SLO-class bound histogram children, resolved ONCE at
         # admission (observability.slo): the decode hot loop observes
         # straight into them — zero per-token label resolution, the
@@ -207,21 +217,30 @@ class _Seq:
 
 
 class _Launched:
-    """One decode program that was launched and whose tokens the host
-    has not read yet. ``seqs`` holds, by slot, the ``_Seq`` OBJECTS it
-    was launched for (None: the row was fed nothing): a row that was
+    """What was launched and the host has not read yet: one decode
+    program and the prefills of the rows admitted since its launch.
+    ``seqs`` holds, by slot, the ``_Seq`` OBJECTS whose next token is
+    on the device (None: the row was fed nothing): a row that was
     finished while its step ran, by a deadline, a shed or an EOS found
     one step late, has its lagged token dropped by identity, also when
-    its slot was admitted again meanwhile. ``nxt`` and ``counted`` are
-    the program's device outputs and die with the read."""
+    its slot was admitted again meanwhile. An admission enters its new
+    ``_Seq`` at its row (over a finished occupant the step was launched
+    for) and its slot into ``admitted``: the prefill is that row's step
+    in flight. ``nxt`` and ``counted`` are the decode program's device
+    outputs and die with the read (None in a record that admissions
+    alone made, when no step was in flight); ``feed`` is what the next
+    launch takes as ``prev``: ``nxt``, with every admitted row's first
+    token written into its place by the adopt program."""
 
-    __slots__ = ("nxt", "counted", "seqs", "step")
+    __slots__ = ("nxt", "counted", "seqs", "step", "feed", "admitted")
 
     def __init__(self, nxt, counted, seqs, step):
         self.nxt = nxt
         self.counted = counted
         self.seqs = seqs
         self.step = step
+        self.feed = nxt
+        self.admitted = []
 
 
 class _RequestPhase(profiler.RecordEvent):
@@ -377,10 +396,16 @@ class ServingEngine:
         # this iteration admitted while other rows were resident: its
         # host_gap sample is a metrics.admit_hold sample too
         self._admit_held = False
-        # the decode step launched and not yet read (_Launched), None
-        # when nothing is: before the first launch, after admission
-        # settled it, under speculation, on an idle engine
+        # what is launched and not yet read (_Launched), None when
+        # nothing is: before the first admission, under speculation,
+        # on an idle engine
         self._in_flight = None
+        # what the adopt program is handed where it has no first token
+        # to place: the tokens to merge into when no step is in flight,
+        # and the token of an adoption that admits no row (a
+        # speculative round's block, a restored page)
+        self._no_feed = jnp.zeros((self.max_batch_size,), jnp.int32)
+        self._no_first = jnp.zeros((1,), jnp.int32)
         self._closed = False
         # runtime lint guard: the whole engine design exists so that
         # admission/retirement NEVER recompile — if compile caches grow
@@ -485,17 +510,23 @@ class ServingEngine:
         return fn
 
     def _adopt_fn(self, bucket):
+        """Copy a prefilled [1, bucket] block into decode row ``slot``
+        and the prefill's ``first`` token ``[1]`` into the row's place
+        of ``feed``, the ``[max_batch]`` tokens the next decode launch
+        takes as ``prev``: the new row enters the batch whole, its
+        token never leaving the device. Returns the slab's arrays and,
+        last, the feed."""
         fn = self._adopt_fns.get(bucket)
         if fn is not None:
             return fn
 
-        def adopt_body(flat_decode, flat_block, slot):
+        def adopt_body(flat_decode, flat_block, slot, feed, first):
             from ..quantization.kv import adopt_into_slab
 
             return [
                 adopt_into_slab(d, b, slot)
                 for d, b in zip(flat_decode, flat_block)
-            ]
+            ] + [jax.lax.dynamic_update_slice(feed, first, (slot,))]
 
         fn = jax.jit(
             adopt_body, donate_argnums=(0,)
@@ -505,6 +536,27 @@ class ServingEngine:
             "serving::adopt", bucket, origin="serving/engine.py"
         )
         return fn
+
+    def _adopt(self, bucket, flat_block, *where, first=None):
+        """Run ``bucket``'s adopt program over the resident KV state:
+        ``flat_block`` lands at ``where`` (``_adopt_fn``'s arguments
+        between the block and the feed, a row's last) and ``first``,
+        an admission's first token (on the device, or a remote
+        prefill's integer), in that row's place of the tokens the next
+        launch is fed. Returns that feed; an adoption that admits no
+        row hands over no token and drops it."""
+        if first is None:
+            first = self._no_first
+        elif not isinstance(first, jax.Array):
+            first = jnp.asarray([first], jnp.int32)
+        fl = self._in_flight
+        out = self._run(
+            ("adopt", bucket), self._adopt_fn(bucket),
+            self._flat, flat_block, *where,
+            self._no_feed if fl is None else fl.feed, first,
+        )
+        self._flat = out[:-1]
+        return out[-1]
 
     def _restore_net_state(self):
         """Put the imperative net back in concrete serving state —
@@ -585,10 +637,7 @@ class ServingEngine:
         same adopt program admission uses, at bucket ``width``
         (positions < ``pos`` came back unchanged; [pos, pos+K] carry
         the verify's writes)."""
-        self._flat = self._run(
-            ("adopt", width), self._adopt_fn(width),
-            self._flat, new_block, jnp.int32(slot),
-        )
+        self._adopt(width, new_block, jnp.int32(slot))
 
     def _spec_rollback(self, slot, new_pos):
         """Drop verify writes past the accepted span (the row's next
@@ -758,10 +807,10 @@ class ServingEngine:
         t_pre = self.clock()
         try:
             # the request's engine.prefill span covers what the phase
-            # does: the prefill, its adoption and the first-token read
+            # does: the launches of the prefill and of its adoption
             with _RequestPhase("prefill", handle, bucket=bucket,
                                span={"mode": "local", "bucket": bucket}):
-                nxt, new_flat = self._run(
+                first, new_flat = self._run(
                     ("prefill", bucket), self._prefill_fn(bucket),
                     self._params, self._buffers, jnp.asarray(ids),
                     jnp.int32(req.prompt_len), _flatten(blk.caches),
@@ -769,11 +818,8 @@ class ServingEngine:
                 )
                 blk.caches = _unflatten(new_flat, self.config)
                 with _RequestPhase("adopt", handle, bucket=bucket):
-                    self._flat = self._run(
-                        ("adopt", bucket), self._adopt_fn(bucket),
-                        self._flat, new_flat, jnp.int32(slot),
-                    )
-                t0 = int(np.asarray(nxt)[0])
+                    feed = self._adopt(bucket, new_flat, jnp.int32(slot),
+                                       first=first)
         except BaseException:
             self._slab.release(slot)
             # the failed call may already have consumed the block's
@@ -782,26 +828,81 @@ class ServingEngine:
             self.pool.discard(blk)
             raise
         self.pool.free(blk)
+        self._seat(slot, handle, first, feed, key, now, t_pre)
+
+    def _seat(self, slot, handle, first, feed, key, now, t_pre):
+        """The end of an admission, whose programs are launched: the
+        request is RUNNING in row ``slot``, and its prefill is that
+        row's step in flight. Its ``first`` token stays on the device:
+        the adopt program wrote it into the row's place of ``feed``,
+        which the next decode launch takes as ``prev``, and the new
+        ``_Seq`` is entered into the record of what is in flight (one
+        is made when nothing is), so that ``_launch_pos`` feeds the row
+        at ``prompt_len`` from the device, or nothing where
+        ``max_new_tokens`` is 1, and ``_decode_once`` reads the token
+        after that launch. Where the device does not have the token (a
+        remote prefill handed an integer) or the host needs it before
+        any launch (speculation proposes from it) it is taken here, and
+        the row is fed from the host like any row that is not in
+        flight: the input decides, no option does."""
+        req = handle.request
         handle.status = RUNNING
         handle.weights_version = self.weights_version
         handle.admit_time = now
         handle.admitted_step = self.step_count
-        handle.first_token_time = self.clock()
         wait = now - handle.submit_time
         tid = None if handle.trace is None else handle.trace.trace_id
         self.metrics.admitted.inc()
         self.metrics.prefill_tokens.inc(req.prompt_len)
         self.metrics.queue_wait.observe(wait, trace_id=tid)
-        self.metrics.prefill.observe(handle.first_token_time - t_pre)
         slo_ttft, slo_itl, slo_e2e = self.metrics.slo_children(
             req.slo_class
         )
-        slo_ttft.observe(handle.first_token_time - handle.submit_time,
-                         trace_id=tid)
         self._trace_admitted(handle, slot, wait)
-        self._seqs[slot] = _Seq(handle, t0, key=np.asarray(key),
-                                slo_itl=slo_itl, slo_e2e=slo_e2e)
-        self._append(slot, t0)
+        seq = self._seqs[slot] = _Seq(
+            handle, (first, t_pre, slo_ttft), key=np.asarray(key),
+            slo_itl=slo_itl, slo_e2e=slo_e2e)
+        if self.speculative is not None or not isinstance(first, jax.Array):
+            self._first_token(slot, seq)
+            return
+        fl = self._in_flight
+        if fl is None:
+            fl = self._in_flight = _Launched(
+                None, None, [None] * self.max_batch_size, self.step_count)
+        fl.feed = feed
+        fl.seqs[slot] = seq
+        fl.admitted.append(slot)
+
+    def _first_token(self, slot, seq, in_flight=False):
+        """Take row ``slot``'s first token to the host and emit it: the
+        far end of the ``prefill`` and TTFT samples. ``in_flight``: the
+        read waits for the prefill program, with the next decode step
+        queued behind it, and what the host does from its return to
+        the next launch is that launch's ``host_gap``. What the
+        prefill or its adoption raised on the device surfaces only
+        here: the request ends as an admission that raised at its
+        launch does (``admission_error``), its row and pages go back,
+        and the other rows go on."""
+        first, t_pre, slo_ttft = seq.first
+        seq.first = None
+        h = seq.handle
+        try:
+            with profiler.RecordEvent("serving::first_token",
+                                      rid=h.request.request_id):
+                tok = int(np.asarray(first).reshape(-1)[0])
+        except Exception as e:
+            self.metrics.rejected.inc(label="admission_error")
+            self._finish(slot, REJECTED,
+                         reason=f"admission_error:{type(e).__name__}")
+            return
+        now = h.first_token_time = seq.t_tok = self.clock()
+        if in_flight:
+            self._read_done = now
+        self.metrics.prefill.observe(now - t_pre)
+        slo_ttft.observe(
+            now - h.submit_time,
+            trace_id=None if h.trace is None else h.trace.trace_id)
+        self._append(slot, tok)
 
     def _decode_extra(self, fed):
         """Extra positional decode-step inputs between the KV state and
@@ -840,19 +941,21 @@ class ServingEngine:
         return None
 
     def step(self):
-        """One engine iteration: retire expired, admit into free slots,
-        launch one decode step over the whole resident KV state and
-        read the one launched before it. Each phase is a
-        ``RecordEvent`` span with a fixed name (a phase's own time is
-        its span less the spans inside it; what varies, the bucket, the
-        request and the step, is a stat): ``serving::admit`` (with
-        ``serving::settle``, the read of the step in flight that an
-        admission waits for, that step's ``serving::emit`` and the
-        admission's ``gather``, ``prefill``, ``chunk_prefill`` and
-        ``adopt`` inside it), ``serving::decode_inputs``,
+        """One engine iteration: retire expired, admit into free slots
+        (launches alone, nothing is read for it), launch one decode
+        step over the whole resident KV state, read the one launched
+        before it and then the first tokens of the rows just admitted.
+        Each phase is a ``RecordEvent`` span with a fixed name (a
+        phase's own time is its span less the spans inside it; what
+        varies, the bucket, the request and the step, is a stat):
+        ``serving::admit`` (with the admission's ``gather``,
+        ``prefill``, ``chunk_prefill`` and ``adopt`` inside it, each
+        the launch of its program), ``serving::decode_inputs``,
         ``serving::decode_step`` (own time: the launch of step n+1
         alone; ``serving::read``, the blocking read of step n, is
         inside it), ``serving::emit`` (step n's tokens),
+        ``serving::first_token`` (the blocking read of an admitted
+        row's first token: the wait for its prefill program),
         ``serving::step_tail``."""
         if self._closed:
             raise RuntimeError(f"{type(self).__name__} is closed")
@@ -889,7 +992,11 @@ class ServingEngine:
     def _admit(self):
         """Admission: fill free capacity in priority-FIFO order under
         the in-flight token cap (and the per-step prefill cap, when
-        set)."""
+        set), against the host's state as it stands with a step in
+        flight: nothing is read first. That state lags the device by
+        at most that step and only to the safe side: a row that ends
+        in the step in flight holds its slot, its pages and its share
+        of the token budget for one more iteration."""
         cap = self._max_admissions_per_step()
         admitted = 0
         self._admit_held = False
@@ -899,11 +1006,6 @@ class ServingEngine:
         while self._pending_swap is None and self._has_capacity() and (
             cap is None or admitted < cap
         ):
-            if self._in_flight is not None and self.scheduler.depth:
-                # admission sees settled state: slots, pages and the
-                # budget as they are with every launched token read,
-                # and no ready token waits behind a prefill
-                self._settle()
             handle = self.scheduler.pop_next(self._admission_budget(),
                                              fits=self._admission_fits())
             if handle is None:
@@ -932,10 +1034,11 @@ class ServingEngine:
     def _launch_pos(self, slot):
         """The cache position the next decode launch feeds row ``slot``
         at: its host position, one further while its token of the step
-        in flight is unread. None where the launch feeds nothing: a
-        free row, or a row whose unread token is its last by
-        ``max_new_tokens`` (known without seeing it, so a length-bound
-        request never runs a step too many)."""
+        in flight is unread, be that step a decode step or, for a row
+        just admitted, its prefill (``prompt_len``). None where the
+        launch feeds nothing: a free row, or a row whose unread token
+        is its last by ``max_new_tokens`` (known without seeing it, so
+        a length-bound request never runs a step too many)."""
         seq = self._seqs[slot]
         if seq is None:
             return None
@@ -960,12 +1063,22 @@ class ServingEngine:
         A row whose unread token is its last is fed nothing; a row that
         ends on EOS is found one step late, and its extra step (the EOS
         token's true KV at the next position of its own pages) is
-        dropped with it. When nothing is in flight (the first step, an
-        iteration that admitted, see ``_admit``) there is nothing to
-        read and the launch is all that happens. Free and unfed rows
-        ride along as masked garbage (their writes land on slots
-        adoption overwrites). Speculation runs its own rounds and never
-        has a step in flight."""
+        dropped with it. Free and unfed rows ride along as masked
+        garbage (their writes land on slots adoption overwrites).
+
+        An iteration that admitted is the same iteration with two more
+        programs queued: step n+1 is launched behind step n, the
+        prefill and its adoption, and takes the new row's first token
+        from the adopt program's ``feed``; step n is read from ITS OWN
+        output, which no prefill stands before, and emitted; then the
+        first token of each row admitted is read (``_first_token``),
+        which waits for the prefill with step n+1 queued behind it. A
+        resident stream's gap across an admission is the prefill, the
+        adoption and one decode program. When no decode step is in
+        flight (the first admission of a busy stretch) there is none to
+        read and the order is the serial one: launch, then the first
+        token. Speculation runs its own rounds and never has a step in
+        flight."""
         if not self.active_slots:
             return
         if self.speculative is not None:
@@ -1000,7 +1113,7 @@ class ServingEngine:
                     tok, self._flat, *self._decode_extra(fed),
                     jnp.asarray(pos), jnp.float32(self.temperature),
                     jnp.asarray(keys),
-                    tok if prev is None else prev.nxt,
+                    tok if prev is None else prev.feed,
                     jnp.asarray(from_host),
                 )
         with profiler.RecordEvent("serving::decode_step",
@@ -1021,7 +1134,7 @@ class ServingEngine:
                 )
                 self._in_flight = _Launched(nxt, counted, fed,
                                             self.step_count)
-                if prev is not None:
+                if prev is not None and prev.nxt is not None:
                     self.metrics.steps_overlapped.inc()
                 # the uploads die here, with the launch, as temporaries
                 # would: freeing a device array lets go of the GIL, and
@@ -1030,9 +1143,17 @@ class ServingEngine:
                 # outputs live in the record alone, until their read
                 del inputs, tok, nxt, counted
             if prev is not None:
-                toks = self._read(prev)
-        if prev is not None:
+                # launched from: it dies here too, as the uploads did
+                prev.feed = None
+                toks = None if prev.nxt is None else self._read(prev)
+        if prev is None:
+            return
+        if toks is not None:
             self._emit(prev, toks)
+        for slot in prev.admitted:
+            seq = prev.seqs[slot]
+            if self._seqs[slot] is seq:     # not shed since
+                self._first_token(slot, seq, in_flight=True)
 
     def _read(self, launched):
         """Block until a launched step's tokens are on the host: the
@@ -1057,7 +1178,8 @@ class ServingEngine:
         sample (read returned to read returned; from its first token's
         time for a row's first decode token), ``_append``, ``on_token``.
         A row that is no longer the one the step was launched for gets
-        nothing."""
+        nothing, and a row admitted since the launch has no token in
+        it (``_first_token`` brings its own)."""
         now = self._read_done
         with profiler.RecordEvent("serving::emit"):
             # sampled-out runs never look for a span: this one integer
@@ -1065,7 +1187,8 @@ class ServingEngine:
             traced = self._traced_live
             occ = traced and sum(seq is not None for seq in launched.seqs)
             for i, seq in enumerate(launched.seqs):
-                if seq is None or self._seqs[i] is not seq:
+                if seq is None or self._seqs[i] is not seq \
+                        or seq.first is not None:
                     continue
                 dt = now - seq.t_tok
                 seq.t_tok = now
@@ -1080,20 +1203,6 @@ class ServingEngine:
                 # resolution (and no allocation) on this per-token path
                 (seq.slo_itl or self.metrics.itl).observe(dt)
                 self._append(i, toks[i])
-
-    def _settle(self):
-        """Read and emit the step in flight and leave nothing in
-        flight: what an admission waits for. That is enough for the
-        arrays a net keeps a ROW too: the step in flight was launched
-        with the row free (or for an occupant that has since finished),
-        so whatever it writes into the row's state it writes BEFORE the
-        adopt program, which takes that step's output arrays as its
-        input and overwrites the row; and no step launched after the
-        adoption feeds the row anything but its new occupant's tokens."""
-        launched, self._in_flight = self._in_flight, None
-        with profiler.RecordEvent("serving::settle", step=launched.step):
-            toks = self._read(launched)
-        self._emit(launched, toks)
 
     def run_until_idle(self, max_steps=100_000):
         """Drive ``step()`` until queue and slab are empty."""
@@ -1268,7 +1377,8 @@ class ServingEngine:
         )
 
     def _adopt_example_args(self, flat_block, bucket):
-        return (self._flat, flat_block, jnp.int32(0))
+        return (self._flat, flat_block, jnp.int32(0), self._no_feed,
+                self._no_first)
 
     def _program_signature(self, name):
         cfg = self.config

@@ -155,15 +155,18 @@ class ServingMetrics:
             help="driver thread, per decode step: return of the last "
                  "blocking read to the next launch of the decode "
                  "program: the host's work a step, which runs under "
-                 "the program launched before that read (an idle "
+                 "the program launched before that read (an admitted "
+                 "row's first-token read is such a read; an idle "
                  "engine starts no sample, nor does a launch that no "
                  "read came before)")
         self.admit_hold = Histogram(
             "admit_hold", prom_name=f"{ns}_admit_hold_seconds",
             help="driver thread, per iteration that admitted a request "
                  "while other rows were resident: that iteration's "
-                 "host_gap sample, which is how long every resident "
-                 "stream waited for the admission beyond a decode step")
+                 "host_gap sample, the host's share of an admission "
+                 "(pop, claims, uploads, the launches of prefill and "
+                 "adopt), which runs under the step in flight: "
+                 "nothing is read inside it")
         self.read_wait = Histogram(
             "read_wait", prom_name=f"{ns}_read_wait_seconds",
             help="driver thread, per decode step read: how long the "
@@ -175,12 +178,14 @@ class ServingMetrics:
             prom_name=f"{ns}_steps_overlapped_total",
             help="decode programs launched while the step before was "
                  "still unread (the host's work for it ran under a "
-                 "program); the rest followed an admission, an idle "
-                 "engine or a speculative round")
+                 "program), the launch after an admission too; the "
+                 "rest followed an idle engine or a speculative round")
         self.prefill = Histogram(
             "prefill", prom_name=f"{ns}_prefill_seconds",
-            help="per admission: gather, prefill, the blocking "
-                 "first-token read and the adopt")
+            help="per admission: its start to its first token on the "
+                 "host, which is read after the next decode launch: "
+                 "gather, prefill, adopt and what was left of the "
+                 "step in flight before them")
         self.submit_wait = Histogram(
             "submit_wait", prom_name=f"{ns}_submit_wait_seconds",
             help="per front-end request: received to engine.submit "

@@ -19,12 +19,15 @@ recompiles — the slab engine's core discipline carries over):
 - **adopt-pages** (per bucket): scatters the prefilled ``[1, bucket]``
   block into the arena as ``bucket / page_size`` whole pages at
   table-supplied ids (tail ids past the request's claim point at the
-  garbage page 0 — no shape variance, no recompiles). Over a net that
-  keeps a state a ROW beside its pages (``generation.row_layout``: a
-  recurrent layer) the block also carries the prompt's final state,
-  and the program, ``adopt_state_body``, copies it into the decode
-  row: two kinds of cache in one manager, arrays addressed by token
-  through the page table and arrays addressed by row.
+  garbage page 0 — no shape variance, no recompiles), and writes the
+  prefill's first token into the decode row's place of the tokens the
+  next decode step is fed from the device (the base engine's
+  ``_seat``). Over a net that keeps a state a ROW beside its pages
+  (``generation.row_layout``: a recurrent layer) the block also
+  carries the prompt's final state, and the program,
+  ``adopt_state_body``, copies it into the decode row: two kinds of
+  cache in one manager, arrays addressed by token through the page
+  table and arrays addressed by row.
 - **decode step** (exactly one): ``[B]`` tokens + the ``[B, P_max]``
   page table -> next tokens; attention gathers K/V through the table,
   and only as far as the batch's longest row reaches: the program
@@ -87,14 +90,13 @@ from ..quantization import kv as qkv
 from .engine import (
     ServingEngine,
     _RequestPhase,
-    _Seq,
     _flatten,
     _unflatten,
     build_chunk_prefill_body,
     step_counters,
 )
 from .paged_pool import PagedKVPool, PagesExhausted
-from .scheduler import CANCELLED, REASON_PAGES_EXHAUSTED, RUNNING
+from .scheduler import CANCELLED, REASON_PAGES_EXHAUSTED
 
 
 class PagedServingEngine(ServingEngine):
@@ -485,10 +487,12 @@ class PagedServingEngine(ServingEngine):
         ``bucket / page_size`` whole pages at traced page ids — one
         program per bucket, ids beyond the request's claim point at the
         garbage page 0 (duplicate scatter indices there are fine: the
-        page is garbage by contract). Over a net that keeps a state a
-        row the program (``adopt_state_body``) also takes the decode
-        ``row`` and copies the block's row arrays, the prompt's final
-        state, into it."""
+        page is garbage by contract), and the prefill's ``first`` token
+        into decode ``row``'s place of ``feed`` (the base engine's
+        ``_adopt_fn``). Over a net that keeps a state a row the program
+        (``adopt_state_body``) also copies the block's row arrays, the
+        prompt's final state, into that row. Returns the arena's arrays
+        and, last, the feed."""
         fn = self._adopt_fns.get(bucket)
         if fn is not None:
             return fn
@@ -496,14 +500,14 @@ class PagedServingEngine(ServingEngine):
         n_pages_b = bucket // ps
         by_row = self._row_arrays
 
-        def adopt_body(flat_arena, flat_block, page_ids, *row):
+        def adopt_body(flat_arena, flat_block, page_ids, row, feed, first):
             from ..quantization.kv import adopt_into_pages, adopt_into_slab
 
             return [
-                adopt_into_slab(a, b, *row) if is_row
+                adopt_into_slab(a, b, row) if is_row
                 else adopt_into_pages(a, b, page_ids, n_pages_b, ps)
                 for a, b, is_row in zip(flat_arena, flat_block, by_row)
-            ]
+            ] + [jax.lax.dynamic_update_slice(feed, first, (row,))]
 
         if any(by_row):
             adopt_body.__name__ = adopt_body.__qualname__ = \
@@ -568,16 +572,9 @@ class PagedServingEngine(ServingEngine):
     def _adopt_example_args(self, flat_block, bucket):
         return (
             self._flat, flat_block,
-            *self._adopt_where(
-                np.zeros((bucket // self.page_size,), np.int32), 0),
+            jnp.zeros((bucket // self.page_size,), jnp.int32),
+            jnp.int32(0), self._no_feed, self._no_first,
         )
-
-    def _adopt_where(self, page_ids, row):
-        """Where an adoption lands, as the adopt program takes it: the
-        page ids, and the decode row where the net keeps a state a
-        row."""
-        where = (jnp.asarray(page_ids),)
-        return where + (jnp.int32(row),) if any(self._row_arrays) else where
 
     def _program_signature(self, name):
         sig = super()._program_signature(name)
@@ -690,11 +687,8 @@ class PagedServingEngine(ServingEngine):
             return None
         ps = self.page_size
         with profiler.RecordEvent("serving::restore_adopt", bucket=ps):
-            self._flat = self._run(
-                ("adopt", ps), self._adopt_fn(ps),
-                self._flat, self._page_block(arrays),
-                jnp.asarray(page, jnp.int32),
-            )
+            self._adopt(ps, self._page_block(arrays),
+                        jnp.asarray(page, jnp.int32), jnp.int32(0))
         return page[0]
 
     # ------------------------------------------- speculative backend seams
@@ -770,10 +764,8 @@ class PagedServingEngine(ServingEngine):
         lo = pos // ps
         n = min(len(pages), width // ps)
         page_ids[lo:n] = pages[lo:n]
-        self._flat = self._run(
-            ("adopt", width), self._adopt_fn(width),
-            self._flat, new_block, jnp.asarray(page_ids),
-        )
+        self._adopt(width, new_block, jnp.asarray(page_ids),
+                    jnp.int32(slot))
 
     def _spec_rollback(self, slot, new_pos):
         """Release the rejected tail's demand-claimed pages (anything
@@ -815,8 +807,9 @@ class PagedServingEngine(ServingEngine):
 
     def _remote_prefill(self, req, bucket, key, trace=None):
         """Try the attached prefill pool: ``(first_token, flat_block)``
-        on success, None when the transport is absent/down/failing (the
-        caller runs local prefill — clean fallback, counted).
+        (the token a host integer) on success, None when the transport
+        is absent/down/failing (the caller runs local prefill — clean
+        fallback, counted).
         ``trace`` is the admission's prefill span: the transport
         parents its wire span (and the worker's remote span) under
         it."""
@@ -927,14 +920,13 @@ class PagedServingEngine(ServingEngine):
                 self.chunk_prefills += 1
                 with _RequestPhase("chunk_prefill", handle, bucket=bucket,
                                    tail=tb):
-                    nxt, new_flat = self._run(
+                    first, new_flat = self._run(
                         ("chunk", bucket, tb),
                         self._chunk_fn(bucket, tb),
                         self._params, self._buffers, jnp.asarray(tail),
                         jnp.int32(L), jnp.int32(c), flat_block,
                         jnp.float32(self.temperature), key,
                     )
-                    t0 = int(np.asarray(nxt)[0])
                 if c % ps:
                     # recompute boundary inside a cached page: its
                     # content was cloned through the gather into a
@@ -944,18 +936,18 @@ class PagedServingEngine(ServingEngine):
             elif remote is None:
                 self.local_prefills += 1
                 with _RequestPhase("prefill", handle, bucket=bucket):
-                    nxt, new_flat = self._run(
+                    first, new_flat = self._run(
                         ("prefill", bucket), self._prefill_fn(bucket),
                         self._params, self._buffers, jnp.asarray(ids),
                         jnp.int32(req.prompt_len), _flatten(blk.caches),
                         jnp.float32(self.temperature), key,
                     )
                     blk.caches = _unflatten(new_flat, self.config)
-                    t0 = int(np.asarray(nxt)[0])
             else:
                 # the prefill pool already ran the bucket program; the
-                # wire block adopts through the SAME compiled scatter
-                t0, new_flat = remote
+                # wire block adopts through the SAME compiled scatter,
+                # and its first token is the host's already
+                first, new_flat = remote
             if psp is not None:
                 psp.finish()
             with _RequestPhase("adopt", handle, bucket=bucket,
@@ -967,11 +959,8 @@ class PagedServingEngine(ServingEngine):
                 page_ids = np.zeros((bucket // ps,), np.int32)
                 k1 = min(n_init, bucket // ps)
                 page_ids[n_ref:k1] = row_pages[n_ref:k1]
-                self._flat = self._run(
-                    ("adopt", bucket), self._adopt_fn(bucket),
-                    self._flat, new_flat,
-                    *self._adopt_where(page_ids, row),
-                )
+                feed = self._adopt(bucket, new_flat, jnp.asarray(page_ids),
+                                   jnp.int32(row), first=first)
             if self.prefix_cache is not None:
                 # publish-on-admission: full prompt pages are stable
                 # the moment prefill wrote them (decode writes start at
@@ -998,26 +987,7 @@ class PagedServingEngine(ServingEngine):
         self._row_meta[row] = (
             tuple(int(t) for t in req.input_ids), req.prompt_len
         )
-        handle.status = RUNNING
-        handle.weights_version = self.weights_version
-        handle.admit_time = now
-        handle.admitted_step = self.step_count
-        handle.first_token_time = self.clock()
-        wait = now - handle.submit_time
-        tid = None if handle.trace is None else handle.trace.trace_id
-        self.metrics.admitted.inc()
-        self.metrics.prefill_tokens.inc(req.prompt_len)
-        self.metrics.queue_wait.observe(wait, trace_id=tid)
-        self.metrics.prefill.observe(handle.first_token_time - t_pre)
-        slo_ttft, slo_itl, slo_e2e = self.metrics.slo_children(
-            req.slo_class
-        )
-        slo_ttft.observe(handle.first_token_time - handle.submit_time,
-                         trace_id=tid)
-        self._trace_admitted(handle, row, wait)
-        self._seqs[row] = _Seq(handle, t0, key=np.asarray(key),
-                               slo_itl=slo_itl, slo_e2e=slo_e2e)
-        self._append(row, t0)
+        self._seat(row, handle, first, feed, key, now, t_pre)
 
     # ------------------------------------------------------- AOT warmup
     def warmup(self, aot_cache=None, buckets=None):
@@ -1078,8 +1048,7 @@ class PagedServingEngine(ServingEngine):
                 self._warm_one(
                     cache, f"adopt_b{ps}", ("adopt", ps),
                     self._adopt_fn(ps),
-                    (self._flat, self._page_block(),
-                     jnp.zeros((1,), jnp.int32)),
+                    self._adopt_example_args(self._page_block(), ps),
                     lambda comp: self._adopt_fns
                     .__setitem__(ps, comp), stats,
                     donate=(0,),

@@ -34,6 +34,8 @@ IDENTICAL sampled streams for the same seed and submission order.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 # speculative purpose folds (any distinct constants; folded below the
 # position key so the undecorated position key IS the vanilla stream)
@@ -49,16 +51,31 @@ class SamplingKeySource:
     per ``_admit_one``, in admission order — the same order on every
     engine geometry for a fixed workload (the scheduler is strict
     priority-FIFO), which is what makes sampled streams comparable
-    across backends."""
+    across backends.
+
+    A key is handed out as a host array: the engine stacks the rows'
+    keys on the host a decode launch, and an admission must not wait
+    for the device, which is busy with the step in flight. So the
+    keys are derived ``_AHEAD`` admissions at a time and read once."""
+
+    _AHEAD = 64
 
     def __init__(self, seed):
         self._master = jax.random.PRNGKey(int(seed))
         self.next_index = 0
+        self._first = 0         # the admission index of _keys[0]
+        self._keys = ()
 
     def next_request_key(self):
-        key = jax.random.fold_in(self._master, self.next_index)
+        i = self.next_index - self._first
+        if not 0 <= i < len(self._keys):
+            self._first, i = self.next_index, 0
+            self._keys = np.asarray(jax.vmap(
+                jax.random.fold_in, (None, 0))(
+                    self._master,
+                    self._first + jnp.arange(self._AHEAD, dtype=jnp.uint32)))
         self.next_index += 1
-        return key
+        return self._keys[i]
 
 
 def position_key(request_key, position):

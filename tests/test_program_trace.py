@@ -35,6 +35,7 @@ DRIVER_SPANS = (
     "serving::grow_pages", "serving::decode_inputs",
     "serving::decode_step", "serving::read", "serving::emit",
     "serving::step_tail", "serving::prefill", "serving::adopt",
+    "serving::first_token",
 )
 
 
@@ -142,16 +143,24 @@ def test_phases_nest_inside_the_step(traced):
         for sp in by[phase]:
             assert _inside(sp, by["serving::step"]), phase
     assert len(by["serving::prefill"]) == len(by["serving::adopt"]) == 3
-    for sp in by["serving::prefill"] + by["serving::adopt"] \
-            + by["serving::settle"]:
+    for sp in by["serving::prefill"] + by["serving::adopt"]:
         assert _inside(sp, by["serving::admit"])
-    # the blocking read is a span of its own, inside the launch's span
-    # or inside the settle an admission waits for
+    # the blocking read is a span of its own, inside the launch's span:
+    # an admission waits for none, and nothing but launches lies in it
+    assert "serving::settle" not in by
     for sp in by["serving::read"]:
-        assert _inside(sp, by["serving::decode_step"]
-                       + by["serving::settle"])
-    for sp in by["serving::settle"]:
-        assert sum(_inside(r, [sp]) for r in by["serving::read"]) == 1
+        assert _inside(sp, by["serving::decode_step"])
+        assert not _inside(sp, by["serving::admit"])
+    # an admitted row's first token is read after the iteration's
+    # launch and the read of the step before
+    assert len(by["serving::first_token"]) == 3
+    for sp in by["serving::first_token"]:
+        assert _inside(sp, by["serving::step"])
+        assert not _inside(sp, by["serving::admit"]
+                           + by["serving::decode_step"])
+        assert any(d[2] <= sp[1] and _inside(d, [s for s in
+                   by["serving::step"] if _inside(sp, [s])])
+                   for d in by["serving::decode_step"])
     for sp in by["frontend::lock_wait"]:
         assert not _inside(sp, by["serving::step"])
     # phases of one step follow one another and do not overlap
@@ -207,15 +216,15 @@ def test_histogram_counts_follow_from_the_run(traced):
     assert rep["prefill"]["count"] == 3           # one an admission
     assert rep["counters"]["admitted"] == 3
     # every launched step is read once (no request ends on EOS, so
-    # none is dropped); a launch starts a host_gap sample when a read
-    # returned since the launch before it: not the first two of a busy
-    # stretch, nor the one after an admission's launch, and a row's
+    # none is dropped); a launch starts a host_gap sample when a
+    # blocking read, a step's or a first token's, returned since the
+    # launch before it: not the first of a busy stretch, and a row's
     # last step launches nothing (the exact count is pinned in
     # test_no_host_gap_sample_across_an_idle_engine)
     assert rep["slot_occupancy"]["count"] == steps
     launches = rep["resident_tokens"]["count"]
     assert rep["read_wait"]["count"] == launches < steps
-    assert 1 <= rep["host_gap"]["count"] <= launches - 2
+    assert 1 <= rep["host_gap"]["count"] <= launches - 1
     assert 1 <= rep["counters"]["steps_overlapped"] <= launches - 1
     for name in ("host_gap", "read_wait", "prefill", "submit_wait"):
         assert rep[name]["sum"] > 0.0
@@ -229,18 +238,18 @@ def test_no_host_gap_sample_across_an_idle_engine(net):
     eng.generate([np.arange(1, 6)[None]], max_new_tokens=4)
     first = eng.step_count
     # four tokens: the prefill's and three decode launches, of which
-    # the third alone follows a read (the second is launched before
-    # the first is read); the fourth step reads the last and launches
-    # nothing
+    # the first alone follows no read (the first token is read behind
+    # it, the first step behind the second); the fourth step reads the
+    # last and launches nothing
     assert first == 4
-    assert eng.metrics.host_gap.count == 1
+    assert eng.metrics.host_gap.count == 2
     assert eng._read_done is None     # the last row left: idle
     assert eng._in_flight is None
     for _ in range(50):               # the clock runs on meanwhile
         eng.clock()
     eng.generate([np.arange(1, 8)[None]], max_new_tokens=4)
     assert eng.step_count - first == 4
-    assert eng.metrics.host_gap.count == 2
+    assert eng.metrics.host_gap.count == 4
     # on the engine's clock, and never the idle stretch in between
     assert eng.metrics.host_gap.snapshot()["max"] < 20
     eng.close()

@@ -646,14 +646,25 @@ def _lag_engine(kind, net, **kw):
     return PagedServingEngine(net, **kw)
 
 
+def _settle(eng):
+    """Read and emit the decode step in flight and leave nothing in
+    flight (what an admission waited for until it became the new row's
+    step in flight itself)."""
+    launched, eng._in_flight = eng._in_flight, None
+    if launched is not None:
+        assert not launched.admitted     # read in their own iteration
+        eng._emit(launched, eng._read(launched))
+
+
 def _drive_serially(eng):
-    """The engine's order before it kept a step in flight: every
-    launched step is read before the next launch, so every input token
-    visits the host (``from_host`` on all rows, nothing overlapped)."""
+    """The serial order: every launched decode step is read before the
+    next launch, so every continuing row's token visits the host
+    (``from_host``) and no launch is overlapped. An admission then
+    finds nothing in flight, and its first token still reaches its
+    first step on the device."""
     while eng.scheduler.depth or eng.active_slots:
         eng.step()
-        if eng._in_flight is not None:
-            eng._settle()
+        _settle(eng)
 
 
 def _ref(net, prompt, max_new):
@@ -663,15 +674,20 @@ def _ref(net, prompt, max_new):
 
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "sampled"])
-@pytest.mark.parametrize("kind", ["slab", "paged", "paged-demand"])
+@pytest.mark.parametrize(
+    "kind", ["slab", "paged", "paged-demand", "paged-prefix"])
 def test_lagged_streams_equal_serial_order_and_generate(net, kind,
                                                         sampled):
     """Rows of unequal ``max_new_tokens`` (finishes fall on different
-    steps, slots turn over mid-run) through the loop that launches
-    step n+1 before it reads step n: token for token what the serial
-    order gives (each continuing row's token fed from the device, not
-    from the host), greedy equal to ``generate()``; sampled too,
-    because keys are addressed by position."""
+    steps, slots turn over mid-run, every later request is admitted
+    with a step in flight) through the loop that launches step n+1
+    before it reads step n and reads no first token at admission:
+    token for token what the serial order gives (each continuing
+    row's token and each new row's first fed from the device, not from
+    the host), greedy equal to ``generate()``; sampled too, because
+    keys are addressed by position. ``paged-prefix``: the prompts share
+    a head of two pages, so all but the first are admitted warm, their
+    first token the chunked prefill's."""
     kw = dict(max_batch_size=3)
     if sampled:
         kw.update(do_sample=True, temperature=0.8, top_k=8, seed=11)
@@ -679,6 +695,10 @@ def test_lagged_streams_equal_serial_order_and_generate(net, kind,
         kw.update(demand_paging=True)
     prompts = [RNG.randint(0, 64, (1, L)) for L in (6, 5, 7, 9, 4, 8)]
     max_news = [3, 9, 6, 8, 1, 12]
+    if kind == "paged-prefix":
+        kw.update(prefix_cache=True)
+        head = RNG.randint(0, 64, (1, 16))
+        prompts = [np.concatenate([head, p], 1) for p in prompts]
 
     def run(drive):
         eng = _lag_engine(kind.split("-")[0], net, **kw)
@@ -686,7 +706,9 @@ def test_lagged_streams_equal_serial_order_and_generate(net, kind,
         drive(eng)
         assert eng._in_flight is None and eng.active_slots == 0
         assert eng.pool.occupancy == 0
-        if kind != "slab":
+        if kind == "paged-prefix":
+            assert eng.chunk_prefills == len(prompts) - 1
+        elif kind != "slab":
             assert eng.page_pool.pages_in_use == 0
         rep = eng.metrics.report()
         eng.close()
@@ -694,8 +716,14 @@ def test_lagged_streams_equal_serial_order_and_generate(net, kind,
 
     lagged, rep = run(lambda eng: eng.run_until_idle())
     serial, rep_s = run(_drive_serially)
-    assert rep["counters"]["steps_overlapped"] > 0
+    # the slots turn over and the engine never drains: every launch but
+    # the first was made with a step in flight, admissions or none
+    assert rep["counters"]["steps_overlapped"] \
+        == rep["resident_tokens"]["count"] - 1
     assert rep_s["counters"]["steps_overlapped"] == 0
+    # an admission a request, each timed to its first token's read
+    assert rep["prefill"]["count"] == rep_s["prefill"]["count"] \
+        == len(prompts)
     # the same work (the positions fed, summed over all launches): no
     # row runs a step more for its last token being known late
     assert rep["resident_tokens"]["sum"] == rep_s["resident_tokens"]["sum"]
@@ -707,15 +735,19 @@ def test_lagged_streams_equal_serial_order_and_generate(net, kind,
             np.testing.assert_array_equal(h.output_ids, _ref(net, p, m))
 
 
+@pytest.mark.parametrize("nth", [2, 0], ids=["third", "first"])
 @pytest.mark.parametrize("kind", ["slab", "paged"])
-def test_lagged_eos_extra_step_is_dropped(net, kind):
+def test_lagged_eos_extra_step_is_dropped(net, kind, nth):
     """A row that ends on EOS is found one step late: the step launched
     for it meanwhile is dropped (its token never emitted, its page
     given back), whether another row keeps the engine going or the
-    engine goes idle under it."""
+    engine goes idle under it. ``first``: the EOS is the prefill's own
+    token, which the host sees only after the row's first decode step
+    was launched; beside another row the admission had a step in
+    flight."""
     prompts = [RNG.randint(0, 64, (1, L)) for L in (6, 7)]
     free = [_ref(net, p, 12) for p in prompts]
-    eos = int(free[0][6 + 2])            # row 0's 3rd generated token
+    eos = int(free[0][6 + nth])          # row 0's generated token nth
     stop = list(free[0][6:]).index(eos) + 1
     kw = dict(max_batch_size=2)
     if kind == "paged":
@@ -723,9 +755,13 @@ def test_lagged_eos_extra_step_is_dropped(net, kind):
     for others in (True, False):
         eng = _lag_engine(kind, net, **kw)
         seen = []
+        h1 = eng.submit(prompts[1], 12) if others else None
+        if others and not nth:
+            for _ in range(3):
+                eng.step()
+            assert eng._in_flight.nxt is not None
         h0 = eng.submit(prompts[0], 12, eos_token_id=eos,
                         on_token=lambda t, h: seen.append(int(t)))
-        h1 = eng.submit(prompts[1], 12) if others else None
         eng.run_until_idle()
         assert h0.status == "DONE"
         assert h0.tokens == list(free[0][6:6 + stop]) == seen
@@ -749,8 +785,10 @@ def test_lagged_eos_extra_step_is_dropped(net, kind):
 def test_lagged_token_never_reaches_a_readmitted_slot(net, kind):
     """The step in flight remembers the rows it was launched for by
     identity: a row finished while its step ran (a cancel here), whose
-    slot is admitted again before that step is read, hands the new
-    row nothing of the old one's."""
+    slot is admitted again before that step is read (as every
+    admission is: nothing is read for one), hands the new row nothing
+    of the old one's. The new row takes the old one's place in the
+    record: its prefill is its step in flight."""
     from paddle_tpu.serving.scheduler import CANCELLED
 
     eng = _lag_engine(kind, net, max_batch_size=2)
@@ -764,11 +802,16 @@ def test_lagged_token_never_reaches_a_readmitted_slot(net, kind):
     n_a = len(ha.tokens)
     eng._finish(slot, CANCELLED, reason="client_gone")
     hb = eng.submit(pb, 9)
-    # around _admit, which would read the step first: the slot is taken
-    # again while the old row's token is still on the device
-    eng._admit_one(eng.scheduler.pop_next())
-    assert eng._seqs[slot].handle is hb
-    assert eng._in_flight.seqs[slot].handle is ha
+    launched = eng._in_flight
+    # the slot is taken again while the old row's token is still on
+    # the device, and the new row's first token stays there too
+    eng._admit()
+    assert eng._seqs[slot].handle is hb and hb.tokens == []
+    assert eng._in_flight is launched
+    assert launched.seqs[slot] is eng._seqs[slot]
+    assert launched.admitted == [slot]
+    assert launched.feed is not launched.nxt
+    assert eng._launch_pos(slot) == 5 == eng._seqs[slot].pos + 1
     eng.run_until_idle()
     assert ha.status == "CANCELLED" and len(ha.tokens) == n_a
     np.testing.assert_array_equal(hb.output_ids, _ref(net, pb, 9))
@@ -803,6 +846,114 @@ def test_deadline_timeout_with_a_step_in_flight(net, kind):
     assert eng._in_flight is None and eng.pool.occupancy == 0
     if kind == "paged":
         assert eng.page_pool.pages_in_use == 0
+    eng.close()
+
+
+@pytest.mark.parametrize("kind", ["slab", "paged", "paged-demand"])
+def test_admission_beside_rows_is_an_overlapped_iteration(net, kind):
+    """An iteration that admits beside a resident row reads nothing
+    before its launches: the prefill and the adoption go behind the
+    step in flight, the next decode step behind them (counted as
+    overlapped), that step feeds the new row at ``prompt_len`` from the
+    device, and only then the step before and the first token are
+    read. A request of one token admitted so is fed nothing."""
+    kw = {"demand_paging": True} if kind == "paged-demand" else {}
+    eng = _lag_engine(kind.split("-")[0], net, max_batch_size=3, **kw)
+    m = eng.metrics
+    pa, pb, pc = (RNG.randint(0, 64, (1, L)) for L in (6, 8, 5))
+    ha = eng.submit(pa, 12)
+    for _ in range(3):
+        eng.step()
+    hb, hc = eng.submit(pb, 6), eng.submit(pc, 1)
+    step_n, n_a = eng._in_flight, len(ha.tokens)
+    reads, launches = m.read_wait.count, m.resident_tokens.count
+    overlapped = m.steps_overlapped.value
+    eng._admit()
+    # both admitted (a paged engine built by _lag_engine has no cap),
+    # nothing read: no token of the step in flight, no first token
+    assert hb.status == hc.status == "RUNNING"
+    assert m.read_wait.count == reads and len(ha.tokens) == n_a
+    assert hb.tokens == hc.tokens == [] and hb.first_token_time is None
+    assert m.prefill.count == 1 and m.admitted.value == 3
+    assert eng._in_flight is step_n and step_n.nxt is not None
+    sb, sc = (next(i for i, s in enumerate(eng._seqs)
+                   if s is not None and s.handle is h) for h in (hb, hc))
+    assert sorted(step_n.admitted) == sorted([sb, sc])
+    assert eng._launch_pos(sb) == 8 and eng._launch_pos(sc) is None
+    fed = []
+    decode = eng._decode_fn
+    eng._decode_fn = lambda *a: (fed.append(
+        (np.asarray(a[-1]), np.asarray(a[-2]))), decode(*a))[1]
+    eng._decode_once()
+    eng._decode_fn = decode
+    from_host, prev = fed[0]
+    assert not from_host[sb] and prev[sb] == hb.tokens[0]
+    assert m.resident_tokens.count == launches + 1
+    assert m.steps_overlapped.value == overlapped + 1
+    assert eng._in_flight.seqs[sb].handle is hb
+    assert eng._in_flight.seqs[sc] is None
+    assert len(ha.tokens) == n_a + 1 and len(hb.tokens) == 1
+    assert hc.status == "DONE" and len(hc.tokens) == 1
+    assert m.prefill.count == 3 and hb.first_token_time is not None
+    eng.run_until_idle()
+    for h, p, n in ((ha, pa, 12), (hb, pb, 6), (hc, pc, 1)):
+        np.testing.assert_array_equal(h.output_ids, _ref(net, p, n))
+    assert m.steps_overlapped.value == m.resident_tokens.count - 1
+    assert eng.pool.occupancy == 0
+    eng.close()
+
+
+@pytest.mark.parametrize("how", ["deadline", "shed", "device_error"])
+def test_a_row_ended_before_its_first_token_is_read(net, how):
+    """A row whose first token is still on the device and that a
+    deadline, a page shed or an error of its prefill ends: it ends
+    with no token, its row and pages go back, the step launched
+    meanwhile is dropped for it, and the other row's stream is exact."""
+    from paddle_tpu.serving.scheduler import REASON_PAGES_EXHAUSTED
+
+    t = [0.0]
+    # 2 pages hold the resident row to its end, 2 the new prompt
+    eng = _lag_engine("paged", net, max_batch_size=2, num_pages=4,
+                      demand_paging=True, clock=lambda: t[0])
+    pa = RNG.randint(0, 64, (1, 6))
+    # a prompt of two full pages: its first step writes position 16,
+    # into a third page that the arena does not have
+    pb = RNG.randint(0, 64, (1, 16 if how == "shed" else 12))
+    ha = eng.submit(pa, 9)
+    for _ in range(3):
+        eng.step()
+    hb = eng.submit(pb, 4, deadline_s=5.0)
+    if how == "shed":
+        eng.step()
+        assert hb.status == "CANCELLED"
+        assert hb.reason == REASON_PAGES_EXHAUSTED
+    else:
+        eng._admit()
+        slot = eng._in_flight.admitted[0]
+        seq = eng._seqs[slot]
+        assert seq.handle is hb and seq.first is not None
+        if how == "deadline":
+            t[0] = 10.0
+            eng.step()
+            assert hb.status == "TIMEOUT" and hb.reason == REASON_TIMEOUT
+        else:
+            class Lost:
+                def __array__(self, *a, **k):
+                    raise RuntimeError("device lost")
+
+            seq.first = (Lost(),) + seq.first[1:]
+            eng.step()
+            assert hb.status == "REJECTED"
+            assert hb.reason == "admission_error:RuntimeError"
+            assert eng.metrics.rejected.by_label() == {"admission_error": 1}
+    assert hb.tokens == [] and hb.first_token_time is None
+    assert eng.metrics.prefill.count == 1
+    eng.run_until_idle()
+    np.testing.assert_array_equal(ha.output_ids, _ref(net, pa, 9))
+    assert eng.metrics.tokens_out.value == 9
+    assert eng._in_flight is None and eng.pool.occupancy == 0
+    st = eng.page_pool.stats()
+    assert st["pages_in_use"] == 0 and st["claims"] == st["releases"]
     eng.close()
 
 
@@ -940,8 +1091,9 @@ def test_full_batch_overlaps_every_step_but_the_first(net, kind):
     assert rep["counters"]["steps_overlapped"] / launches > 0.8
     assert rep["read_wait"]["count"] == launches
     assert rep["itl"]["count"] == rows * launches
-    # a sample starts at a read's return: not at the first two launches
-    assert rep["host_gap"]["count"] == launches - 2
+    # a sample starts at a blocking read's return, the first tokens'
+    # too: at every launch but the first
+    assert rep["host_gap"]["count"] == launches - 1
     assert rep["read_wait"]["sum"] >= 0.0
     eng.close()
 
@@ -983,6 +1135,10 @@ def test_speculation_never_has_a_step_in_flight(net):
     while eng.scheduler.depth or eng.active_slots:
         eng.step()
         assert eng._in_flight is None
+        # a round proposes from the row's last token: an admission
+        # (the third beside a resident row) takes its first one at once
+        assert all(s is None or (s.first is None and s.handle.tokens)
+                   for s in eng._seqs)
     assert all(h.status == "DONE" for h in hs)
     rep = eng.metrics.report()
     assert rep["counters"]["steps_overlapped"] == 0

@@ -1,9 +1,11 @@
 """What PR 36 added to the serving loop's instruments, on the CPU: an
 admission beside resident rows leaves one ``admit_hold`` sample, that
-iteration's ``host_gap``; an idle engine leaves none; the blocking read
-is a span of its own, one a ``read_wait`` sample; a phase that serves a
-request names it (``rid``) and keeps what varies out of its name, and
-the request's own spans come from the same calls.
+iteration's ``host_gap`` (since PR 37 the host's work of an iteration
+that admits, under the step in flight: nothing is read inside it); an
+idle engine leaves none; the blocking read is a span of its own, one a
+``read_wait`` sample, and so is an admitted row's first-token read; a
+phase that serves a request names it (``rid``) and keeps what varies
+out of its name, and the request's own spans come from the same calls.
 """
 import numpy as np
 import pytest
@@ -89,21 +91,30 @@ def test_admit_hold_is_the_host_gap_of_an_admission_beside_rows(
     for _ in range(3):
         eng.step()
     # the first admission found no row resident, and no iteration since
-    # admitted: host gaps, no hold
-    assert m.host_gap.count == 1 and m.admit_hold.count == 0
+    # admitted: host gaps (from the first token's read on), no hold
+    assert m.host_gap.count == 2 and m.admit_hold.count == 0
     b = eng.submit(np.arange(1, 8)[None], 4)
     gaps = m.host_gap.sum
-    eng.step()
+    reads = m.read_wait.count
+    eng._admit()
+    # the admission's launches lie inside the gap, and no read does
+    assert m.read_wait.count == reads and m.prefill.count == 1
+    assert m.host_gap.count == 2
+    eng._decode_once()
     assert eng.active_slots == 2
-    assert m.admit_hold.count == 1 and m.host_gap.count == 2
+    assert m.admit_hold.count == 1 and m.host_gap.count == 3
     assert m.admit_hold.sum == m.host_gap.sum - gaps
-    # longer than an ordinary step's gap: the prefill's clock reads lie
-    # inside it
-    assert m.admit_hold.sum > gaps
+    # longer than an ordinary step's gap: the admission's clock reads
+    # lie inside it
+    assert m.admit_hold.sum > gaps / 2
+    # the gap ended at the launch; the step in flight and the first
+    # token were read after it, and the next gap starts at the latter
+    assert m.read_wait.count == reads + 1 and m.prefill.count == 2
+    assert eng._read_done == b.first_token_time
     eng.run_until_idle()
     assert a.status == b.status == "DONE"
     assert m.admit_hold.count == 1 <= m.admitted.value
-    assert m.host_gap.count > 2
+    assert m.host_gap.count > 3
     eng.close()
 
 
@@ -172,11 +183,13 @@ def test_every_read_is_a_span_and_a_read_wait_sample(net, cls, kw):
     m = eng.metrics
     assert host["serving::read"] == m.read_wait.count > 0
     # a step is read at most once (a row's end may drop the last one
-    # unread), under the next launch's span or under a settle: one for
-    # the admission beside a row
+    # unread), under the next launch's span; nothing is read for an
+    # admission, whose first token has a read of its own
     assert host["serving::read"] <= m.resident_tokens.count \
         <= host["serving::decode_step"]
-    assert host["serving::settle"] == m.admit_hold.count == 1
+    assert "serving::settle" not in host and m.admit_hold.count == 1
+    assert host["serving::first_token"] == m.prefill.count \
+        == m.admitted.value == 2
     # one clock read on either side of the span: a tick a read
     assert m.read_wait.sum == m.read_wait.count * eng.clock.tick
     # what varies is no part of a name

@@ -557,25 +557,60 @@ def test_the_shares_add_up_to_the_uncut_layer(toy):
 
 
 # ------------------------------------------------------------ the engines
-@pytest.mark.parametrize("engine_cls", [PagedServingEngine, ServingEngine])
-def test_engines_reproduce_generate_and_the_reference(toy_share, engine_cls):
+def _drive_serially(eng):
+    """Every decode step read before the next launch (the order of
+    ``tests/test_serving.py``'s helper of the same name)."""
+    while eng.scheduler.depth or eng.active_slots:
+        eng.step()
+        launched, eng._in_flight = eng._in_flight, None
+        if launched is not None:
+            eng._emit(launched, eng._read(launched))
+
+
+@pytest.mark.parametrize("engine_cls, sampled", [
+    (PagedServingEngine, False), (ServingEngine, False),
+    (ServingEngine, True)], ids=["paged", "slab", "slab-sampled"])
+def test_engines_reproduce_generate_and_the_reference(toy_share, engine_cls,
+                                                      sampled):
     """Through the engine as served (bucketed prefill, adoption into
-    pages and rows, decode over every row, admissions with a step in
-    flight): the token streams of ``generate()``, every served token
-    the reference's top logit."""
+    pages and rows, decode over every row, every first token fed to
+    its row's first step on the device, the paged engine's second and
+    third request admitted with a step in flight): the
+    token streams of ``generate()``, every served token the
+    reference's top logit; sampled, the streams of the serial order."""
     net, cfg, w = toy_share
     prompts = [_ids(9, 7).tolist(), _ids(9, 8).tolist(),
                _ids(9, 9).tolist()]
     kw = {"page_size": 8} if engine_cls is PagedServingEngine else {}
-    eng = engine_cls(net, max_batch_size=2, max_seq_len=48, min_bucket=16,
-                     cache_dtype="float32", **kw)
-    # two rows for three requests: the third is admitted into a freed
-    # row while the other row has a step in flight
-    handles = eng.generate(prompts, max_new_tokens=6)
-    rep = eng.metrics.report()
-    eng.close()
-    assert rep["counters"]["steps_overlapped"] > 0
+    if sampled:
+        kw.update(do_sample=True, temperature=0.8, top_k=8, seed=3)
+
+    def run(drive):
+        eng = engine_cls(net, max_batch_size=2, max_seq_len=48,
+                         min_bucket=16, cache_dtype="float32", **kw)
+        # two rows for three requests: the paged engine admits the
+        # third into a freed row while the other row has a step in flight
+        handles = [eng.submit(p, 6) for p in prompts]
+        drive(eng)
+        rep = eng.metrics.report()
+        eng.close()
+        return handles, rep
+
+    handles, rep = run(lambda eng: eng.run_until_idle())
+    # every launch but the first of a busy stretch had a step in
+    # flight, the one after the third request's admission too (the
+    # paged engine admits one an iteration; the slab engine admits the
+    # first two at once, they end together and the third finds it idle)
+    stretches = 1 + (engine_cls is ServingEngine)
+    assert rep["counters"]["steps_overlapped"] \
+        == rep["resident_tokens"]["count"] - stretches > 0
     assert all(layer.mlp.last_counts is None for layer in net.model.layers)
+    if sampled:
+        serial, rep_s = run(_drive_serially)
+        assert rep_s["counters"]["steps_overlapped"] == 0
+        assert [h.tokens for h in handles] == [h.tokens for h in serial]
+        assert all(len(h.tokens) == 6 for h in handles)
+        return
     want = np.asarray(net.generate(
         paddle.to_tensor(np.asarray(prompts)), max_new_tokens=6,
         cache_dtype="float32").value)[:, 9:]

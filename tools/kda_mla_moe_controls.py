@@ -181,8 +181,10 @@ def main(argv=None):
     # --------------------------------------------- A. the served tokens
     def adoption_fault(name):
         """What an engine hands its adoption program under the fault
-        ``name``: ``(arena, block, page ids, row)`` rewritten."""
-        def plant(engine, arena, block, page_ids, row):
+        ``name``: ``(arena, block, page ids, row)`` rewritten, the
+        first token and the tokens it is merged into (``feed``) as
+        they came."""
+        def plant(engine, arena, block, page_ids, row, *feed):
             rows = engine.max_batch_size
             if name == "rows_next":
                 row = (row + 1) % rows
@@ -199,7 +201,7 @@ def main(argv=None):
                 block = [jnp.pad(a[:, :-1], ((0, 0), (1, 0), (0, 0)))
                          if kept and a.ndim == 3 else a
                          for a, kept in zip(block, engine._row_arrays)]
-            return arena, block, page_ids, row
+            return (arena, block, page_ids, row, *feed)
 
         return plant
 
